@@ -373,6 +373,9 @@ def run_refresh_loop(
 
 
 def main(argv: Optional[List[str]] = None) -> None:
+    from photon_ml_tpu.utils import compile_cache
+
+    compile_cache.enable()
     args = build_parser().parse_args(argv)
     logging.basicConfig(
         level=getattr(logging, args.logging_level.upper(), logging.INFO)

@@ -163,6 +163,9 @@ def run(args) -> dict:
 
 
 def main(argv: Optional[List[str]] = None) -> None:
+    from photon_ml_tpu.utils import compile_cache
+
+    compile_cache.enable()
     run(build_parser().parse_args(argv))
 
 

@@ -1086,6 +1086,9 @@ def _iter_avro_records(path: str) -> Iterator[dict]:
 
 
 def main(argv: Optional[List[str]] = None) -> None:
+    from photon_ml_tpu.utils import compile_cache
+
+    compile_cache.enable()
     raw_argv = list(sys.argv[1:] if argv is None else argv)
     args = build_parser().parse_args(raw_argv)
     if args.mh_serve_worker:

@@ -673,6 +673,9 @@ def _run_job(
 
 
 def main(argv: Optional[List[str]] = None) -> None:
+    from photon_ml_tpu.utils import compile_cache
+
+    compile_cache.enable()
     raw_argv = list(sys.argv[1:] if argv is None else argv)
     args = build_parser().parse_args(raw_argv)
     if args.mh_worker:

@@ -66,9 +66,20 @@ class SparseFeatures:
         return (*self.values.shape[:-1], self.dim)
 
     def matvec(self, w: Array) -> Array:
-        """x @ w for every row: gather w at indices, multiply, reduce."""
-        prod = jnp.take(w, self.indices, axis=-1) * self.values
-        return prod.sum(axis=self.ell_axis)
+        """x @ w for every row: gather w at indices, multiply, reduce.
+
+        In the (N, K) layout the gather goes through the transposed (K, N)
+        index plane and is transposed back: the same values land in the
+        same places, but XLA's TPU compiler takes minutes over a gather
+        whose indices are a long, narrow (N, K) array (181 s at 200k x 9)
+        and seconds over its transpose."""
+        if self.ell_axis == -1 and self.indices.ndim >= 2:
+            gathered = jnp.swapaxes(
+                jnp.take(w, jnp.swapaxes(self.indices, -1, -2), axis=-1), -1, -2
+            )
+        else:
+            gathered = jnp.take(w, self.indices, axis=-1)
+        return (gathered * self.values).sum(axis=self.ell_axis)
 
     def rmatvec(self, u: Array) -> Array:
         """X^T u via scatter-add (the transpose of `matvec`).

@@ -104,8 +104,7 @@ class ShardDict(dict):
     which only need dtype/dim or read the host planes anyway) peek with
     `host_view` — so a shard whose training runs entirely on the bucketed
     or projected layout NEVER ships its raw ELL to the device (at
-    MovieLens-20M scale that is ~1.6 GB of HBM and, on a remote-device
-    link, a minute of transfer).
+    MovieLens-20M scale that is ~1.6 GB of the chip's 16 GB of HBM).
 
     `prefetch` extends the lazy upload to an ASYNC one: a consumer that
     knows it will need a shard soon (the coordinate-descent loop, before
@@ -253,8 +252,8 @@ class GameDataset:
     host_csr: Dict[str, "HostCSR"] = dataclasses.field(default_factory=dict)
     # Host copies of each shard's ELL planes (indices, values numpy) from
     # ingest. Projector construction and feature statistics read these
-    # instead of pulling the device arrays back over the interconnect
-    # (np.asarray on a remote-device array is a full download). Absent for
+    # instead of pulling the device arrays back (np.asarray on a device
+    # array is a synchronous device->host copy of all of it). Absent for
     # hand-built datasets (consumers fall back to np.asarray).
     host_ell: Dict[str, tuple] = dataclasses.field(default_factory=dict)
     # Factorized id-tag columns from ingest: tag -> (codes int64 per sample,
@@ -649,7 +648,7 @@ def _build_random_effect_dataset(
         # num_entities). Every (capacity, E) bucket shape then comes from a
         # SMALL discrete set, so the per-bucket train programs compile once
         # and are reused across buckets, chunks, and coordinates (each XLA
-        # compile costs seconds on a remote-compile backend; a GLMix fit
+        # compile costs seconds on the chip's compiler; a GLMix fit
         # had ~70). Dummy scatters land on the zero row, which training
         # re-zeroes at the end.
         n_chunks = -(-e // max_e)

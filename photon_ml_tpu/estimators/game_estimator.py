@@ -658,7 +658,7 @@ class GameEstimator:
         # Stage WALLS are delta'd against stage_base instead; notes have
         # no delta, so they reset.
         self.timing_registry.clear_notes(
-            "pack_path", "re_path", "sparse_layout"
+            "pack_path", "re_path", "sparse_layout", "sparse_objective"
         )
         # Snapshot the pod-scale robustness counters so fit_timing reports
         # THIS fit's events (the process-wide counters are cumulative).
@@ -1143,6 +1143,13 @@ class GameEstimator:
             # sparse shard packed) — the evidence the planner's
             # sparse_layout rule adopts next run.
             "layout": self.timing_registry.get_note("sparse_layout")
+            or "none",
+            # Which objective the packed sparse fixed effect runs:
+            # "pallas_fused" (one entry stream), "pallas_composed"
+            # (matvec + rmatvec kernels), or "none" (ELL through XLA).
+            "sparse_objective": self.timing_registry.get_note(
+                "sparse_objective"
+            )
             or "none",
         }
         bucket_shapes: Dict[str, object] = {}

@@ -66,6 +66,7 @@ from photon_ml_tpu.optimize import problem
 from photon_ml_tpu.optimize.common import OptResult
 from photon_ml_tpu.utils import faults
 from photon_ml_tpu.utils.knobs import get_knob
+from photon_ml_tpu.utils.observability import set_stage_note
 from photon_ml_tpu.optimize.config import CoordinateOptimizationConfig
 from photon_ml_tpu.game.model import (
     Coefficients,
@@ -86,8 +87,8 @@ _PACK_UNDECIDED = object()
 # per-movie trained under one GameOptimizationConfiguration) then share
 # compiled programs for equal block shapes — with the canonical bucket
 # shapes from build_random_effect_dataset this cuts a GLMix fit's XLA
-# program count by ~2x (each compile costs seconds on a remote-compile
-# backend). Only the norm-free case caches (normalization contexts carry
+# program count by ~2x (each compile costs seconds on the chip's
+# compiler). Only the norm-free case caches (normalization contexts carry
 # arrays, which must not leak across coordinates via a closure).
 _RE_JIT_CACHE: dict = {}
 
@@ -107,7 +108,7 @@ def sweep_scan_enabled() -> bool:
     Flare's whole-pipeline-compilation thesis applied to the solver loop:
     at bench scale the per-sweep program count drops from O(buckets) to
     O(distinct block shapes), which is what dominates small-coordinate
-    fits on a dispatch-latency-bound (remote or contended) backend.
+    fits, whose wall is host dispatch latency rather than device time.
 
     Reads through the typed knob registry at DISPATCH-DECISION time only
     (train_sweep's host-side gate) — never from inside a traced body, so
@@ -210,8 +211,8 @@ class FixedEffectCoordinate:
                 if cached is _PACK_UNDECIDED:
                     # Preferred path: pack from the host CSR the ingest
                     # stashed on the dataset — no device->host pull of the
-                    # ELL arrays (the r03 bench measured that round trip at
-                    # 275x the solve time on a remote-device backend). The
+                    # ELL arrays (a synchronous copy of the whole shard
+                    # back over PCIe for data the host already holds). The
                     # stash is consumed here so the arrays don't pin host
                     # RAM for the run's lifetime; COO expansion is deferred
                     # to this point so ingest never pays it. Fallback keeps
@@ -238,6 +239,13 @@ class FixedEffectCoordinate:
                 else:
                     bf = cached
             if bf is not None:
+                # A kernel the chip's compiler refuses is an error HERE,
+                # with the compiler's message — not inside train_fn's
+                # trace, and never a quiet switch to XLA.
+                set_stage_note(
+                    "sparse_objective",
+                    pallas_sparse.require_compiles(bf, self.loss),
+                )
                 self._features = bf
                 # The bucketed repack succeeded, so the objective's fused
                 # sparse gate (objective.value_and_gradient: `use_pallas is

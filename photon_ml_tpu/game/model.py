@@ -260,7 +260,11 @@ def random_effect_margins(features, entity_rows: Array, matrix: Array, norm) -> 
             out = jnp.sum(rows * features.values, axis=0)
         else:
             # (N, K) gather out of the (E+1, D) matrix, then sparse dot.
-            rows = matrix[entity_rows[:, None], features.indices]
+            # Gathered through the (K, N) transpose of the index plane and
+            # transposed back (same values, same places): a gather indexed
+            # by a long, narrow (N, K) array costs XLA's TPU compiler
+            # minutes, its transpose a second (see SparseFeatures.matvec).
+            rows = matrix[entity_rows[None, :], features.indices.T].T
             out = jnp.sum(rows * features.values, axis=-1)
     else:
         # Multiply-broadcast + per-row reduce, NOT einsum("nd,nd->n"): the

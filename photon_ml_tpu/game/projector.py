@@ -105,8 +105,8 @@ class IndexMapProjector:
         (IndexMapProjectorRDD.scala:60-90 unions active+passive; here
         `entity_rows` covers every sample so both are included).
         `host_planes` is ingest's (indices, values) host copy
-        (GameDataset.host_ell) — without it, np.asarray on a remote-device
-        array pulls the whole shard back over the interconnect.
+        (GameDataset.host_ell) — without it, np.asarray on a device array
+        is a synchronous device->host copy of the whole shard.
 
         Device path (data/device_assemble.py, PHOTON_DEVICE_ASSEMBLY):
         the nnz-sized key sort/unique/table scatter runs as XLA programs
@@ -207,7 +207,7 @@ class IndexMapProjector:
         """Rewrite global ELL indices to per-entity local slots (one-time).
         Entries whose feature is absent from the entity's table (value-0
         padding, or unseen entities) are zeroed out. `host_planes` avoids
-        the remote-device pull (see build). A device-built projector
+        the device->host copy (see build). A device-built projector
         projects as one XLA program (bitwise-equal to the host sweep)."""
         if host_planes is not None:
             idx, val = host_planes

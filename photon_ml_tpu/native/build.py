@@ -3,8 +3,10 @@
 Builds `libphoton_native.so` from the C++ sources in this directory with the
 system `g++` the first time it is needed and caches the result next to the
 sources (keyed by a content hash, so edits trigger a rebuild). Returns None
-when no compiler is available — callers fall back to the pure-Python
-implementations of the same on-disk formats.
+when the build fails — callers fall back to the pure-Python implementations
+of the same on-disk formats, which are a different program (ingest an order
+of magnitude slower), so the compiler's error is kept (`build_error()`) and
+logged once instead of being swallowed.
 
 Setting PHOTON_DISABLE_NATIVE=1 disables the native library for EVERY
 component (index store, LibSVM parser, ...) — one global kill switch, not
@@ -16,6 +18,7 @@ from __future__ import annotations
 
 import ctypes
 import hashlib
+import logging
 import os
 import subprocess
 import threading
@@ -38,6 +41,7 @@ _CACHED: Optional[str] = None
 _ATTEMPTED = False
 _CDLL: Optional[ctypes.CDLL] = None
 _CDLL_TRIED = False
+_BUILD_ERROR: Optional[str] = None
 
 _DISABLE_ENV = "PHOTON_DISABLE_NATIVE"
 
@@ -54,6 +58,22 @@ def _zlib_failure(stderr: bytes) -> bool:
     """Did the compile/link fail because zlib is absent on this host?"""
     s = stderr.decode("utf-8", "replace")
     return "-lz" in s or "zlib.h" in s
+
+
+def build_error() -> Optional[str]:
+    """Why the last build attempt of this process failed (the compiler's
+    stderr, or the OS error), or None when it succeeded / never ran."""
+    return _BUILD_ERROR
+
+
+def _build_failed(reason: str) -> None:
+    global _BUILD_ERROR
+    _BUILD_ERROR = reason
+    logging.getLogger(__name__).warning(
+        "native library build failed; every binding now runs its "
+        "pure-Python fallback (ingest is an order of magnitude slower): %s",
+        reason,
+    )
 
 
 def native_library_path() -> Optional[str]:
@@ -98,9 +118,9 @@ def native_library_path() -> Optional[str]:
                     subprocess.run(cmd, check=True, capture_output=True, timeout=120)
                     return None
                 except subprocess.CalledProcessError as e:
-                    return e.stderr or b""
-                except (OSError, subprocess.SubprocessError):
-                    return b""
+                    return e.stderr or b"g++ failed without output"
+                except (OSError, subprocess.SubprocessError) as e:
+                    return repr(e).encode()
 
             err = _compile(_SOURCES, ["-lz"])
             if err is None:
@@ -114,19 +134,26 @@ def native_library_path() -> Optional[str]:
                 # and falls back to the Python codec. Any other failure
                 # (transient OOM, genuine compile error) caches nothing so
                 # the next process retries the full build.
+                logging.getLogger(__name__).warning(
+                    "zlib is absent on this host: the native library is "
+                    "built without the Avro reader, and Avro ingest runs "
+                    "the pure-Python codec"
+                )
                 if os.path.exists(nozlib_path):
                     _CACHED = nozlib_path
-                elif _compile(
-                    [s for s in _SOURCES if s != "avro_reader.cc"], []
-                ) is None:
-                    os.replace(tmp, nozlib_path)
-                    _CACHED = nozlib_path
                 else:
-                    _CACHED = None
+                    err2 = _compile(
+                        [s for s in _SOURCES if s != "avro_reader.cc"], []
+                    )
+                    if err2 is None:
+                        os.replace(tmp, nozlib_path)
+                        _CACHED = nozlib_path
+                    else:
+                        _build_failed(err2.decode("utf-8", "replace")[-2000:])
             else:
-                _CACHED = None
-        except OSError:
-            _CACHED = None
+                _build_failed(err.decode("utf-8", "replace")[-2000:])
+        except OSError as e:
+            _build_failed(repr(e))
         return _CACHED
 
 

@@ -42,19 +42,10 @@ DATA_AXIS = "data"
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, check=False):
-    """`jax.shard_map` across the API move: new jax exposes it top-level
-    with `check_vma`; 0.4.x keeps it in jax.experimental.shard_map with
-    `check_rep`. Every shard_map in the tree goes through here so the
-    framework runs on both."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
+    """`jax.shard_map` with replication checking off by default — the one
+    spelling every shard_map in the tree goes through."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
     )
 
 

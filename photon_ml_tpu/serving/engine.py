@@ -308,7 +308,6 @@ class ServingEngine:
         self._watchdog = Watchdog(on_trip=self._on_watchdog_trip)
         self._hang_seen = False
         self._warmup_compiles: Optional[int] = None
-        self._dispatched_buckets: set = set()
         self._t_first: Optional[float] = None
         self._t_last: Optional[float] = None
         self._batchers: List[object] = []
@@ -740,8 +739,6 @@ class ServingEngine:
                 self._hang_seen = False
                 self.health.clear_degraded("device_hang")
         host_total, host_means = out
-        with self._lock:
-            self._dispatched_buckets.add(packed["bucket"])
         return np.asarray(host_total), np.asarray(host_means)
 
     def _dispatch_device(
@@ -873,14 +870,8 @@ class ServingEngine:
     @property
     def compiles(self) -> int:
         """XLA programs compiled by THIS engine: the jit wrapper's cache
-        size (an honest compile count), falling back to the number of
-        distinct bucket shapes dispatched if the private cache API ever
-        goes away (same value whenever each bucket is one program)."""
-        try:
-            return int(self._jit._cache_size())
-        except AttributeError:
-            with self._lock:
-                return len(self._dispatched_buckets)
+        size (an honest compile count)."""
+        return int(self._jit._cache_size())
 
     @property
     def recompiles_after_warmup(self) -> Optional[int]:

@@ -1427,10 +1427,7 @@ class TenantRegistry:
         t_done = time.monotonic()
         with self._cv:
             self._cobatch_dispatches += 1
-            try:
-                self._cobatch_compiles = int(self._jit._cache_size())
-            except AttributeError:
-                pass
+            self._cobatch_compiles = int(self._jit._cache_size())
             # Decaying max of dispatch service time (claim -> answers),
             # the micro-batcher's deadline-horizon estimate.
             self._service_tail_s = max(

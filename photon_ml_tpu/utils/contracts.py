@@ -300,6 +300,32 @@ PALLAS_GATE_TOLERANCES = {
     "bf16": {"rtol": 3e-2},
 }
 
+# chip_smoke.py's serving check: margins answered by `cli.serve` on the chip
+# against a plain numpy float32 recomputation from the written model
+# (fixed-effect dot + gathered random-effect rows + offset). Both sides are
+# f32 multiply + per-row reduce — no MXU pass on the serving path
+# (game/model.gathered_row_margins) — so they differ only by the order of
+# at most ~200 additions per coordinate: a few ulp of an O(1) margin. A
+# mis-resolved entity row or feature index is off by O(0.1-1).
+CHIP_SMOKE_SERVING_TOLERANCE = {"rtol": 1e-5, "atol": 1e-5}
+
+# Two DIFFERENT programs over the same data: a fit or a bucket program
+# partitioned over a mesh against the same computation on one device.
+# Sharding changes which partial sums exist and the order they combine in
+# (psum over per-device partials, ring gathers, a per-shard then cross-shard
+# reduce), so equality is numerical, not structural; bitwise equality
+# between the SAME program on the same inputs (restore, replay) stays a
+# separate, exact contract. "fit": coefficients and scores after an
+# iterative solve, where a last-ulp difference in one gradient is amplified
+# through line searches over the remaining iterations. "serve": one
+# bucket program's margins (a gather and a row reduce — reduction-order
+# noise only). chip_smoke.py --four-chips holds the chip to these; ROADMAP
+# D0 reuses them for the sharded-vs-replicated tests on the CPU mesh.
+SHARDED_VS_SINGLE_TOLERANCES = {
+    "fit": {"rtol": 5e-3, "atol": 5e-4},
+    "serve": {"rtol": 1e-5, "atol": 1e-5},
+}
+
 # bench.py multi_tenant section (ISSUE 15): the serving-platform
 # isolation certificate — 10 tenant bundles on one 8-virtual-device
 # fleet; injected faults, hangs, and overload confined to ONE chaos

@@ -29,8 +29,10 @@ module is the shared machinery:
 * `retry(fn, policy)` — bounded exponential backoff around transient
   failures. Default policy: 3 attempts, 50 ms base delay doubling to a
   2 s cap, retrying `InjectedFault`, `OSError`/`ConnectionError`/
-  `TimeoutError`, and XLA runtime errors (a remote-device tunnel surfaces
-  transient transport failures as `XlaRuntimeError`). Knobs:
+  `TimeoutError`, and the XLA runtime errors whose status can clear by
+  itself (`UNAVAILABLE`, `DEADLINE_EXCEEDED`, `ABORTED`, `CANCELLED`);
+  a refused compile or `RESOURCE_EXHAUSTED` is deterministic on an
+  attached chip and propagates at once. Knobs:
   `PHOTON_RETRY_MAX_ATTEMPTS`, `PHOTON_RETRY_BASE_DELAY_S`,
   `PHOTON_RETRY_MAX_DELAY_S`.
 
@@ -421,16 +423,38 @@ def reset_counters() -> None:
 # --------------------------------------------------------------------- retry
 
 
+# Status codes of an XLA runtime error that can clear on their own with
+# the chip attached to this host: a peer of a multi-host collective went
+# away or a rendezvous timed out. Every other status is deterministic for
+# the same program on the same device — RESOURCE_EXHAUSTED (out of HBM),
+# INTERNAL / INVALID_ARGUMENT / UNIMPLEMENTED (the compiler refused, or
+# Mosaic failed, the program), FAILED_PRECONDITION — and re-fails
+# identically, so retrying or degrading around it only hides it.
+_TRANSIENT_XLA_STATUSES = (
+    "UNAVAILABLE", "DEADLINE_EXCEEDED", "ABORTED", "CANCELLED",
+)
+
+
+def _xla_status(exc: BaseException) -> Optional[str]:
+    """The leading status code of an XLA runtime error ("RESOURCE_EXHAUSTED:
+    ..." -> "RESOURCE_EXHAUSTED"); None for any other exception."""
+    if type(exc).__name__ not in ("JaxRuntimeError", "XlaRuntimeError"):
+        return None
+    return str(exc).split(":", 1)[0].strip()
+
+
 def _default_transient(exc: BaseException) -> bool:
-    """Transient by default: injected faults, host I/O failures, and the
-    XLA runtime errors a remote-device tunnel surfaces transport blips as.
-    Deliberately NOT retried: programming errors (TypeError/ValueError/
-    KeyError...), which would re-fail identically and mask the bug."""
+    """Transient by default: injected faults, host I/O failures, and XLA
+    runtime errors whose status can clear by itself
+    (_TRANSIENT_XLA_STATUSES). Deliberately NOT retried: programming
+    errors (TypeError/ValueError/KeyError...) and the deterministic XLA
+    failures — a compile the chip refuses, an allocation HBM cannot hold
+    — which would re-fail identically and mask the bug."""
     if isinstance(
         exc, (InjectedFault, DeviceHang, OSError, ConnectionError, TimeoutError)
     ):
         return True
-    return type(exc).__name__ == "XlaRuntimeError"
+    return _xla_status(exc) in _TRANSIENT_XLA_STATUSES
 
 
 @dataclasses.dataclass(frozen=True)
@@ -512,11 +536,12 @@ def retry(
 
 
 def is_device_error(exc: BaseException) -> bool:
-    """True for failures attributable to the device/transport layer — the
-    class the serving circuit breaker counts toward opening (a malformed
+    """True for failures the device layer may recover from — the class
+    the serving circuit breaker counts toward opening (a malformed
     request raising TypeError/ValueError is the REQUEST's fault and must
-    never trip the breaker). Same classification as the retry policy's
-    transient set: what retry could not fix but was device-shaped."""
+    never trip the breaker; a refused compile or an out-of-memory is the
+    PROGRAM's, and failing the request is the honest answer). Same
+    classification as the retry policy's transient set."""
     return _default_transient(exc)
 
 

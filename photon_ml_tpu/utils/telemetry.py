@@ -168,6 +168,12 @@ METRIC_DESCRIPTIONS = {
     "committed on a serving tenant",
     "tier_rollbacks": "ladder transitions abandoned after retry "
     "exhaustion, the old generation still serving",
+    # Persistent compilation cache traffic (utils/compile_cache.py):
+    # programs compiled by this process = requests - hits.
+    "compile_cache_requests": "compile requests that consulted JAX's "
+    "persistent compilation cache",
+    "compile_cache_hits": "compile requests answered from the persistent "
+    "compilation cache instead of compiling",
     # -- histograms (fixed log-spaced buckets, mergeable) --
     "serving_latency_ms": "per-request wall latency through the batcher",
     "serving_queue_wait_ms": "submit-to-claim queue wait per request",
@@ -972,10 +978,30 @@ def validate_journal(path: str) -> Tuple[int, List[str]]:
 
 # ------------------------------------------------------------------- profile
 
-# Physical HBM roofline per chip (GB/s), the annotation bench.py carries
-# on every bandwidth figure — recorded in the profile so the planner can
-# judge achieved bandwidth without re-deriving hardware constants.
-HBM_ROOFLINE_GB_S = {"tpu": 819.0}
+# Peak HBM bandwidth per chip (GB/s), keyed by the `device_kind` string
+# the chip reports through JAX — the annotation bench.py carries on every
+# bandwidth figure, recorded in the profile so the planner can judge
+# achieved bandwidth without re-deriving hardware constants. Keyed by
+# kind, not platform: every TPU generation has its own peak, and a number
+# judged against another chip's roofline is wrong without looking wrong.
+# Source: Google Cloud documentation, "TPU v5e" (16 GB HBM2e at 819 GB/s);
+# a v5e reports itself as "TPU v5 lite".
+HBM_PEAK_GB_S = {"TPU v5 lite": 819.0}
+
+
+def hbm_peak_gb_s(platform: object, device_kind: object) -> Optional[float]:
+    """The chip's peak HBM bandwidth. None off the TPU (a CPU run carries
+    no roofline annotation); a TPU of a kind not in the table is an error,
+    never a default."""
+    if platform != "tpu":
+        return None
+    if device_kind not in HBM_PEAK_GB_S:
+        raise KeyError(
+            f"no HBM peak recorded for TPU device_kind {device_kind!r}: add "
+            f"it to telemetry.HBM_PEAK_GB_S with its source "
+            f"(known: {sorted(HBM_PEAK_GB_S)})"
+        )
+    return HBM_PEAK_GB_S[device_kind]
 
 
 def device_topology() -> Dict[str, object]:
@@ -1030,7 +1056,9 @@ def build_profile(
         "bucket_shapes": dict(bucket_shapes),
         "device_topology": topo,
         "roofline": {
-            "hbm_gb_per_s": HBM_ROOFLINE_GB_S.get(topo.get("platform")),
+            "hbm_gb_per_s": hbm_peak_gb_s(
+                topo.get("platform"), topo.get("device_kind")
+            ),
         },
         "metrics": dict(metrics if metrics is not None else METRICS.snapshot()),
     }

@@ -1,8 +1,7 @@
 """Hang watchdog: a deadline armed around device dispatches.
 
 The failure mode no counter observed before ISSUE 10: a wedged device (a
-dead ICI link mid-collective, a hung remote-compile tunnel, a runtime
-deadlock) blocks the dispatching host thread FOREVER — the fit never
+dead ICI link mid-collective, a runtime deadlock) blocks the dispatching host thread FOREVER — the fit never
 fails, the serving request never resolves, and every robustness counter
 reads zero because nothing ever *errored*. Spark's substrate covers this
 with speculative re-execution and executor-loss timeouts; our pjit mesh

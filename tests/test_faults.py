@@ -208,6 +208,35 @@ class TestRetry:
         assert len(calls) == 1
         assert faults.counters().get("retries", 0) == 0
 
+    @pytest.mark.parametrize(
+        "message, transient",
+        [
+            ("RESOURCE_EXHAUSTED: Attempting to allocate 17.2G on a 16G chip", False),
+            ("INTERNAL: Mosaic failed to compile TPU kernel", False),
+            ("INVALID_ARGUMENT: unsupported layout", False),
+            ("UNAVAILABLE: peer task went away", True),
+            ("DEADLINE_EXCEEDED: collective rendezvous timed out", True),
+        ],
+    )
+    def test_xla_status_decides_what_is_retried(self, message, transient):
+        """With the chip attached, a refused compile or an out-of-memory
+        re-fails identically: it propagates at once, uncounted. Only the
+        statuses that can clear by themselves get the retry policy."""
+        import jax
+
+        calls = []
+
+        def failing():
+            calls.append(1)
+            raise jax.errors.JaxRuntimeError(message)
+
+        exc = jax.errors.JaxRuntimeError(message)
+        assert faults.is_device_error(exc) is transient
+        with pytest.raises(jax.errors.JaxRuntimeError):
+            faults.retry(failing, self._policy(attempts=2))
+        assert len(calls) == (2 if transient else 1)
+        assert faults.counters().get("retries", 0) == (1 if transient else 0)
+
     def test_backoff_is_bounded(self):
         p = faults.RetryPolicy(
             max_attempts=10, base_delay_s=0.5, max_delay_s=1.5, backoff=2.0

@@ -144,6 +144,33 @@ def test_kill_switch_is_global(tmp_path, monkeypatch):
     assert build.native_library_path() is None
 
 
+def test_failed_build_keeps_and_logs_the_compiler_error_once(monkeypatch, caplog):
+    """When g++ fails, the bindings fall back to the Python codecs — a
+    different program — so the compiler's error is kept and logged, once."""
+    import logging
+    import subprocess
+
+    from photon_ml_tpu.native import build
+
+    def failing_gxx(cmd, **kwargs):
+        raise subprocess.CalledProcessError(
+            1, cmd, stderr=b"avro_reader.cc:7: fatal error: vector: No such file"
+        )
+
+    monkeypatch.delenv("PHOTON_DISABLE_NATIVE", raising=False)
+    monkeypatch.setattr(build, "_ATTEMPTED", False)
+    monkeypatch.setattr(build, "_CACHED", None)
+    monkeypatch.setattr(build, "_BUILD_ERROR", None)
+    monkeypatch.setattr(build, "_source_hash", lambda: "0" * 16)  # nothing cached
+    monkeypatch.setattr(build.subprocess, "run", failing_gxx)
+    with caplog.at_level(logging.WARNING, logger=build.__name__):
+        assert build.native_library_path() is None
+        assert build.native_library_path() is None  # no second attempt
+    assert "fatal error: vector" in build.build_error()
+    logged = [r for r in caplog.records if "native library build failed" in r.message]
+    assert len(logged) == 1 and "fatal error: vector" in logged[0].getMessage()
+
+
 def test_missing_value_after_colon_rejected(tmp_path):
     """'idx:' with no attached value must fail in both engines — the native
     parser must not consume the next line's label as the value."""

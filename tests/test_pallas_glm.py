@@ -133,35 +133,41 @@ def test_should_use_policy(rng):
 
 
 def test_health_probe_gates_dispatch(rng, monkeypatch):
-    """A kernel that crashes or miscomputes on this backend must disable
-    dispatch instead of taking down training."""
+    """A kernel the backend refuses must stop the job with the compiler's
+    message — never a quiet switch to the XLA objective."""
     big = jnp.zeros((4096, 256), jnp.float32)
     w = jnp.zeros((256,), jnp.float32)
     monkeypatch.setattr(pallas_glm, "FORCE_INTERPRET", True)
 
     # Healthy: probe passes and is cached.
-    monkeypatch.setattr(pallas_glm, "_HEALTHY", None)
+    monkeypatch.setattr(pallas_glm, "_HEALTHY", False)
     assert pallas_glm.should_use(big, w)
     assert pallas_glm._HEALTHY is True
 
-    # Crashing kernel: falls back.
-    monkeypatch.setattr(pallas_glm, "_HEALTHY", None)
+    # Crashing kernel: raises, carrying the message, and stays unhealthy.
+    monkeypatch.setattr(pallas_glm, "_HEALTHY", False)
     def boom(*a, **k):
         raise RuntimeError("mosaic says no")
     monkeypatch.setattr(pallas_glm, "value_gradient_sums", boom)
-    assert not pallas_glm.should_use(big, w)
+    with pytest.raises(RuntimeError, match="mosaic says no"):
+        pallas_glm.should_use(big, w)
     assert pallas_glm._HEALTHY is False
+
+    # The one explicit way round: the kill switch, which never probes.
+    monkeypatch.setattr(pallas_glm, "_ENABLED", False)
+    assert not pallas_glm.should_use(big, w)
 
 
 def test_health_probe_checks_numerics(rng, monkeypatch):
     big = jnp.zeros((4096, 256), jnp.float32)
     w = jnp.zeros((256,), jnp.float32)
     monkeypatch.setattr(pallas_glm, "FORCE_INTERPRET", True)
-    monkeypatch.setattr(pallas_glm, "_HEALTHY", None)
+    monkeypatch.setattr(pallas_glm, "_HEALTHY", False)
 
     real = pallas_glm.value_gradient_sums
     def wrong(*a, **k):
         val, g, su = real(*a, **k)
         return val + 100.0, g, su  # silently wrong value
     monkeypatch.setattr(pallas_glm, "value_gradient_sums", wrong)
-    assert not pallas_glm.should_use(big, w)
+    with pytest.raises(RuntimeError, match="disagree.*value"):
+        pallas_glm.should_use(big, w)

@@ -466,6 +466,35 @@ class TestObjectiveIntegration:
         s2 = np.asarray(coord_ell.score(model))
         np.testing.assert_allclose(s1, s2, rtol=1e-4, atol=1e-4)
 
+    def test_refused_kernel_is_an_error_at_coordinate_construction(
+        self, interpret_kernels, monkeypatch
+    ):
+        """A sparse kernel the compiler refuses stops the job where the
+        pack is accepted, with the compiler's message — not inside the
+        solver's trace, and never by switching to the XLA reference."""
+        from photon_ml_tpu.data.game_dataset import GameDataset
+        from photon_ml_tpu.game.coordinate import FixedEffectCoordinate
+        from photon_ml_tpu.optimize.config import L2, CoordinateOptimizationConfig
+        from photon_ml_tpu.types import TaskType
+
+        rng = np.random.default_rng(16)
+        n, d, k = 9100, 190, 6  # shapes no other test traces
+        idx = rng.integers(0, d, size=(n, k)).astype(np.int32)
+        val = rng.normal(size=(n, k)).astype(np.float32)
+        y = (rng.uniform(size=n) < 0.5).astype(np.float32)
+        ds = GameDataset.build({"s": SparseFeatures(jnp.asarray(idx), jnp.asarray(val), d)}, y)
+        cfg = CoordinateOptimizationConfig(regularization=L2, reg_weight=1.0)
+
+        def refuse(*a, **k):
+            raise AssertionError("indices_aval.shape == in_aval.shape + (1,)")
+
+        monkeypatch.setattr(pallas_sparse, "_level_rmatvec", refuse)
+        with pytest.raises(RuntimeError, match=r"do not compile.*indices_aval"):
+            FixedEffectCoordinate(ds, "s", cfg, TaskType.LOGISTIC_REGRESSION)
+        monkeypatch.undo()
+        coord = FixedEffectCoordinate(ds, "s", cfg, TaskType.LOGISTIC_REGRESSION)
+        assert isinstance(coord._features, BucketedSparseFeatures)
+
 
 class TestHostCooPack:
     def test_coordinate_packs_from_host_csr(self, interpret_kernels, monkeypatch):

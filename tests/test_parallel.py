@@ -280,7 +280,7 @@ class TestShardedFusedObjective:
         from photon_ml_tpu.ops import pallas_glm
 
         monkeypatch.setattr(pallas_glm, "FORCE_INTERPRET", True)
-        monkeypatch.setattr(pallas_glm, "_HEALTHY", None)
+        monkeypatch.setattr(pallas_glm, "_HEALTHY", False)
         return pallas_glm
 
     @pytest.fixture

@@ -595,6 +595,26 @@ class TestProfile:
         with pytest.raises(ValueError, match="kind"):
             telemetry.read_profile(path, kind="serve")
 
+    @pytest.mark.parametrize(
+        "platform, kind, peak",
+        [
+            ("tpu", "TPU v5 lite", 819.0),  # what a v5e reports itself as
+            ("cpu", "cpu", None),  # off the chip: no roofline annotation
+            ("tpu", "TPU v9 imaginary", KeyError),  # never another chip's peak
+        ],
+    )
+    def test_hbm_peak_is_keyed_by_device_kind(self, platform, kind, peak):
+        topo = dict(telemetry.device_topology(), platform=platform, device_kind=kind)
+        build = lambda: telemetry.build_profile(
+            "serve", wall_s=0.0, stages={}, dispatch={}, bucket_shapes={},
+            serving={}, topology=topo,
+        )
+        if peak is KeyError:
+            with pytest.raises(KeyError, match="TPU v9 imaginary"):
+                build()
+        else:
+            assert build()["roofline"] == {"hbm_gb_per_s": peak}
+
 
 # ------------------------------------------------------- tracing-off no-ops
 

@@ -1,0 +1,224 @@
+"""The main path's kernels, compiled at real widths by the chip's own compiler.
+
+Interpret mode accepts programs that Mosaic refuses (the grouped sparse
+rmatvec passed every parity test for sixteen PRs and had never compiled
+for a TPU), so these cases hand the kernels to the TPU compiler that is
+installed here, for a v5e that is described and not attached. Nothing
+runs: a pass says the chip's compiler takes the program at this shape
+and that the kernel is in it (`tpu_custom_call`), not that it is right
+or fast.
+
+All in ONE file, the topology described inside a module-scoped fixture
+and never at import, in a `skipif` or in `parametrize` arguments: only
+one process may hold the TPU library, and under xdist every worker
+imports every test file. The persistent compilation cache is off around
+the compiles (an entry written for a described device cannot be read
+back without the chip).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from photon_ml_tpu.data import bucketed
+from photon_ml_tpu.ops import pallas_glm, pallas_sparse
+from photon_ml_tpu.ops.losses import LOGISTIC
+from photon_ml_tpu.types import TaskType
+
+# README headline shape (dense fixed effect) and the e2e MovieLens shape
+# (sparse fixed effect; bench.py e2e_from_disk, chip_smoke.py).
+DENSE_N, DENSE_D = 1_048_576, 512
+SPARSE_N, SPARSE_D, SPARSE_NNZ = 262_144, 200, 8
+# chip_smoke's served model at 2M rows: d = 200 + intercept, rows//145
+# users, rows//740 movies (+ the pinned zero row each).
+SERVE_D, SERVE_USERS, SERVE_MOVIES, SERVE_BATCH = 201, 13_794, 2_703, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A SingleDeviceSharding on one described v5e chip, the persistent
+    compilation cache off for as long as the module's tests run."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree,
+    )
+
+
+def _vec(sharding, n, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct((n,), dtype, sharding=sharding)
+
+
+def _scalar(sharding):
+    return jax.ShapeDtypeStruct((), jnp.float32, sharding=sharding)
+
+
+def _compiled_text(lowered) -> str:
+    return lowered.compile().as_text()
+
+
+# ------------------------------------------------------------------- dense
+
+
+@pytest.mark.parametrize("x_dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("kernel", ["value_gradient", "hessian_vector"])
+def test_dense_kernels_compile_for_v5e(one_chip, kernel, x_dtype):
+    X = jax.ShapeDtypeStruct((DENSE_N, DENSE_D), x_dtype, sharding=one_chip)
+    w, n, s = _vec(one_chip, DENSE_D), _vec(one_chip, DENSE_N), _scalar(one_chip)
+    if kernel == "value_gradient":
+        lowered = pallas_glm.value_gradient_sums.lower(LOGISTIC, w, s, X, n, n, n)
+    else:
+        lowered = pallas_glm.hessian_vector_sums.lower(
+            LOGISTIC, w, s, w, s, X, n, n, n
+        )
+    assert "tpu_custom_call" in _compiled_text(lowered)
+
+
+# ------------------------------------------------------------------ sparse
+
+
+@functools.lru_cache(maxsize=None)
+def _movielens_pack(layout: str) -> bucketed.BucketedSparseFeatures:
+    """A real host pack at the MovieLens shape. Its mean (tile, bucket)
+    segment is exactly MAX_SP entries, so level 1 fills and the variance
+    tail spills to a grouped level 2 and the COO list: the "spill" case
+    keeps that; the two level-1-only cases drop the spill and force the
+    level-1 layout, keeping the real segment widths."""
+    rng = np.random.default_rng(0)
+    rows = np.repeat(np.arange(SPARSE_N, dtype=np.int64), SPARSE_NNZ)
+    cols = rng.integers(0, SPARSE_D, size=SPARSE_N * SPARSE_NNZ)
+    vals = rng.standard_normal(SPARSE_N * SPARSE_NNZ).astype(np.float32)
+    bf = bucketed.pack_bucketed(
+        rows, cols, vals, SPARSE_N, SPARSE_D, host_only=True,
+        row_aligned={"rowalign": True, "grouped": False, "spill": None}[layout],
+    )
+    assert bf.level2 is not None and not bf.level2.row_aligned
+    assert bf.overflow_vals.shape[0] > 0
+    if layout == "spill":
+        return bf
+    none = np.zeros((0,), np.int32)
+    return dataclasses.replace(
+        bf, level2=None, overflow_rows=none, overflow_cols=none,
+        overflow_vals=np.zeros((0,), np.float32),
+    )
+
+
+@pytest.mark.parametrize("layout", ["rowalign", "grouped", "spill"])
+@pytest.mark.parametrize("kernel", ["matvec", "rmatvec", "fused"])
+def test_sparse_kernels_compile_for_v5e(one_chip, kernel, layout):
+    pack = _movielens_pack(layout)
+    if layout != "spill":  # the spill case keeps the planner's own layout
+        assert pack.level1.row_aligned == (layout == "rowalign")
+    bf = _on(one_chip, pack)
+    if kernel == "matvec":
+        lowered = pallas_sparse.matvec.lower(bf, _vec(one_chip, SPARSE_D))
+    elif kernel == "rmatvec":
+        lowered = pallas_sparse.rmatvec.lower(bf, _vec(one_chip, SPARSE_N))
+    else:
+        assert pallas_sparse.fused_feasible(pack)
+        n = _vec(one_chip, SPARSE_N)
+        lowered = pallas_sparse.fused_value_gradient_sums.lower(
+            LOGISTIC, _vec(one_chip, SPARSE_D), _scalar(one_chip), bf, n, n, n
+        )
+    assert "tpu_custom_call" in _compiled_text(lowered)
+
+
+# ------------------------------------------------------ long, narrow planes
+
+# A raw ELL plane at the MovieLens shape is (N, 9). XLA's TPU compiler takes
+# minutes over a reshape that flattens such an array, or a gather indexed by
+# it (157-181 s each at N = 200,000; 271 s for _project_entries at 2M on the
+# v5e), and a second or two over the same work through the (9, N) transpose.
+# These programs go through the transpose; the bound is loose enough for a
+# loaded host and far below what the direct form costs.
+NARROW_N, NARROW_K = 200_000, 9
+NARROW_COMPILE_LIMIT_S = 60.0
+
+
+@pytest.mark.parametrize("program", ["project_entries", "fe_margins_ell", "re_margins_ell"])
+def test_narrow_planes_compile_in_seconds_for_v5e(one_chip, program):
+    from photon_ml_tpu.data import device_assemble
+    from photon_ml_tpu.data.containers import SparseFeatures
+    from photon_ml_tpu.transformers.game_transformer import _fe_margins, _re_margins
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n, k, dim, entities = NARROW_N, NARROW_K, SERVE_D, NARROW_N // 145
+    idx, val = spec((n, k), jnp.int32), spec((n, k), jnp.float32)
+    ent = spec((n,), jnp.int32)
+    if program == "project_entries":
+        lowered = device_assemble._project_entries.lower(
+            spec((276_000,), jnp.int32), spec((entities + 2,), jnp.int32),
+            idx, val, ent, dimw=dim + 1,
+        )
+    elif program == "fe_margins_ell":
+        lowered = _fe_margins.lower(
+            SparseFeatures(idx, val, dim), spec((dim,), jnp.float32), None
+        )
+    else:
+        lowered = _re_margins.lower(
+            SparseFeatures(idx, val, 208), ent,
+            spec((entities + 1, 208), jnp.float32), None,
+        )
+    t0 = time.perf_counter()
+    lowered.compile()
+    assert time.perf_counter() - t0 < NARROW_COMPILE_LIMIT_S
+
+
+# ----------------------------------------------------------------- serving
+
+
+def test_serving_bucket_program_compiles_for_v5e(one_chip):
+    """One bucket program of the engine serving chip_smoke's model: fixed
+    effect + per-user + per-movie rows, request scratch donated as the
+    engine donates it on an accelerator. No kernel is expected in it: the
+    program is a gather and per-row reduces."""
+    from photon_ml_tpu.serving.engine import _score_program
+
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((SERVE_BATCH,), jnp.int32, sharding=one_chip)
+    program = jax.jit(
+        _score_program,
+        static_argnames=("kinds", "shards", "meshes", "task"),
+        donate_argnums=(0, 1, 2, 3),
+    )
+    compiled = program.lower(
+        f32(SERVE_BATCH),
+        {"g": f32(SERVE_BATCH, SERVE_D)},
+        (None, rows, rows),
+        (None, None, None),
+        (f32(SERVE_D), f32(SERVE_USERS, SERVE_D), f32(SERVE_MOVIES, SERVE_D)),
+        (None, None, None),
+        kinds=("fe", "re", "re"),
+        shards=("g", "g", "g"),
+        meshes=(None, None, None),
+        task=TaskType.LOGISTIC_REGRESSION,
+    ).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes > 4 * SERVE_USERS * SERVE_D
+    assert "tpu_custom_call" not in compiled.as_text()
