@@ -91,6 +91,7 @@ GameOptimizationConfiguration = Mapping[str, CoordinateOptimizationConfig]
 from photon_ml_tpu.utils.contracts import (
     PREPARE_STAGES,
     ROBUSTNESS_CLEAN_ZERO_KEYS,
+    SOLVE_STAGES,
 )
 
 
@@ -592,8 +593,26 @@ class GameEstimator:
 
         plan_owned = planner.current_plan() is None
         installed = planner.ensure_ambient_plan()
+        # Where this fit's SOLVE_STAGES walls are recorded. Pipelined: the
+        # estimator's own registry, as the solve's scope always was there.
+        # Synchronous: a registry of this fit's own, because the
+        # estimator's must stay closed outside prepare work — with it open
+        # across the solve, solve-time uploads would land in `upload` and
+        # the PREPARE_STAGES would no longer tile `prepare_s`. The data
+        # plane's own scopes (prepare, validation prep, coordinate
+        # construction) nest inside and still reach the estimator's.
+        from photon_ml_tpu.data.pipeline import pipeline_enabled
+
+        stages = (
+            self.timing_registry
+            if pipeline_enabled(self.pipeline)
+            else TimingRegistry()
+        )
+        stages_base = dict(stages.sections)
         try:
-            with telemetry.span("fit", num_configs=len(opt_configs)):
+            with stage_scope(stages), stage_timer(
+                "fit", num_configs=len(opt_configs)
+            ):
                 if emit is not None:
                     emit(TrainingStartEvent(num_samples=int(data.num_samples)))
                 results = self._fit(
@@ -615,10 +634,35 @@ class GameEstimator:
                             ),
                         )
                     )
-                return results
+            self._publish_stages(stages, stages_base)
+            return results
         finally:
             if plan_owned and installed is not None:
                 planner.uninstall_plan()
+
+    def _publish_stages(self, stages: TimingRegistry, base: Dict[str, float]):
+        """This fit's stage walls and evaluation counts, per fit and per
+        process: `fit_timing["stages_s"]` (plain floats; a stage that did
+        not run reads 0.0) beside `fit_timing["fn_evals"]`, and the same
+        numbers into `telemetry.METRICS` — histogram
+        `fit_stage_s{stage=<name>}`, counter
+        `objective_evaluations{coordinate=<id>,kind=fixed|random}` — so a
+        reader that sees only the process gets a window's totals as the
+        process total less the fits made before it."""
+        self.fit_timing["stages_s"] = {
+            k: float(stages.get(k) - base.get(k, 0.0)) for k in SOLVE_STAGES
+        }
+        for stage, seconds in self.fit_timing["stages_s"].items():
+            telemetry.METRICS.observe(
+                "fit_stage_s", seconds, labels=(("stage", stage),)
+            )
+        for cid, evals in self.fit_timing["fn_evals"].items():
+            kind = "random" if self._prepared[cid].re_dataset is not None else "fixed"
+            telemetry.METRICS.increment(
+                "objective_evaluations",
+                evals,
+                labels=(("coordinate", cid), ("kind", kind)),
+            )
 
     def _on_cd_event(self, etype: str, **fields) -> None:
         """run_coordinate_descent's event hook -> typed bus events
@@ -647,10 +691,13 @@ class GameEstimator:
         # Stage breakdown (prepare = host-side dataset/coordinate builds,
         # solve = coordinate descent + validation): exposed as
         # `self.fit_timing` so drivers/benchmarks report where fit wall
-        # goes without instrumenting internals. `prepare_s` additionally
-        # splits into the PREPARE_STAGES keys (+ `other`, the residual
-        # glue) recorded by the data-plane functions themselves.
-        t0 = time.perf_counter()
+        # goes without instrumenting internals. Both are sums of this
+        # fit's SOLVE_STAGES walls: `prepare_s` of `fit/revalidate`,
+        # `fit/validation_prep` and every configuration's
+        # `fit/coordinates`, `solve_s` of every `fit/descent` and
+        # `fit/final_evaluate`. `prepare_s` additionally splits into the
+        # PREPARE_STAGES keys (+ `other`, the residual glue) recorded by
+        # the data-plane functions themselves.
         stage_base = dict(self.timing_registry.sections)
         # Per-fit note evidence: the placement/layout notes describe THIS
         # fit's decisions (a second fit on cached packs legitimately
@@ -667,40 +714,48 @@ class GameEstimator:
         robustness_base = {
             k: _faults.COUNTERS.get(k) for k in ROBUSTNESS_CLEAN_ZERO_KEYS
         }
-        prepared = self.prepare(data)
-        for cfgs in opt_configs:
-            missing = [c for c in self.update_sequence if c not in cfgs and c not in self.locked]
-            if missing:
-                raise ValueError(f"optimization config missing coordinates {missing}")
+        # The first fit prepares here; every later fit of a refit loop
+        # finds the estimator prepared and only checks and rebuilds.
+        with stage_timer("fit/revalidate") as revalidate:
+            prepared = self.prepare(data)
+            for cfgs in opt_configs:
+                missing = [c for c in self.update_sequence if c not in cfgs and c not in self.locked]
+                if missing:
+                    raise ValueError(f"optimization config missing coordinates {missing}")
 
-        suite = self._validation_suite(validation_data) if validation_data is not None else None
-        specs = self.scoring_specs()
+            suite = self._validation_suite(validation_data) if validation_data is not None else None
+            specs = self.scoring_specs()
 
-        # One-time host prep of the validation dataset per coordinate
-        # (projection + entity-row resolution) reused across every CD step;
-        # attributed to the `projector` stage (it is projection +
+        # Host prep of the validation dataset per coordinate (projection +
+        # entity-row resolution), once a fit and reused across every CD
+        # step; attributed to the `projector` stage (it is projection +
         # entity-row resolution over the validation sample axis).
         val_prep = None
-        if validation_data is not None:
-            with stage_scope(self.timing_registry):
-                # Prefetch INSIDE the scope: AsyncUploader captures the
-                # submitter's registry at submit time, so these uploads'
-                # walls land in the breakdown's `upload` stage.
-                prefetch_fixed_effect_shards(
-                    specs, self.update_sequence, validation_data, self.pipeline
-                )
-                with self._exclusive_stage("projector"):
-                    val_prep = {
-                        cid: prepare_coordinate_data(specs[cid], validation_data)
-                        for cid in self.update_sequence
-                    }
+        with stage_timer("fit/validation_prep") as validation_prep:
+            if validation_data is not None:
+                with stage_scope(self.timing_registry):
+                    # Prefetch INSIDE the scope: AsyncUploader captures the
+                    # submitter's registry at submit time, so these uploads'
+                    # walls land in the breakdown's `upload` stage.
+                    prefetch_fixed_effect_shards(
+                        specs, self.update_sequence, validation_data, self.pipeline
+                    )
+                    with self._exclusive_stage("projector"):
+                        val_prep = {
+                            cid: prepare_coordinate_data(specs[cid], validation_data)
+                            for cid in self.update_sequence
+                        }
 
-        self.fit_timing = {"prepare_s": time.perf_counter() - t0, "solve_s": 0.0}
+        self.fit_timing = {
+            "prepare_s": revalidate.seconds + validation_prep.seconds,
+            "solve_s": 0.0,
+        }
 
         results: List[GameResult] = []
         prev_model: Optional[GameModel] = initial_model
         diverged_steps = 0
         collective_bytes = 0
+        fn_evals: Dict[str, int] = {}
         sharding_infos: Dict[str, dict] = {}
         default_cfg = CoordinateOptimizationConfig()
         for ci, cfgs in enumerate(opt_configs):
@@ -708,14 +763,14 @@ class GameEstimator:
                 self.event_emitter.send(
                     SweepConfigEvent(index=ci, total=len(opt_configs))
                 )
-            t_coord = time.perf_counter()
-            coordinates = {
-                cid: self._coordinate_for(
-                    data, cid, prepared[cid], cfgs.get(cid, default_cfg)
-                )
-                for cid in self.update_sequence
-            }
-            self.fit_timing["prepare_s"] += time.perf_counter() - t_coord
+            with stage_timer("fit/coordinates") as construction:
+                coordinates = {
+                    cid: self._coordinate_for(
+                        data, cid, prepared[cid], cfgs.get(cid, default_cfg)
+                    )
+                    for cid in self.update_sequence
+                }
+            self.fit_timing["prepare_s"] += construction.seconds
             if ci == 0:
                 # The sharding decision each coordinate trains under
                 # (entity axis size, rows per shard, collective bytes) —
@@ -725,7 +780,6 @@ class GameEstimator:
                     info = getattr(coord, "sharding_info", None)
                     if info is not None:
                         sharding_infos[cid] = info()
-            t_solve = time.perf_counter()
             if ci == 0:
                 # Every fixed-effect coordinate that wanted the ingest's
                 # host-COO stash has consumed it by now (its pack decision
@@ -744,19 +798,14 @@ class GameEstimator:
                 def validation_scorer(cid, model):
                     return coordinate_margins(specs[cid], model, val_prep[cid])
 
-            # Pipelined: keep the stage scope open across the solve so the
-            # prefetched uploads (which run DURING coordinate descent, on
-            # background threads) land in the `upload` stage — the
-            # breakdown must show overlapped transfers even though no
-            # prepare wall waited on them. Synchronous runs keep the scope
-            # closed: solve-time uploads are solve work there, and the
-            # stage keys must tile prepare_s exactly.
-            solve_scope = (
-                stage_scope(self.timing_registry)
-                if pipelined
-                else contextlib.nullcontext()
-            )
-            with solve_scope:
+            # The ambient stage scope (opened by fit()) is the estimator's
+            # registry only when pipelined: the prefetched uploads (which
+            # run DURING coordinate descent, on background threads) then
+            # land in the `upload` stage — the breakdown must show
+            # overlapped transfers even though no prepare wall waited on
+            # them. In a synchronous run solve-time uploads are solve work,
+            # and the stage keys must tile prepare_s exactly.
+            with stage_timer("fit/descent") as descent:
                 cd = run_coordinate_descent(
                     coordinates,
                     self.cd_iterations,
@@ -788,9 +837,10 @@ class GameEstimator:
                     ),
                 )
             evaluation = None
-            if validation_data is not None and suite is not None:
-                transformer = self._make_transformer(cd.model)
-                evaluation = transformer.evaluate(validation_data, suite, val_prep)
+            with stage_timer("fit/final_evaluate") as final_evaluate:
+                if validation_data is not None and suite is not None:
+                    transformer = self._make_transformer(cd.model)
+                    evaluation = transformer.evaluate(validation_data, suite, val_prep)
             results.append(
                 GameResult(
                     model=cd.model,
@@ -803,91 +853,95 @@ class GameEstimator:
             prev_model = cd.model
             diverged_steps += cd.diverged_steps
             collective_bytes += cd.collective_bytes
-            self.fit_timing["solve_s"] += time.perf_counter() - t_solve
+            for cid, evals in cd.fn_evals.items():
+                fn_evals[cid] = fn_evals.get(cid, 0) + evals
+            self.fit_timing["solve_s"] += descent.seconds + final_evaluate.seconds
             logger.info(
                 "configuration %d/%d trained%s",
                 ci + 1,
                 len(opt_configs),
                 f": {evaluation.results}" if evaluation else "",
             )
-        # Finalize the per-stage prepare breakdown: deltas of the timing
-        # registry over this fit call. In a synchronous run the stages +
-        # `other` tile `prepare_s`; in a pipelined run overlapped stages
-        # record where they ran, so their sum can exceed the wall they hid
-        # behind (that excess IS the overlap win).
-        stages = {
-            k: self.timing_registry.get(k) - stage_base.get(k, 0.0)
-            for k in PREPARE_STAGES
-        }
-        stages["other"] = max(
-            0.0, self.fit_timing["prepare_s"] - sum(stages.values())
-        )
-        self.fit_timing.update(stages)
-        # Pack placement split (nested inside the `pack` stage, so NOT part
-        # of the tiling sum above): where the bucketed placement pass
-        # actually ran, plus which implementation ran it. The keys are
-        # always present — the bench e2e contract fails loudly on their
-        # absence like the stage keys — and `pack_path` is "none" when no
-        # pack engaged this fit.
-        self.fit_timing["pack_device_s"] = self.timing_registry.get(
-            "pack_device"
-        ) - stage_base.get("pack_device", 0.0)
-        self.fit_timing["pack_host_s"] = self.timing_registry.get(
-            "pack_host"
-        ) - stage_base.get("pack_host", 0.0)
-        self.fit_timing["pack_path"] = (
-            self.timing_registry.get_note("pack_path") or "none"
-        )
-        # RE-assembly placement split (nested inside the `re_build` stage,
-        # so NOT part of the tiling sum): where the entity-block build ran
-        # (device_assemble vs the host loops). Keys always present —
-        # `re_path` is "none" when no random-effect coordinate was built.
-        self.fit_timing["re_device_s"] = self.timing_registry.get(
-            "re_device"
-        ) - stage_base.get("re_device", 0.0)
-        self.fit_timing["re_host_s"] = self.timing_registry.get(
-            "re_host"
-        ) - stage_base.get("re_host", 0.0)
-        self.fit_timing["re_path"] = (
-            self.timing_registry.get_note("re_path") or "none"
-        )
-        # Robustness counter: coordinate updates rejected by the divergence
-        # guard across every configuration of this fit (0 on a clean fit —
-        # nonzero in a bench artifact is a loud regression signal).
-        self.fit_timing["diverged_steps"] = diverged_steps
-        # Pod-scale robustness counters for THIS fit (ISSUE 10): collective
-        # re-dispatches, shard-staging retries, failed promotions, watchdog
-        # trips — all keys always present and all-zero on a clean fit (the
-        # bench clean-run contract enforces it).
-        self.fit_timing["robustness"] = {
-            k: _faults.COUNTERS.get(k) - robustness_base[k]
-            for k in ROBUSTNESS_CLEAN_ZERO_KEYS
-        }
-        # The pod-scale sharding decision as proper JSON keys (ISSUE 7):
-        # always present — `entity_sharded` False with axis_size 1 on the
-        # single-device path — so the bench e2e contract can fail loudly on
-        # absence rather than ship an artifact that silently lost it.
-        # The adaptive-runtime plan block (ISSUE 14): always present —
-        # inactive ({"active": False, ...}) on an unplanned fit — so the
-        # bench e2e contract can fail loudly on absence, and an auditor
-        # can tell "planner off" from "block lost".
-        self.fit_timing["plan"] = planner.plan_block()
-        re_infos = [i for i in sharding_infos.values() if i is not None]
-        self.fit_timing["sharding"] = {
-            "entity_sharded": any(i["entity_sharded"] for i in re_infos),
-            "axis_size": max(
-                [i["axis_size"] for i in re_infos], default=1
-            ),
-            "rows_per_shard": {
-                cid: i["rows_per_shard"] for cid, i in sharding_infos.items()
-            },
-            "collective_bytes_per_sweep": sum(
-                i["collective_bytes_per_sweep"] for i in re_infos
-            ),
-            # Actually moved across the whole fit (every accepted sweep of
-            # every configuration) — 0 on the replicated path.
-            "collective_bytes_total": int(collective_bytes),
-        }
+        with stage_timer("fit/publish"):
+            self.fit_timing["fn_evals"] = fn_evals
+            # Finalize the per-stage prepare breakdown: deltas of the timing
+            # registry over this fit call. In a synchronous run the stages +
+            # `other` tile `prepare_s`; in a pipelined run overlapped stages
+            # record where they ran, so their sum can exceed the wall they hid
+            # behind (that excess IS the overlap win).
+            stages = {
+                k: self.timing_registry.get(k) - stage_base.get(k, 0.0)
+                for k in PREPARE_STAGES
+            }
+            stages["other"] = max(
+                0.0, self.fit_timing["prepare_s"] - sum(stages.values())
+            )
+            self.fit_timing.update(stages)
+            # Pack placement split (nested inside the `pack` stage, so NOT part
+            # of the tiling sum above): where the bucketed placement pass
+            # actually ran, plus which implementation ran it. The keys are
+            # always present — the bench e2e contract fails loudly on their
+            # absence like the stage keys — and `pack_path` is "none" when no
+            # pack engaged this fit.
+            self.fit_timing["pack_device_s"] = self.timing_registry.get(
+                "pack_device"
+            ) - stage_base.get("pack_device", 0.0)
+            self.fit_timing["pack_host_s"] = self.timing_registry.get(
+                "pack_host"
+            ) - stage_base.get("pack_host", 0.0)
+            self.fit_timing["pack_path"] = (
+                self.timing_registry.get_note("pack_path") or "none"
+            )
+            # RE-assembly placement split (nested inside the `re_build` stage,
+            # so NOT part of the tiling sum): where the entity-block build ran
+            # (device_assemble vs the host loops). Keys always present —
+            # `re_path` is "none" when no random-effect coordinate was built.
+            self.fit_timing["re_device_s"] = self.timing_registry.get(
+                "re_device"
+            ) - stage_base.get("re_device", 0.0)
+            self.fit_timing["re_host_s"] = self.timing_registry.get(
+                "re_host"
+            ) - stage_base.get("re_host", 0.0)
+            self.fit_timing["re_path"] = (
+                self.timing_registry.get_note("re_path") or "none"
+            )
+            # Robustness counter: coordinate updates rejected by the divergence
+            # guard across every configuration of this fit (0 on a clean fit —
+            # nonzero in a bench artifact is a loud regression signal).
+            self.fit_timing["diverged_steps"] = diverged_steps
+            # Pod-scale robustness counters for THIS fit (ISSUE 10): collective
+            # re-dispatches, shard-staging retries, failed promotions, watchdog
+            # trips — all keys always present and all-zero on a clean fit (the
+            # bench clean-run contract enforces it).
+            self.fit_timing["robustness"] = {
+                k: _faults.COUNTERS.get(k) - robustness_base[k]
+                for k in ROBUSTNESS_CLEAN_ZERO_KEYS
+            }
+            # The pod-scale sharding decision as proper JSON keys (ISSUE 7):
+            # always present — `entity_sharded` False with axis_size 1 on the
+            # single-device path — so the bench e2e contract can fail loudly on
+            # absence rather than ship an artifact that silently lost it.
+            # The adaptive-runtime plan block (ISSUE 14): always present —
+            # inactive ({"active": False, ...}) on an unplanned fit — so the
+            # bench e2e contract can fail loudly on absence, and an auditor
+            # can tell "planner off" from "block lost".
+            self.fit_timing["plan"] = planner.plan_block()
+            re_infos = [i for i in sharding_infos.values() if i is not None]
+            self.fit_timing["sharding"] = {
+                "entity_sharded": any(i["entity_sharded"] for i in re_infos),
+                "axis_size": max(
+                    [i["axis_size"] for i in re_infos], default=1
+                ),
+                "rows_per_shard": {
+                    cid: i["rows_per_shard"] for cid, i in sharding_infos.items()
+                },
+                "collective_bytes_per_sweep": sum(
+                    i["collective_bytes_per_sweep"] for i in re_infos
+                ),
+                # Actually moved across the whole fit (every accepted sweep of
+                # every configuration) — 0 on the replicated path.
+                "collective_bytes_total": int(collective_bytes),
+            }
         return results
 
     # -------------------------------------------------------------- sweeps

@@ -93,6 +93,35 @@ _PACK_UNDECIDED = object()
 _RE_JIT_CACHE: dict = {}
 
 
+@dataclasses.dataclass(frozen=True)
+class RandomEffectSolveStats:
+    """What one random-effect sweep's solves did. `iterations` and
+    `fn_evals` (value+gradient evaluations, line-search trials included)
+    are summed over every entity; `buckets` has one record per bucket
+    shape (`capacity`, `entities`, `buckets`, `iterations`, `fn_evals`).
+    `per_entity` keeps each dispatch's (bucket indices, iterations,
+    fn_evals) with the arrays still on the device:
+    `RandomEffectCoordinate.entity_counts` fetches them when a caller
+    asks which entity took how many."""
+
+    buckets: List[dict]
+    iterations: int
+    fn_evals: int
+    # Not compared: a scan sweep and the per-bucket loop count the same
+    # solves in dispatches of different shapes.
+    per_entity: List[tuple] = dataclasses.field(compare=False, repr=False)
+
+
+@jax.jit
+def _solve_totals(counts):
+    """[(iterations, fn_evals)] per dispatch -> one (dispatches, 2) array of
+    their sums: the sweep's solve record in a single program, so reading it
+    is a single fetch."""
+    return jnp.stack(
+        [jnp.stack([jnp.sum(its), jnp.sum(evals)]) for its, evals in counts]
+    )
+
+
 def _config_with_traced_weight(
     config: CoordinateOptimizationConfig, reg_weight: Array
 ) -> CoordinateOptimizationConfig:
@@ -281,14 +310,15 @@ class FixedEffectCoordinate:
                     negatives_only=down_sampler_for_task(task),
                 )
             data = LabeledData(features, labels, offsets, weights)
-            res = problem.solve(
-                loss,
-                data,
-                _config_with_traced_weight(cfg, reg_weight),
-                w0,
-                norm,
-                use_pallas=use_pallas,
-            )
+            with jax.named_scope("fe_solve"):
+                res = problem.solve(
+                    loss,
+                    data,
+                    _config_with_traced_weight(cfg, reg_weight),
+                    w0,
+                    norm,
+                    use_pallas=use_pallas,
+                )
             return res
 
         def score_fn(features, w):
@@ -644,12 +674,14 @@ class RandomEffectCoordinate:
 
                         vv = jax.vmap(onev)(block, res.coefficients)
                     v = v.at[ent].set(vv)
-                return (m, v), res.iterations
+                return (m, v), (res.iterations, res.fn_evals)
 
-            (matrix, var_matrix), iters = jax.lax.scan(
-                step, (matrix, var_matrix), (gathers, masks, ents)
-            )
-            return matrix, var_matrix, iters
+            # One scope per bucket shape: (K, E, S) operands, capacity S.
+            with jax.named_scope(f"re_scan/{gathers.shape[2]}"):
+                (matrix, var_matrix), solved = jax.lax.scan(
+                    step, (matrix, var_matrix), (gathers, masks, ents)
+                )
+            return matrix, var_matrix, solved
 
         if scan_cache_key:
             _RE_JIT_CACHE[scan_cache_key] = train_scan
@@ -730,12 +762,13 @@ class RandomEffectCoordinate:
 
                     vv = jax.vmap(onev)(block, res.coefficients)
                     v = ring_scatter_rows(v, ent, vv, mesh)
-                return (m, v), res.iterations
+                return (m, v), (res.iterations, res.fn_evals)
 
-            (matrix, var_matrix), iters = jax.lax.scan(
-                step, (matrix, var_matrix), (gathers, masks, ents)
-            )
-            return matrix, var_matrix, iters
+            with jax.named_scope(f"re_scan/{gathers.shape[2]}"):
+                (matrix, var_matrix), solved = jax.lax.scan(
+                    step, (matrix, var_matrix), (gathers, masks, ents)
+                )
+            return matrix, var_matrix, solved
 
         if sh_cache_key:
             _RE_JIT_CACHE[sh_cache_key] = train_scan_sharded
@@ -747,7 +780,7 @@ class RandomEffectCoordinate:
         initial_model: Optional[RandomEffectModel] = None,
         *,
         reg_weight: Optional[float] = None,
-    ) -> Tuple[RandomEffectModel, dict]:
+    ) -> Tuple[RandomEffectModel, RandomEffectSolveStats]:
         """Train every entity bucket; returns the new coefficient matrix model.
 
         Per-entity warm start: gather previous rows (the reference's
@@ -799,8 +832,10 @@ class RandomEffectCoordinate:
         # coordinate-descent loop / estimator for the sharding artifact keys.
         self.last_train_collective_bytes = self.sweep_collective_bytes()
         # No host syncs inside the loop: bucket programs dispatch back-to-back
-        # and stats materialize once at the end.
-        bucket_iters: List = [None] * len(red.buckets)
+        # and stats materialize once at the end. One entry per dispatch:
+        # (bucket indices, per-entity iterations, per-entity fn_evals), the
+        # arrays (K, E) from a scan group and (E,) from a single bucket.
+        solved: List[tuple] = []
         if (
             red.buckets
             and sweep_scan_enabled()
@@ -826,7 +861,7 @@ class RandomEffectCoordinate:
                 for group in self._scan_group_list():
                     idxs = group[0]
                     try:
-                        matrix, var_matrix, iters = self._dispatch_scan_group(
+                        matrix, var_matrix, counts = self._dispatch_scan_group(
                             group, matrix, var_matrix, offsets, rw, wd, wd_ms
                         )
                     except BaseException as exc:  # noqa: BLE001 - gated below
@@ -846,21 +881,18 @@ class RandomEffectCoordinate:
                         )
                         with collective_faults_suppressed():
                             matrix, var_matrix = self._train_buckets(
-                                idxs, matrix, var_matrix, bucket_iters,
-                                offsets, rw,
+                                idxs, matrix, var_matrix, solved, offsets, rw
                             )
                         continue
-                    for k, bi in enumerate(idxs):
-                        bucket_iters[bi] = iters[k]
+                    solved.append((list(idxs), *counts))
             finally:
                 if wd is not None:
                     wd.close()
-            return self._finish_train(matrix, var_matrix, bucket_iters)
+            return self._finish_train(matrix, var_matrix, solved)
         matrix, var_matrix = self._train_buckets(
-            range(len(red.buckets)), matrix, var_matrix, bucket_iters,
-            offsets, rw,
+            range(len(red.buckets)), matrix, var_matrix, solved, offsets, rw
         )
-        return self._finish_train(matrix, var_matrix, bucket_iters)
+        return self._finish_train(matrix, var_matrix, solved)
 
     def _dispatch_scan_group(
         self, group, matrix, var_matrix, offsets, rw, wd, wd_ms
@@ -881,7 +913,7 @@ class RandomEffectCoordinate:
 
         def run():
             if mesh is not None:
-                m, v, iters = self._train_scan_sharded(
+                m, v, counts = self._train_scan_sharded(
                     ds.shards[red.feature_shard], ds.labels, ds.weights,
                     offsets, matrix, var_matrix, gathers, masks, ents,
                     red.feature_mask, rw,
@@ -890,14 +922,14 @@ class RandomEffectCoordinate:
                 norm_f = norm_s = None
                 if self._per_entity_norm:
                     norm_f, norm_s = self.norm.factors, self.norm.shifts
-                m, v, iters = self._train_scan(
+                m, v, counts = self._train_scan(
                     ds.shards[red.feature_shard], ds.labels, ds.weights,
                     offsets, matrix, var_matrix, gathers, masks, ents,
                     red.feature_mask, norm_f, norm_s, rw,
                 )
             if wd is not None:
                 jax.block_until_ready(m)
-            return m, v, iters
+            return m, v, counts
 
         def attempt():
             if mesh is not None:
@@ -915,7 +947,7 @@ class RandomEffectCoordinate:
         )
 
     def _train_buckets(
-        self, bucket_indices, matrix, var_matrix, bucket_iters, offsets, rw
+        self, bucket_indices, matrix, var_matrix, solved, offsets, rw
     ):
         """The per-bucket dispatch loop over `bucket_indices` — the default
         path with the scan sweep off, and the degraded fallback tier for a
@@ -961,7 +993,7 @@ class RandomEffectCoordinate:
                     )
                 else:
                     var_matrix = var_matrix.at[blocks.entity_rows].set(v)
-            bucket_iters[bi] = res.iterations
+            solved.append(([bi], res.iterations, res.fn_evals))
         return matrix, var_matrix
 
     def _scan_group_list(self):
@@ -1096,20 +1128,39 @@ class RandomEffectCoordinate:
             "collective_bytes_per_sweep": self.sweep_collective_bytes(),
         }
 
-    def _finish_train(self, matrix, var_matrix, bucket_iters):
+    def _finish_train(self, matrix, var_matrix, solved):
+        """The trained model and the sweep's solve record. ONE fetch,
+        whatever the bucket count: every dispatch's totals are reduced on
+        the device in one program and read back together."""
         red = self.re_dataset
         e_total = red.num_entities
-        stats = {
-            "buckets": [
+        totals = (
+            jax.device_get(_solve_totals([(its, ev) for _, its, ev in solved]))
+            if solved
+            else np.zeros((0, 2), np.int32)
+        )
+        by_shape: dict = {}
+        for (idxs, _, _), (iterations, fn_evals) in zip(solved, totals):
+            b = red.buckets[idxs[0]]
+            rec = by_shape.setdefault(
+                (b.capacity, b.num_entities),
                 dict(
                     capacity=b.capacity,
                     entities=b.num_entities,
-                    mean_iterations=float(jnp.mean(its)),
-                )
-                for b, its in zip(red.buckets, bucket_iters)
-            ],
-            "total_iterations": int(sum(int(jnp.sum(its)) for its in bucket_iters)),
-        }
+                    buckets=0,
+                    iterations=0,
+                    fn_evals=0,
+                ),
+            )
+            rec["buckets"] += len(idxs)
+            rec["iterations"] += int(iterations)
+            rec["fn_evals"] += int(fn_evals)
+        stats = RandomEffectSolveStats(
+            buckets=list(by_shape.values()),
+            iterations=int(totals[:, 0].sum()),
+            fn_evals=int(totals[:, 1].sum()),
+            per_entity=solved,
+        )
         # Keep the unseen-entity row pinned to zero — in BOTH matrices:
         # dummy-padded chunk entities (build_random_effect_dataset block
         # splitting) scatter their inert solves into this row.
@@ -1123,6 +1174,22 @@ class RandomEffectCoordinate:
             n_entities=e_total if matrix.shape[0] != e_total + 1 else None,
         )
         return model, stats
+
+    def entity_counts(
+        self, stats: RandomEffectSolveStats
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """(iterations, fn_evals) of one `train` call by coefficient-matrix
+        row, from its stats' `per_entity` arrays (one fetch). Row
+        `num_entities` sums the inert solves of dummy-padded block slots."""
+        red = self.re_dataset
+        out = np.zeros((2, red.num_entities + 1), np.int64)
+        for idxs, its, evals in jax.device_get(stats.per_entity):
+            rows = np.concatenate(
+                [np.asarray(red.buckets[bi].entity_rows) for bi in idxs]
+            )
+            np.add.at(out[0], rows, np.reshape(its, -1))
+            np.add.at(out[1], rows, np.reshape(evals, -1))
+        return out[0], out[1]
 
     # -- stacked-trial hooks (hyperparameter/sweep.py) ----------------------
 
@@ -1146,7 +1213,7 @@ class RandomEffectCoordinate:
             norm_f = norm_s = None
             if self._per_entity_norm:
                 norm_f, norm_s = self.norm.factors, self.norm.shifts
-            matrix, var_matrix, _iters = self._train_scan(
+            matrix, var_matrix, _counts = self._train_scan(
                 ds.shards[red.feature_shard], ds.labels, ds.weights, offsets,
                 matrix, var_matrix, gathers, masks, ents, red.feature_mask,
                 norm_f, norm_s, reg_weight,

@@ -23,7 +23,6 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-import time
 from typing import Dict, List, Mapping, Optional, Sequence, Set, Tuple
 
 import jax
@@ -32,7 +31,7 @@ import jax.numpy as jnp
 from photon_ml_tpu.evaluation.suite import EvaluationResults, EvaluationSuite
 from photon_ml_tpu.game.model import GameModel
 from photon_ml_tpu.utils import faults, telemetry
-from photon_ml_tpu.utils.observability import record_stage
+from photon_ml_tpu.utils.observability import record_stage, stage_timer
 
 logger = logging.getLogger(__name__)
 
@@ -165,6 +164,20 @@ def _recover_from_mesh_loss(
     return models, best_models, snap_best_res, snap_pass, completed_steps, "memory"
 
 
+def _fetch_verdict(ok, stats):
+    """(finite, fn_evals) of one attempted update, with the ONE
+    device-to-host fetch the divergence guard always made: the fixed
+    effect's `OptResult.fn_evals` is a device scalar, ready since its solve
+    ended, and rides along with the guard's boolean. A random effect's
+    stats hold the count on the host already (`_finish_train`'s one
+    fetch). None where the coordinate's train reports no count."""
+    evals = getattr(stats, "fn_evals", None)
+    if isinstance(evals, jax.Array):
+        finite, evals = jax.device_get((ok, evals))
+        return bool(finite), int(evals)
+    return bool(ok), evals
+
+
 def _update_all_finite(model, scores) -> bool:
     """ONE scalar all-finite check over a coordinate update (new model +
     new scores): the and-reduction builds device-side, so the guard costs a
@@ -185,6 +198,11 @@ class CoordinateDescentResult:
     # out of the seconds-valued `timing` dict so per-coordinate timing
     # artifacts stay pure wall clock). 0 on a clean run.
     diverged_steps: int = 0
+    # Objective evaluations per coordinate, as its optimizer counted them
+    # (every attempt of every update; line-search trials and
+    # Hessian-vector products included). A coordinate whose train reports
+    # none is absent.
+    fn_evals: Dict[str, int] = dataclasses.field(default_factory=dict)
     # Analytic wire bytes moved through entity-shard ring collectives by
     # the accepted coordinate updates (RandomEffectCoordinate.train sets
     # last_train_collective_bytes per sweep; 0 on the replicated path) —
@@ -232,8 +250,9 @@ def run_coordinate_descent(
     changes only when uploads happen, never their content.
 
     `on_event(etype, **fields)` is the lifecycle hook (ISSUE 11): called
-    with ("coordinate", iteration/coordinate/seconds/accepted) after every
-    update and ("checkpoint", step/coordinate) after every durable save —
+    with ("coordinate", iteration/coordinate/seconds/accepted/fn_evals)
+    after every update and ("checkpoint", step/coordinate) after every
+    durable save —
     the estimator forwards these as typed bus events into the run journal.
 
     `checkpoint_dir` enables checkpoint-restart of the outer loop (SURVEY
@@ -287,6 +306,7 @@ def run_coordinate_descent(
 
     models: Dict[str, object] = dict(initial_models.models) if initial_models else {}
     timing: Dict[str, float] = {}
+    fn_evals: Dict[str, int] = {}
     diverged_steps = 0
     collective_bytes = 0
     validation_history: List[Tuple[int, str, EvaluationResults]] = []
@@ -477,31 +497,6 @@ def run_coordinate_descent(
             if step < completed_steps:
                 continue  # fast-forward past checkpointed updates
             coord = coordinates[cid]
-            t0 = time.perf_counter()
-            _prefetch_after(step)
-            residual, offsets = _residual_offsets(
-                summed, scores.get(cid, _score_zeros(n, dtype, base_offsets)), base_offsets
-            )
-            kwargs = {}
-            if reg_weights and cid in reg_weights:
-                kwargs["reg_weight"] = reg_weights[cid]
-            if getattr(coord.config, "down_sampling_rate", 1.0) < 1.0:
-                # Fresh subsample per optimize call, as in the reference's
-                # runWithSampling (DistributedOptimizationProblem.scala:144).
-                kwargs["key"] = jax.random.fold_in(root_key, step)
-
-            # Mesh-loss fault site (ISSUE 13): one invocation per
-            # coordinate update. An armed plan simulates part of the mesh
-            # dying mid-update — converted to the typed MeshLoss the
-            # sweep-boundary handler below recovers from.
-            try:
-                faults.fault_point("mesh_loss")
-            except faults.InjectedFault as exc:
-                raise faults.MeshLoss(
-                    f"injected mesh loss at iteration {it} "
-                    f"coordinate {cid!r}"
-                ) from exc
-
             # Divergence guard: an update whose new model or scores carry a
             # non-finite value is REJECTED — committing it would poison every
             # later coordinate's residual this run AND, via the checkpoint,
@@ -513,11 +508,41 @@ def run_coordinate_descent(
             model = None
             new_scores = None
             new_summed = None
-            # One trace span per coordinate update (utils/telemetry.py):
-            # the solver's wall structure in Perfetto, no-op untraced.
-            with telemetry.span(
+            update_evals: Optional[int] = None
+            # One stage per coordinate update, its wall the update's
+            # `timing` entry; the cd/* stages inside it are declared in
+            # contracts.SOLVE_STAGES (which are dispatch walls, which wait).
+            with stage_timer(
                 "coordinate_update", coordinate=cid, iteration=it
-            ) as _span:
+            ) as update:
+                with stage_timer("cd/residual"):
+                    _prefetch_after(step)
+                    residual, offsets = _residual_offsets(
+                        summed,
+                        scores.get(cid, _score_zeros(n, dtype, base_offsets)),
+                        base_offsets,
+                    )
+                kwargs = {}
+                if reg_weights and cid in reg_weights:
+                    kwargs["reg_weight"] = reg_weights[cid]
+                if getattr(coord.config, "down_sampling_rate", 1.0) < 1.0:
+                    # Fresh subsample per optimize call, as in the reference's
+                    # runWithSampling
+                    # (DistributedOptimizationProblem.scala:144).
+                    kwargs["key"] = jax.random.fold_in(root_key, step)
+
+                # Mesh-loss fault site (ISSUE 13): one invocation per
+                # coordinate update. An armed plan simulates part of the
+                # mesh dying mid-update — converted to the typed MeshLoss
+                # the sweep-boundary handler below recovers from.
+                try:
+                    faults.fault_point("mesh_loss")
+                except faults.InjectedFault as exc:
+                    raise faults.MeshLoss(
+                        f"injected mesh loss at iteration {it} "
+                        f"coordinate {cid!r}"
+                    ) from exc
+
                 for attempt in range(1 + faults.solve_retry_attempts()):
                     try:
                         faults.fault_point("solve")
@@ -530,19 +555,25 @@ def run_coordinate_descent(
                         finite = False
                     else:
                         try:
-                            cand_model, _stats = coord.train(
-                                offsets, models.get(cid), **kwargs
-                            )
-                            cand_scores = coord.score(cand_model)
-                            # One fused program: the next summed-scores
-                            # vector and the divergence guard's reduction;
-                            # one bool fetch.
-                            cand_summed, ok = _commit_update(
-                                residual,
-                                cand_scores,
-                                _model_arrays(cand_model, cand_scores),
-                            )
-                            finite = bool(ok)
+                            with stage_timer("cd/train"):
+                                cand_model, stats = coord.train(
+                                    offsets, models.get(cid), **kwargs
+                                )
+                            with stage_timer("cd/score"):
+                                cand_scores = coord.score(cand_model)
+                            with stage_timer("cd/commit"):
+                                # One fused program: the next summed-scores
+                                # vector and the divergence guard's
+                                # reduction; one fetch, the solve's
+                                # evaluation count riding along.
+                                cand_summed, ok = _commit_update(
+                                    residual,
+                                    cand_scores,
+                                    _model_arrays(cand_model, cand_scores),
+                                )
+                                finite, evals = _fetch_verdict(ok, stats)
+                            if evals is not None:
+                                update_evals = (update_evals or 0) + evals
                         except faults.MeshLoss:
                             raise
                         except BaseException as exc:
@@ -576,36 +607,44 @@ def run_coordinate_descent(
                         cid,
                         attempt + 1,
                     )
-                _span.set(accepted=model is not None)
-            accepted = model is not None
-            if accepted:
-                summed = new_summed
-                scores[cid] = new_scores
-                models[cid] = model
-                collective_bytes += int(
-                    getattr(coord, "last_train_collective_bytes", 0)
-                )
-            else:
-                logger.error(
-                    "iteration %d coordinate %s diverged on every attempt — "
-                    "keeping its last-good model; the rejected update is not "
-                    "checkpointed",
-                    it,
-                    cid,
-                )
-            timing[f"{cid}/iter{it}"] = time.perf_counter() - t0
-            telemetry.METRICS.observe(
-                "coordinate_update_s", timing[f"{cid}/iter{it}"]
-            )
+                accepted = model is not None
+                update.set(accepted=accepted, fn_evals=update_evals)
+                if accepted:
+                    summed = new_summed
+                    scores[cid] = new_scores
+                    models[cid] = model
+                    collective_bytes += int(
+                        getattr(coord, "last_train_collective_bytes", 0)
+                    )
+                else:
+                    logger.error(
+                        "iteration %d coordinate %s diverged on every "
+                        "attempt — keeping its last-good model; the "
+                        "rejected update is not checkpointed",
+                        it,
+                        cid,
+                    )
+            if update_evals is not None:
+                fn_evals[cid] = fn_evals.get(cid, 0) + update_evals
+            timing[f"{cid}/iter{it}"] = update.seconds
+            telemetry.METRICS.observe("coordinate_update_s", update.seconds)
             if on_event is not None:
                 on_event(
                     "coordinate",
                     iteration=it,
                     coordinate=cid,
-                    seconds=timing[f"{cid}/iter{it}"],
+                    seconds=update.seconds,
                     accepted=accepted,
+                    fn_evals=update_evals,
                 )
-            logger.info("iteration %d coordinate %s trained in %.3fs", it, cid, timing[f"{cid}/iter{it}"])
+            logger.info(
+                "iteration %d coordinate %s trained in %.3fs (%s objective "
+                "evaluations)",
+                it,
+                cid,
+                update.seconds,
+                "uncounted" if update_evals is None else update_evals,
+            )
 
             # Overlap the step's durable model write with the validation
             # evaluation below (EvaluationSuite's device round trip): the
@@ -622,18 +661,21 @@ def run_coordinate_descent(
                 and validation_scorer is not None
                 and validation_suite is not None
             ):
-                staged_write = ckpt.begin_model_write(
-                    completed_steps=step + 1, cid=cid, model=model
-                )
+                with stage_timer("cd/checkpoint"):
+                    staged_write = ckpt.begin_model_write(
+                        completed_steps=step + 1, cid=cid, model=model
+                    )
 
             if accepted and validation_scorer is not None and validation_suite is not None:
-                val_scores[cid] = validation_scorer(cid, model)
-                # Seed with the validation offsets so selection uses the same
-                # score definition as the final reported evaluation.
-                total = validation_offsets
-                for s in val_scores.values():
-                    total = s if total is None else total + s
-                results = validation_suite.evaluate(total)
+                with stage_timer("cd/validation_score"):
+                    val_scores[cid] = validation_scorer(cid, model)
+                    # Seed with the validation offsets so selection uses the
+                    # same score definition as the final reported evaluation.
+                    total = validation_offsets
+                    for s in val_scores.values():
+                        total = s if total is None else total + s
+                with stage_timer("cd/validation_evaluate"):
+                    results = validation_suite.evaluate(total)
                 validation_history.append((it, cid, results))
                 logger.info("validation after %s: %s", cid, results.results)
                 pass_results = results
@@ -653,17 +695,18 @@ def run_coordinate_descent(
                 # still advances (resume replays from the same (seed, step)
                 # keys), but the non-finite model is NEVER written — the
                 # durable state keeps the last-good model.
-                ckpt.save(
-                    completed_steps=step + 1,
-                    seed=seed,
-                    config_key=ckpt_config_key,
-                    models=models,
-                    trained_cid=cid if accepted else None,
-                    best_is_current=best_updated,
-                    best_results=best_results,
-                    validation_history=validation_history,
-                    staged=staged_write,
-                )
+                with stage_timer("cd/checkpoint"):
+                    ckpt.save(
+                        completed_steps=step + 1,
+                        seed=seed,
+                        config_key=ckpt_config_key,
+                        models=models,
+                        trained_cid=cid if accepted else None,
+                        best_is_current=best_updated,
+                        best_results=best_results,
+                        validation_history=validation_history,
+                        staged=staged_write,
+                    )
                 if on_event is not None:
                     on_event("checkpoint", step=step + 1, coordinate=cid)
             elif staged_write is not None:  # pragma: no cover - ckpt is set
@@ -770,6 +813,7 @@ def run_coordinate_descent(
         validation_history=validation_history,
         timing=timing,
         diverged_steps=diverged_steps,
+        fn_evals=fn_evals,
         collective_bytes=collective_bytes,
         mesh_losses=mesh_losses,
         repeated_sweeps=repeated_sweeps,

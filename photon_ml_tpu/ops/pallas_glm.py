@@ -599,6 +599,10 @@ def value_gradient_sums(
     row_spec = pl.BlockSpec((tile, 1), lambda i: (i, 0), memory_space=_VMEM)
     stats, grad = pl.pallas_call(
         kernel,
+        # What a device trace calls this operation, said here and not left
+        # to what JAX infers from the enclosing function: the benchmark's
+        # readers match it (benchmarks/layers/kernels.py).
+        name="value_gradient_sums",
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile, d), lambda i: (i, 0), memory_space=_VMEM),
@@ -664,6 +668,7 @@ def hessian_vector_sums(
     row_spec = pl.BlockSpec((tile, 1), lambda i: (i, 0), memory_space=_VMEM)
     stats, hv = pl.pallas_call(
         kernel,
+        name="hessian_vector_sums",
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile, d), lambda i: (i, 0), memory_space=_VMEM),

@@ -50,7 +50,15 @@ def solve(
     Hessian-vector products.
     """
     l2 = config.l2_weight
-    vg = lambda w: objective.value_and_gradient(loss, w, data, norm, l2, use_pallas)
+
+    # Scopes name the objective's operations in a device trace (metadata
+    # only): under `fe_solve` or `re_scan/<capacity>`, whichever called.
+    def vg(w):
+        with jax.named_scope("objective"):
+            return objective.value_and_gradient(
+                loss, w, data, norm, l2, use_pallas
+            )
+
     opt = config.optimizer
     ot = opt.optimizer_type
 
@@ -60,9 +68,12 @@ def solve(
                 f"{loss.name} has no Hessian; TRON requires TwiceDiffFunction "
                 "(reference restricts smoothed hinge to LBFGS)"
             )
-        hvp = lambda w, v: objective.hessian_vector(
-            loss, w, v, data, norm, l2, use_pallas
-        )
+        def hvp(w, v):
+            with jax.named_scope("hessian_vector"):
+                return objective.hessian_vector(
+                    loss, w, v, data, norm, l2, use_pallas
+                )
+
         return minimize_tron(
             vg, hvp, w0, max_iterations=opt.max_iterations, tolerance=opt.tolerance
         )

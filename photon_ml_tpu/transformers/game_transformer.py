@@ -190,7 +190,10 @@ def prepare_coordinate_data(
 
 @jax.jit
 def _re_margins(features: Features, entity_rows: Array, matrix: Array, norm) -> Array:
-    return random_effect_margins(features, entity_rows, matrix, norm)
+    # The scope names these operations in a device trace; training and
+    # validation rows run the same code as programs of different shapes.
+    with jax.named_scope("score/random"):
+        return random_effect_margins(features, entity_rows, matrix, norm)
 
 
 def _entity_sharded_mesh(matrix):
@@ -231,11 +234,14 @@ def _fe_margins(features: Features, w: Array, norm) -> Array:
     # (n_rows, dim) via .shape, and compute_margins handles each. Dense
     # matrices take the row-stable path (see `dense_margins`); the sparse
     # layouts' gather + per-row-K reductions are already batch-invariant.
-    if isinstance(features, (jax.Array, np.ndarray)):
-        return dense_margins(features, w, norm)
-    n = features.shape[0]
-    zeros = jnp.zeros((n,), w.dtype)
-    return objective.compute_margins(w, LabeledData(features, zeros, zeros, zeros), norm)
+    with jax.named_scope("score/fixed"):
+        if isinstance(features, (jax.Array, np.ndarray)):
+            return dense_margins(features, w, norm)
+        n = features.shape[0]
+        zeros = jnp.zeros((n,), w.dtype)
+        return objective.compute_margins(
+            w, LabeledData(features, zeros, zeros, zeros), norm
+        )
 
 
 def coordinate_margins(

@@ -27,6 +27,52 @@ from __future__ import annotations
 # the work happened.
 PREPARE_STAGES = ("re_build", "projector", "stats", "pack", "upload", "compile")
 
+# Every boundary of a fit (ISSUE 27), each a `stage_timer` of this name where
+# the work happens: a wall in the fit's registry (`fit_timing["stages_s"]`,
+# histogram `fit_stage_s{stage=<name>}`), a telemetry span, and a
+# `photon/<name>` annotation on the profiler's clock. Nesting is
+# SOLVE_STAGE_PARENT; siblings tile their parent up to the glue between them.
+# Dispatch is asynchronous: `cd/residual`, `cd/train`, `cd/score` and
+# `cd/validation_score` are DISPATCH walls (the host handing programs to the
+# device; a random-effect `cd/train` also waits once, for its solves'
+# counts), and the host WAITS for the device in `cd/commit` (the divergence
+# guard's fetch), in `cd/validation_evaluate` and in `fit/final_evaluate`
+# (the metrics' fetch). A stage that did not run in a fit reads 0.0.
+SOLVE_STAGES = (
+    "fit",
+    "fit/revalidate",
+    "fit/validation_prep",
+    "fit/coordinates",
+    "fit/descent",
+    "coordinate_update",
+    "cd/residual",
+    "cd/train",
+    "cd/score",
+    "cd/commit",
+    "cd/validation_score",
+    "cd/validation_evaluate",
+    "cd/checkpoint",
+    "fit/final_evaluate",
+    "fit/publish",
+)
+SOLVE_STAGE_PARENT = {
+    "fit": None,
+    "fit/revalidate": "fit",
+    "fit/validation_prep": "fit",
+    "fit/coordinates": "fit",
+    "fit/descent": "fit",
+    "coordinate_update": "fit/descent",
+    "cd/residual": "coordinate_update",
+    "cd/train": "coordinate_update",
+    "cd/score": "coordinate_update",
+    "cd/commit": "coordinate_update",
+    "cd/validation_score": "fit/descent",
+    "cd/validation_evaluate": "fit/descent",
+    "cd/checkpoint": "fit/descent",
+    "fit/final_evaluate": "fit",
+    "fit/publish": "fit",
+}
+
 # Every key a fit_timing artifact must carry: the stage breakdown plus the
 # residual, the top-level walls, the pack placement split (r06), the
 # entity-sharding decision (r07) and the RE-assembly placement split (r09
@@ -50,6 +96,11 @@ FIT_TIMING_REQUIRED_KEYS = (
     # present; {"active": False, ...} on an unplanned fit so a missing
     # block is loud, never ambiguous with "planner off".
     "plan",
+    # ISSUE 27: this fit's wall per SOLVE_STAGES name, and its objective
+    # evaluations per coordinate (line-search trials and Hessian-vector
+    # products included), counted by the optimizers themselves.
+    "stages_s",
+    "fn_evals",
 )
 
 # ------------------------------------------------------------------- ingest
@@ -603,7 +654,8 @@ JOURNAL_EVENT_SCHEMAS = {
     "setup": ("args",),
     "fit_start": ("num_samples",),
     "sweep_config": ("index", "total"),
-    "coordinate_update": ("iteration", "coordinate", "seconds", "accepted"),
+    "coordinate_update": ("iteration", "coordinate", "seconds", "accepted",
+                          "fn_evals"),
     "checkpoint": ("step", "coordinate"),
     "fit_finish": ("num_configs", "best_metric"),
     "failure": ("error",),
@@ -717,6 +769,7 @@ PLANNER_SECTION_KEYS = (
 # for tests that want to iterate all contracts.
 ALL_CONTRACTS = {
     "PREPARE_STAGES": PREPARE_STAGES,
+    "SOLVE_STAGES": SOLVE_STAGES,
     "FIT_TIMING_REQUIRED_KEYS": FIT_TIMING_REQUIRED_KEYS,
     "INGEST_STAGES": INGEST_STAGES,
     "INGEST_TIMING_REQUIRED_KEYS": INGEST_TIMING_REQUIRED_KEYS,

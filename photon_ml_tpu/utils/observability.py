@@ -197,19 +197,51 @@ def set_stage_note(name: str, value: str) -> None:
         registry.set_note(name, value)
 
 
-@contextmanager
-def stage_timer(name: str):
-    """`with stage_timer("upload"):` — record the block's wall clock into
-    the ambient stage scope. Also opens a trace span of the same name
-    (utils/telemetry.py): the data-plane stages become Perfetto tracks
-    without a second instrumentation pass. Span + stage record are both
-    free no-ops when their ambient sinks are absent."""
-    t0 = time.perf_counter()
-    with telemetry.span(name):
-        try:
-            yield
-        finally:
-            record_stage(name, time.perf_counter() - t0)
+class stage_timer:
+    """`with stage_timer("upload"):` — the one boundary primitive, with
+    three sinks: the block's wall clock goes to the ambient stage scope
+    (`record_stage`), a trace span of the same name opens
+    (utils/telemetry.py: the stages become Perfetto tracks without a
+    second instrumentation pass), and a `photon/<name>` annotation lands
+    on the profiler's clock, beside the device operations of the same
+    `.xplane.pb`. Each is a free no-op when its sink is absent: no scope
+    open, no `Tracer` installed, no profiler session.
+
+    Keyword arguments are the span's; `set(**args)` adds to them
+    mid-flight. After the block `seconds` holds its wall, so a caller
+    that reports the wall itself reads the one the three sinks got."""
+
+    __slots__ = ("name", "seconds", "_span", "_annotation", "_t0")
+
+    def __init__(self, name: str, **args):
+        self.name = name
+        self.seconds = 0.0
+        self._span = telemetry.span(name, **args)
+        # A recorded span (it has an id) annotates itself; the shared
+        # no-op does not, so untraced the stage does.
+        self._annotation = (
+            telemetry.profiler_annotation(name)
+            if getattr(self._span, "span_id", None) is None
+            else None
+        )
+
+    def set(self, **args) -> None:
+        self._span.set(**args)
+
+    def __enter__(self) -> "stage_timer":
+        self._span.__enter__()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.seconds = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        self._span.__exit__(exc_type, exc, tb)
+        record_stage(self.name, self.seconds)
+        return False
 
 
 # -------------------------------------------------------------- PhotonLogger
@@ -314,12 +346,15 @@ class SweepConfigEvent(Event):
 @dataclasses.dataclass(frozen=True)
 class CoordinateUpdateEvent(Event):
     """One coordinate-descent update finished (accepted or rejected by
-    the divergence guard)."""
+    the divergence guard). `fn_evals`: the objective evaluations its
+    solves made, every attempt counted (a random effect's: summed over
+    its entities); None where the coordinate reports none."""
 
     iteration: int = 0
     coordinate: str = ""
     seconds: float = 0.0
     accepted: bool = True
+    fn_evals: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -378,6 +413,7 @@ def journal_listener(journal) -> Callable[[Event], None]:
                 coordinate=event.coordinate,
                 seconds=round(event.seconds, 6),
                 accepted=event.accepted,
+                fn_evals=event.fn_evals,
             )
         elif isinstance(event, CheckpointEvent):
             journal.emit("checkpoint", step=event.step, coordinate=event.coordinate)

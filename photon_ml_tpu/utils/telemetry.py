@@ -25,7 +25,11 @@ Four coordinated parts:
   Gated by the `PHOTON_TRACE` knob: with no tracer installed `span()`
   returns a shared no-op context manager — one global read, no
   allocation — so library code instruments unconditionally (the same
-  near-zero-overhead discipline as `record_stage`).
+  near-zero-overhead discipline as `record_stage`). Every recorded span
+  also enters a `photon/<name>` `jax.profiler.TraceAnnotation`
+  (`profiler_annotation`), so under a profiler session the program's
+  spans sit on the host plane of the `.xplane.pb`, on the device
+  operations' clock.
 
 * **Metrics** — typed Counter/Gauge/Histogram behind one registry
   (`METRICS`). Histograms use FIXED log-spaced bucket bounds
@@ -174,11 +178,20 @@ METRIC_DESCRIPTIONS = {
     "persistent compilation cache",
     "compile_cache_hits": "compile requests answered from the persistent "
     "compilation cache instead of compiling",
+    # Objective evaluations as the optimizers count them (OptResult.fn_evals:
+    # value+gradient evaluations, line-search trials and TRON's
+    # Hessian-vector products), added once a fit, labeled
+    # coordinate=<id>,kind=fixed|random.
+    "objective_evaluations": "objective evaluations the optimizers made, "
+    "per coordinate (labeled coordinate=<id>,kind=fixed|random)",
     # -- histograms (fixed log-spaced buckets, mergeable) --
     "serving_latency_ms": "per-request wall latency through the batcher",
     "serving_queue_wait_ms": "submit-to-claim queue wait per request",
     "serving_batch_size": "requests per dispatched micro-batch",
     "coordinate_update_s": "wall seconds per coordinate-descent update",
+    "fit_stage_s": "wall seconds per fit of each contracts.SOLVE_STAGES "
+    "stage (labeled stage=<name>); the unlabeled aggregate mixes stages "
+    "and means nothing",
     "shadow_score_drift": "per-request |champion - challenger| mean-score "
     "drift observed at window evaluation",
     "shadow_calibration_champion": "per-request |champion mean - label| "
@@ -609,6 +622,25 @@ def trace_from_env() -> bool:
     return bool(get_knob("PHOTON_TRACE"))
 
 
+# What tells the program's spans from a driver's own in a profiler trace.
+PROFILER_PREFIX = "photon/"
+_TRACE_ANNOTATION = None
+
+
+def profiler_annotation(name: str):
+    """A `jax.profiler.TraceAnnotation` named `photon/<name>`: a host span
+    on the profiler's clock, the one the device operations are stamped on.
+    A TraceMe is a no-op outside a profiler session, so callers enter it
+    unconditionally. jax is imported on first use (this module stays
+    stdlib-only at import)."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ANNOTATION = TraceAnnotation
+    return _TRACE_ANNOTATION(PROFILER_PREFIX + name)
+
+
 class _NullSpan:
     """Shared no-op context manager: the entire cost of an un-traced
     `span()` call is one global read plus returning this singleton."""
@@ -631,7 +663,9 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """One open span: records a Chrome 'X' (complete) event on exit."""
 
-    __slots__ = ("tracer", "name", "args", "span_id", "parent_id", "_t0")
+    __slots__ = (
+        "tracer", "name", "args", "span_id", "parent_id", "_t0", "_annotation"
+    )
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, object]):
         self.tracer = tracer
@@ -640,6 +674,7 @@ class _Span:
         self.span_id = tracer._next_id()
         self.parent_id: Optional[int] = None
         self._t0 = 0
+        self._annotation = profiler_annotation(name)
 
     def set(self, **args) -> None:
         """Attach/overwrite span args mid-flight (e.g. outcome fields)."""
@@ -652,11 +687,13 @@ class _Span:
         elif getattr(self.tracer._tls, "adopted_parent", None) is not None:
             self.parent_id = self.tracer._tls.adopted_parent
         stack.append(self.span_id)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter_ns()
+        self._annotation.__exit__(exc_type, exc, tb)
         stack = self.tracer._stack()
         if stack and stack[-1] == self.span_id:
             stack.pop()
@@ -684,8 +721,11 @@ class Tracer:
         # Synthetic track ids, handed out when the OS reuses a dead
         # worker's thread ident (see _tid); offset far past real idents.
         self._synth_tids = itertools.count(1 << 48)
+        # One reading of both clocks, taken together: span timestamps are
+        # perf_counter_ns less `_t0_ns`, so the pair lays an exported trace
+        # beside anything stamped in Unix time (a profiler's .xplane.pb).
         self._t0_ns = time.perf_counter_ns()
-        self._wall_t0 = time.time()
+        self._wall_t0_ns = time.time_ns()
 
     # -- internals ----------------------------------------------------------
 
@@ -766,7 +806,11 @@ class Tracer:
             "displayTimeUnit": "ms",
             "otherData": {
                 "trace_id": self.trace_id,
-                "wall_t0_unix_s": self._wall_t0,
+                "wall_t0_unix_s": self._wall_t0_ns / 1e9,
+                "clock_anchor": {
+                    "perf_counter_ns": self._t0_ns,
+                    "time_ns": self._wall_t0_ns,
+                },
             },
         }
 

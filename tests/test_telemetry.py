@@ -426,6 +426,7 @@ class TestJournal:
         "coordinate": "per-e1",
         "seconds": 0.25,
         "accepted": True,
+        "fn_evals": 11,
         "step": 3,
         "num_configs": 2,
         "best_metric": 0.91,
