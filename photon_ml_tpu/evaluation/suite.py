@@ -18,8 +18,9 @@ the reference's LocalEvaluator-per-group loop becomes one batched kernel.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import re
-from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Callable, Dict, Mapping, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,7 @@ import numpy as np
 
 from photon_ml_tpu.evaluation import metrics
 from photon_ml_tpu.types import TaskType
+from photon_ml_tpu.utils import telemetry
 
 Array = jax.Array
 
@@ -131,12 +133,15 @@ def resolve_metric_fn(
 ) -> Callable:
     """The bare metric callable `(scores, labels, weights) -> device scalar`
     for one evaluator — PRECISION k-binding and grouped-gather wrapping
-    resolved HERE, the single dispatch point shared by offline
-    `EvaluationSuite.evaluate()`, the sweep executor's jitted
-    trial-valuation program (hyperparameter/sweep.py), and the online
-    `StreamingWindowEvaluator` (serving/shadow.py) — so one metric program
-    means the same thing in every world and a new evaluator variant cannot
-    drift between them."""
+    resolved HERE, the single dispatch point shared by `evaluate_metrics`
+    (the one compiled program behind offline `EvaluationSuite.evaluate()`
+    and the online `StreamingWindowEvaluator`, serving/shadow.py) and the
+    sweep executor's jitted trial-valuation program
+    (hyperparameter/sweep.py) — so one metric means the same thing in
+    every world and a new evaluator variant cannot drift between them.
+    The callable is plain traceable jax: called bare it dispatches one
+    device program per operation, so callers on a hot path call it
+    inside a `jax.jit`, as those three do."""
     if et.name == "PRECISION":
         base = lambda s, l, w, _k=et.k: metrics.precision_at_k(_k, s, l, w)
     else:
@@ -197,6 +202,57 @@ def _grouped_metric(
     return jnp.mean(per_group)
 
 
+@functools.partial(jax.jit, static_argnums=0)
+def evaluate_metrics(
+    evaluator_types: Tuple[EvaluatorType, ...],
+    scores: Array,
+    labels: Array,
+    weights: Array,
+    grouped: Mapping[str, GroupedIndex],
+) -> Array:
+    """Every evaluator's metric over one score vector, stacked float32 in
+    `evaluator_types` order: ONE compiled device program an evaluation.
+
+    Static in the evaluator tuple alone (`k` of PRECISION@k rides in it);
+    labels, weights and each id tag's gather are arguments, so the program
+    is found again by shapes and shardings whichever suite calls — a suite
+    built anew in every fit hits JAX's in-process cache from the second fit
+    on. This body runs only when JAX traces it, which is what
+    `evaluation_traces` counts.
+    """
+    telemetry.METRICS.increment("evaluation_traces")
+    return jnp.stack(
+        [
+            jnp.asarray(
+                resolve_metric_fn(et, grouped.get(et.id_tag))(
+                    scores, labels, weights
+                ),
+                jnp.float32,
+            )
+            for et in evaluator_types
+        ]
+    )
+
+
+def _evaluate(
+    evaluator_types: Sequence[EvaluatorType],
+    primary: EvaluatorType,
+    scores: Array,
+    labels: Array,
+    weights: Array,
+    grouped: Mapping[str, GroupedIndex],
+) -> "EvaluationResults":
+    """One `evaluate_metrics` program, one fetch, the results by name."""
+    telemetry.METRICS.increment("evaluation_calls")
+    fetched = np.asarray(
+        evaluate_metrics(tuple(evaluator_types), scores, labels, weights, grouped)
+    )
+    return EvaluationResults(
+        primary=primary,
+        results={str(et): float(v) for et, v in zip(evaluator_types, fetched)},
+    )
+
+
 class EvaluationSuite:
     """Holds validation (labels, offsets, weights) + evaluators; one `evaluate`
     call computes every metric for a score vector (EvaluationSuite.scala:33-56).
@@ -242,24 +298,17 @@ class EvaluationSuite:
         return resolve_metric_fn(et, self._grouped.get(et.id_tag))
 
     def evaluate(self, scores: Array) -> "EvaluationResults":
-        """Compute every metric, then fetch them in ONE device round trip.
-
-        Scores stay on device throughout: each metric dispatches its device
-        reduction and the scalars are stacked and pulled back together —
-        a per-metric float() blocks the host until that metric's program
-        has finished, so the evaluators' programs would run one after the
-        other instead of being queued back to back."""
-        names: List[str] = []
-        vals = []
-        for et in self.evaluator_types:
-            val = self.metric_fn(et)(scores, self.labels, self.weights)
-            names.append(str(et))
-            vals.append(jnp.asarray(val, jnp.float32))
-        fetched = np.asarray(jnp.stack(vals))
-        results: Dict[str, float] = {
-            name: float(v) for name, v in zip(names, fetched)
-        }
-        return EvaluationResults(primary=self.primary, results=results)
+        """Every metric of the suite: one compiled program
+        (`evaluate_metrics`) and one fetch. Scores stay on the device; the
+        host waits once, for the stacked vector."""
+        return _evaluate(
+            self.evaluator_types,
+            self.primary,
+            scores,
+            self.labels,
+            self.weights,
+            self._grouped,
+        )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -281,16 +330,15 @@ class EvaluationResults:
 
 
 class StreamingWindowEvaluator:
-    """Online windowed evaluation over the SAME metric programs as offline.
+    """Online windowed evaluation through the SAME program as offline.
 
     The shadow decision loop (serving/shadow.py, ISSUE 18) scores each
-    joined (scores, labels) window through the exact callables
-    `resolve_metric_fn` hands `EvaluationSuite.evaluate` — same jitted
-    reductions, same stack-then-fetch single device round trip — so an
-    online regression threshold means precisely what it means against an
-    offline validation set (the photon-lib validator gate taken online).
-    Unlike a suite, labels arrive WITH each window instead of being fixed
-    at construction.
+    joined (scores, labels) window through `evaluate_metrics`, the one
+    compiled program `EvaluationSuite.evaluate` runs — compiled once per
+    window size, one fetch a window — so an online regression threshold
+    means precisely what it means against an offline validation set (the
+    photon-lib validator gate taken online). Unlike a suite, labels
+    arrive WITH each window instead of being fixed at construction.
 
     Grouped evaluators (AUC:<idTag>, PRECISION@k:<idTag>) are refused:
     their gather matrices are built against one fixed validation sample
@@ -323,8 +371,8 @@ class StreamingWindowEvaluator:
         labels: Array,
         weights: Optional[Array] = None,
     ) -> "EvaluationResults":
-        """Every metric over one window, ONE device round trip — mirrors
-        `EvaluationSuite.evaluate` exactly (bitwise on identical arrays)."""
+        """Every metric over one window: the program and the fetch of
+        `EvaluationSuite.evaluate` (bitwise on identical arrays)."""
         labels = jnp.asarray(labels)
         if int(labels.shape[0]) == 0:
             raise ValueError(
@@ -333,14 +381,6 @@ class StreamingWindowEvaluator:
             )
         scores = jnp.asarray(scores)
         w = weights if weights is not None else jnp.ones_like(labels)
-        names: List[str] = []
-        vals = []
-        for et in self.evaluator_types:
-            val = resolve_metric_fn(et)(scores, labels, w)
-            names.append(str(et))
-            vals.append(jnp.asarray(val, jnp.float32))
-        fetched = np.asarray(jnp.stack(vals))
-        results: Dict[str, float] = {
-            name: float(v) for name, v in zip(names, fetched)
-        }
-        return EvaluationResults(primary=self.primary, results=results)
+        return _evaluate(
+            self.evaluator_types, self.primary, scores, labels, w, {}
+        )

@@ -37,7 +37,9 @@ PREPARE_STAGES = ("re_build", "projector", "stats", "pack", "upload", "compile")
 # device; a random-effect `cd/train` also waits once, for its solves'
 # counts), and the host WAITS for the device in `cd/commit` (the divergence
 # guard's fetch), in `cd/validation_evaluate` and in `fit/final_evaluate`
-# (the metrics' fetch). A stage that did not run in a fit reads 0.0.
+# (one evaluation program handed over, then the fetch of its metrics: the
+# wall is the device's scoring and evaluation work plus one round trip). A
+# stage that did not run in a fit reads 0.0.
 SOLVE_STAGES = (
     "fit",
     "fit/revalidate",

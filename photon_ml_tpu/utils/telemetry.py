@@ -184,6 +184,14 @@ METRIC_DESCRIPTIONS = {
     # coordinate=<id>,kind=fixed|random.
     "objective_evaluations": "objective evaluations the optimizers made, "
     "per coordinate (labeled coordinate=<id>,kind=fixed|random)",
+    # An evaluation is one compiled program and one fetch
+    # (evaluation/suite.evaluate_metrics): hit share = 1 - traces / calls.
+    "evaluation_calls": "evaluations made (EvaluationSuite.evaluate, "
+    "StreamingWindowEvaluator.evaluate_window): one device program and "
+    "one fetch each",
+    "evaluation_traces": "times JAX traced the evaluation program anew "
+    "(a new evaluator tuple, row count, dtype or sharding); 0 in a "
+    "steady refit loop",
     # -- histograms (fixed log-spaced buckets, mergeable) --
     "serving_latency_ms": "per-request wall latency through the batcher",
     "serving_queue_wait_ms": "submit-to-claim queue wait per request",

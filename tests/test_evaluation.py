@@ -6,15 +6,24 @@ import numpy as np
 import pytest
 from sklearn import metrics as skm
 
+from photon_ml_tpu.data.game_dataset import FixedEffectDataConfig, GameDataset
+from photon_ml_tpu.estimators.game_estimator import GameEstimator
 from photon_ml_tpu.evaluation import metrics
 from photon_ml_tpu.evaluation.suite import (
     EvaluationSuite,
     EvaluatorType,
+    StreamingWindowEvaluator,
     better_than,
     build_grouped_index,
     default_evaluator_for_task,
+    resolve_metric_fn,
+)
+from photon_ml_tpu.optimize.config import (
+    CoordinateOptimizationConfig,
+    OptimizerConfig,
 )
 from photon_ml_tpu.types import TaskType
+from photon_ml_tpu.utils import telemetry
 
 
 def test_auc_matches_sklearn(rng):
@@ -229,3 +238,134 @@ class TestLegacyMetrics:
         )
         assert m2[legacy.R_SQUARED] > 0.8
         assert legacy.PEAK_F1_SCORE not in m2
+
+
+# ---- one compiled program and one fetch an evaluation (ISSUE 28) ----
+
+_PARITY_SPECS = [
+    "AUC",
+    "AUPR",
+    "RMSE",
+    "LOGISTIC_LOSS",
+    "POISSON_LOSS",
+    "SQUARED_LOSS",
+    "SMOOTHED_HINGE_LOSS",
+    "AUC:q",
+    "PRECISION@3:q",
+]
+
+
+def _parity_arrays():
+    """Weighted rows with tied scores, a padding-weight row, and one group
+    (id 0) of a single class."""
+    rng = np.random.default_rng(28)
+    n = 240
+    gids = rng.integers(0, 9, size=n)
+    scores = np.round(rng.normal(size=n), 1).astype(np.float32)  # ties
+    labels = (rng.uniform(size=n) > 0.5).astype(np.float32)
+    labels[gids == 0] = 1.0
+    weights = rng.uniform(0.5, 2.0, size=n).astype(np.float32)
+    weights[3] = 0.0
+    return gids, jnp.asarray(scores), jnp.asarray(labels), jnp.asarray(weights)
+
+
+@pytest.mark.parametrize("spec", _PARITY_SPECS)
+def test_suite_evaluate_equals_bare_metric(spec):
+    """The compiled evaluation computes what the bare `resolve_metric_fn`
+    callable computes operation by operation."""
+    gids, scores, labels, weights = _parity_arrays()
+    et = EvaluatorType.parse(spec)
+    # The suite evaluates all of them in one program; each case reads its own.
+    suite = EvaluationSuite(
+        [EvaluatorType.parse(s) for s in _PARITY_SPECS],
+        labels,
+        weights,
+        id_tag_values={"q": gids},
+        primary=et,
+    )
+    got = suite.evaluate(scores)
+    assert list(got.results) == _PARITY_SPECS
+    grouped = build_grouped_index(gids) if et.is_grouped else None
+    bare = float(resolve_metric_fn(et, grouped)(scores, labels, weights))
+    assert np.isfinite(bare)
+    np.testing.assert_allclose(got.primary_value, bare, rtol=1e-6, atol=1e-6)
+
+
+def _evaluation_counts():
+    c = telemetry.METRICS.counters()
+    return c.get("evaluation_calls", 0), c.get("evaluation_traces", 0)
+
+
+def test_evaluation_program_is_keyed_on_shapes_not_on_the_suite(rng):
+    """Suites built afresh over arrays of one shape and one evaluator list
+    share ONE traced program; a new row count traces one more."""
+    ets = [EvaluatorType("AUC"), EvaluatorType.parse("PRECISION@2:q")]
+
+    def fresh_suite(n):
+        labels = (rng.uniform(size=n) > 0.5).astype(np.float32)
+        weights = rng.uniform(0.5, 2.0, size=n).astype(np.float32)
+        # Every group id appears exactly four times: one gather shape.
+        gids = rng.permutation(np.repeat(np.arange(n // 4), 4))
+        return EvaluationSuite(
+            ets, jnp.asarray(labels), jnp.asarray(weights), id_tag_values={"q": gids}
+        )
+
+    n = 52  # a row count no other test of this file evaluates with these ets
+    calls0, traces0 = _evaluation_counts()
+    for _ in range(3):
+        suite = fresh_suite(n)
+        for _ in range(2):
+            suite.evaluate(jnp.asarray(rng.normal(size=n).astype(np.float32)))
+    calls1, traces1 = _evaluation_counts()
+    assert (calls1 - calls0, traces1 - traces0) == (6, 1)
+
+    fresh_suite(n + 4).evaluate(
+        jnp.asarray(rng.normal(size=n + 4).astype(np.float32))
+    )
+    calls2, traces2 = _evaluation_counts()
+    assert (calls2 - calls1, traces2 - traces1) == (1, 1)
+
+    # The streaming evaluator goes through the same function: a window size
+    # traces once, however many evaluators are built.
+    plain = [EvaluatorType("AUC"), EvaluatorType("RMSE")]
+    for _ in range(3):
+        StreamingWindowEvaluator(plain).evaluate_window(
+            jnp.asarray(rng.normal(size=n).astype(np.float32)),
+            jnp.asarray((rng.uniform(size=n) > 0.5).astype(np.float32)),
+        )
+    calls3, traces3 = _evaluation_counts()
+    assert (calls3 - calls2, traces3 - traces2) == (3, 1)
+
+
+def test_second_fit_does_not_trace_the_evaluation_again(rng):
+    """`GameEstimator.fit` builds a new EvaluationSuite in every fit; the
+    second fit of a process must find the first one's program."""
+    n, n_val, d = 600, 150, 6
+    X = rng.normal(size=(n + n_val, d)).astype(np.float32)
+    y = (rng.uniform(size=n + n_val) > 0.5).astype(np.float32)
+    train = GameDataset.build({"g": X[:n]}, y[:n])
+    val = GameDataset.build({"g": X[n:]}, y[n:])
+    est = GameEstimator(
+        TaskType.LOGISTIC_REGRESSION,
+        {"global": FixedEffectDataConfig("g")},
+        validation_evaluators=[EvaluatorType("AUC")],
+        pipeline=False,
+    )
+    cfg = {
+        "global": CoordinateOptimizationConfig(
+            optimizer=OptimizerConfig(max_iterations=3)
+        )
+    }
+    calls0, traces0 = _evaluation_counts()
+    first = est.fit(train, val, [cfg])
+    calls1, traces1 = _evaluation_counts()
+    second = est.fit(train, val, [cfg])
+    calls2, traces2 = _evaluation_counts()
+    # One evaluation inside coordinate descent and the final one, each fit.
+    assert calls1 - calls0 == 2 and calls2 - calls1 == 2
+    # 0 where an earlier test of the process evaluated AUC over 150 rows.
+    assert traces1 - traces0 <= 1
+    assert traces2 - traces1 == 0
+    assert (
+        second[0].evaluation.primary_value == first[0].evaluation.primary_value
+    )
