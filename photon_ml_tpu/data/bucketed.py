@@ -83,6 +83,19 @@ L2_TILE_ROWS = 16384  # level-2 tile: pools 8 L1 tiles' spill, z-acc (128, 128)
 # iterations per segment, so wider segments would explode compile time.
 # Anything past the cap lands in the COO overflow.
 MAX_SP = 8192
+# ... and the floor: the kernels' (SP/128, 128) blocks need 8 sublanes, so no
+# segment is narrower than this however few entries it holds.
+MIN_SP = 1024
+
+
+def min_level1_slots(n_rows: int, dim: int) -> int:
+    """The fewest slots level 1 can hold for these shapes: one segment of at
+    least MIN_SP slots per (row tile, feature bucket), whatever the pattern
+    and whichever layout `choose_layout` picks. The floor under
+    `pad_blowup` that `ops/pallas_sparse.pack_can_pay` decides by."""
+    B = max(1, -(-dim // BUCKET))
+    T1 = max(1, -(-n_rows // L1_TILE_ROWS))
+    return T1 * B * MIN_SP
 
 
 @jax.tree_util.register_dataclass
@@ -487,7 +500,7 @@ def pack_bucketed(
     sp1 = (
         sp1_hint
         if sp1_hint is not None
-        else min(max(1024, _round_up(int(mean1), 1024)), MAX_SP)
+        else min(max(MIN_SP, _round_up(int(mean1), 1024)), MAX_SP)
     )
     level1, spill, pack_path = _pack_level(
         rows, cols, vals, n_rows, dim, L1_TILE_ROWS, sp1, dtype, host_only,

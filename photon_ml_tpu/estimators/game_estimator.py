@@ -705,7 +705,8 @@ class GameEstimator:
         # Stage WALLS are delta'd against stage_base instead; notes have
         # no delta, so they reset.
         self.timing_registry.clear_notes(
-            "pack_path", "re_path", "sparse_layout", "sparse_objective"
+            "pack_path", "re_path", "sparse_layout", "sparse_objective",
+            "pack_declined",
         )
         # Snapshot the pod-scale robustness counters so fit_timing reports
         # THIS fit's events (the process-wide counters are cumulative).
@@ -1198,12 +1199,17 @@ class GameEstimator:
             # sparse_layout rule adopts next run.
             "layout": self.timing_registry.get_note("sparse_layout")
             or "none",
-            # Which objective the packed sparse fixed effect runs:
-            # "pallas_fused" (one entry stream), "pallas_composed"
-            # (matvec + rmatvec kernels), or "none" (ELL through XLA).
+            # Which objective a sparse fixed effect runs: "pallas_fused"
+            # (one entry stream), "pallas_composed" (matvec + rmatvec
+            # kernels), "ell_xla" (the ELL planes through XLA's gather and
+            # scatter-add; `pack_declined` says why the bucketed pack was
+            # not made: too_small, dtype, sharded, pad_blowup), or "none"
+            # (no sparse fixed effect built its coordinate in this fit).
             "sparse_objective": self.timing_registry.get_note(
                 "sparse_objective"
             )
+            or "none",
+            "pack_declined": self.timing_registry.get_note("pack_declined")
             or "none",
         }
         bucket_shapes: Dict[str, object] = {}

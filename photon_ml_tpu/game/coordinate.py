@@ -224,7 +224,8 @@ class FixedEffectCoordinate:
         # (ops/pallas_sparse.py) instead of XLA gather/scatter — the sparse
         # counterpart of the dense fused-kernel decision above. maybe_pack
         # owns the whole decision (backend, dtype, sharding, size, padding
-        # economics) and returns None when the ELL/XLA path should stay.
+        # economics) and returns None when the ELL/XLA path should stay; it
+        # decides from the shard's shapes before it moves any data.
         self._features = feats
         if isinstance(feats, SparseFeatures):
             from photon_ml_tpu.ops import pallas_sparse
@@ -283,6 +284,11 @@ class FixedEffectCoordinate:
                 # caller's genuine escape hatch for shards where the pack was
                 # declined and the ELL/XLA composition is the right path.
                 self._use_pallas = None
+            else:
+                # The ELL objective through XLA's gather and scatter-add is a
+                # path of its own, named like the packed ones; why the pack
+                # was declined is beside it (`pack_declined`).
+                set_stage_note("sparse_objective", "ell_xla")
         if isinstance(self._features, SparseFeatures) and not isinstance(
             self._features.indices, jax.Array
         ):

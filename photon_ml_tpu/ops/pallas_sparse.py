@@ -114,9 +114,11 @@ _SMEM = pltpu.SMEM
 
 from photon_ml_tpu.data.bucketed import (
     BUCKET,
+    L1_TILE_ROWS,
     BucketedLevel,
     BucketedSparseFeatures,
     _ROW_SHIFT,
+    min_level1_slots,
 )
 from photon_ml_tpu.ops import pallas_glm
 from photon_ml_tpu.utils.knobs import get_knob
@@ -398,13 +400,78 @@ def kernels_eligible() -> bool:
     )
 
 
+# Fewer rows than this cannot amortize a pack.
+MIN_PACK_ROWS = 4 * L1_TILE_ROWS
+
+
 def pack_worth_considering(n_samples: int) -> bool:
     """The cheap engagement gates (backend + size) shared by the pack
     functions here AND by ingest's decision to stash host COO triplets —
     one predicate so the two can't drift apart."""
-    from photon_ml_tpu.data.bucketed import L1_TILE_ROWS
+    return n_samples >= MIN_PACK_ROWS and kernels_eligible()
 
-    return n_samples >= 4 * L1_TILE_ROWS and kernels_eligible()
+
+# Above this padding blowup the bucketed layout streams more bytes than the
+# padding-free ELL path saves — low-nnz data (sp floors at MIN_SP entries per
+# segment) stays on XLA.
+MAX_PAD_BLOWUP = 4.0
+
+
+def pack_can_pay(nnz: int, n_rows: int, dim: int) -> bool:
+    """Can a bucketed pack of these shapes stay under MAX_PAD_BLOWUP at all?
+
+    From the shapes alone, so that every pack entry point asks BEFORE any
+    device-to-host pull, COO expansion or allocation: level 1 holds one
+    segment per (row tile, feature bucket) and no segment is narrower than
+    MIN_SP slots, so the packed planes hold at least `min_level1_slots`
+    slots whatever the pattern, and `pad_blowup` (slots over stored entries)
+    is at least that over `nnz`. A wide, thin matrix — 1,000,000 features at
+    39 entries a row is 10 entries a segment against a floor of 1,024 —
+    cannot pay, and packing it to find that out would allocate the floor
+    (31 G slots at 8,000,000 rows). `nnz` may count padding entries (an ELL
+    plane's size): an upper bound only makes the answer more lenient, and a
+    pack that passes here is still judged on its measured blowup."""
+    return min_level1_slots(n_rows, dim) <= MAX_PAD_BLOWUP * max(int(nnz), 1)
+
+
+def pack_decline_reason(
+    n_samples: int, nnz: int, dim: int, dtype, *, sharded: bool = False
+) -> Optional[str]:
+    """Why the bucketed pack of a shard is not worth starting, or None —
+    from shapes and metadata alone (`pack_declined` in the stage notes,
+    counter `sparse_pack_declined{reason}`): `too_small` to amortize,
+    `dtype` (the kernels compute in f32; a silent downcast of f64 data
+    would diverge from the ELL path), `sharded` (the pack gathers to one
+    host and would lose data parallelism), `pad_blowup` (`pack_can_pay`)."""
+    if n_samples < MIN_PACK_ROWS:
+        return "too_small"
+    if jnp.dtype(dtype) != jnp.float32:
+        return "dtype"
+    if sharded:
+        return "sharded"
+    if not pack_can_pay(nnz, n_samples, dim):
+        return "pad_blowup"
+    return None
+
+
+def _declined(reason: str) -> None:
+    """Record a declined pack where the run profile and the metrics read it."""
+    from photon_ml_tpu.utils import telemetry
+    from photon_ml_tpu.utils.observability import set_stage_note
+
+    set_stage_note("pack_declined", reason)
+    telemetry.METRICS.increment(
+        "sparse_pack_declined", labels=(("reason", reason),)
+    )
+    return None
+
+
+def _kept(bf: BucketedSparseFeatures) -> Optional[BucketedSparseFeatures]:
+    """The data-dependent half of the decision, on the finished pack: its
+    measured blowup (level-2 spill included) against the same limit."""
+    if should_use(bf) and bf.density_report()["pad_blowup"] <= MAX_PAD_BLOWUP:
+        return bf
+    return _declined("pad_blowup")
 
 
 def should_use(bf: BucketedSparseFeatures) -> bool:
@@ -422,11 +489,6 @@ def should_use(bf: BucketedSparseFeatures) -> bool:
         return False
     return True
 
-
-# Above this padding blowup the bucketed layout streams more bytes than the
-# padding-free ELL path saves — low-nnz data (sp floors at 1024 entries per
-# segment) stays on XLA.
-MAX_PAD_BLOWUP = 4.0
 
 # The fused kernel loads one whole tile's (B*spv, 128) packed+values blocks
 # into VMEM; cap the segment-row count so two f32 blocks plus working set
@@ -451,34 +513,40 @@ def maybe_pack(feats, n_samples: int) -> Optional[BucketedSparseFeatures]:
     array is sharded across devices or hosts (the pack gathers to host and
     would both lose data parallelism and crash on non-addressable shards);
     the problem is too small to amortize; or the packed layout's padding
-    blowup makes it a net loss.
+    blowup makes it a net loss. All but the last word of that are decided
+    from the planes' shapes and dtype (`pack_decline_reason`), before
+    anything is pulled to the host; a pack that is made is then judged on
+    its measured blowup.
     """
     from photon_ml_tpu.data.bucketed import pack_from_ell
     from photon_ml_tpu.data.containers import SparseFeatures
 
     if not isinstance(feats, SparseFeatures) or feats.indices.ndim != 2:
         return None
-    if not pack_worth_considering(n_samples):
+    if not kernels_eligible():
         return None
-    if feats.values.dtype != jnp.float32:
-        return None
+    sharded = False
     if isinstance(feats.indices, jax.Array):
         try:
-            if not feats.indices.is_fully_addressable:
-                return None
-            if len(feats.indices.sharding.device_set) > 1:
-                return None
+            sharded = (
+                not feats.indices.is_fully_addressable
+                or len(feats.indices.sharding.device_set) > 1
+            )
         except Exception:
-            return None
+            sharded = True
+    # Decided from the planes' shapes and dtype: nothing is pulled to the
+    # host or expanded for a shard whose pack cannot pay.
+    reason = pack_decline_reason(
+        n_samples, feats.indices.size, feats.dim, feats.values.dtype,
+        sharded=sharded,
+    )
+    if reason is not None:
+        return _declined(reason)
     from photon_ml_tpu.utils.observability import stage_timer
 
     with stage_timer("pack"):
         bf = pack_from_ell(feats)
-    if not should_use(bf):
-        return None
-    if bf.density_report()["pad_blowup"] > MAX_PAD_BLOWUP:
-        return None
-    return bf
+    return _kept(bf)
 
 
 def host_pack_coo(
@@ -493,16 +561,15 @@ def host_pack_coo(
 
     from photon_ml_tpu.data.bucketed import pack_bucketed
 
-    if not pack_worth_considering(n_samples):
+    if not kernels_eligible():
         return None
-    if np.asarray(vals).dtype != np.float32:
-        return None
+    reason = pack_decline_reason(
+        n_samples, len(vals), dim, np.asarray(vals).dtype
+    )
+    if reason is not None:
+        return _declined(reason)
     bf = pack_bucketed(rows, cols, vals, n_samples, dim, host_only=host_only)
-    if not should_use(bf):
-        return None
-    if bf.density_report()["pad_blowup"] > MAX_PAD_BLOWUP:
-        return None
-    return bf
+    return _kept(bf)
 
 
 def pack_coo_auto(
@@ -532,6 +599,12 @@ def maybe_pack_coo(
     return pack_coo_auto(rows, cols, vals, n_samples, dim)
 
 
+def _csr_can_pay(csr, n_samples: int) -> bool:
+    """`pack_can_pay` for an ingest CSR stash, from its lengths alone."""
+    nnz = len(csr.vals) + (n_samples if csr.extra_col is not None else 0)
+    return pack_can_pay(nnz, n_samples, csr.dim)
+
+
 def begin_pack_async(csr, n_samples: int) -> None:
     """Start the host-side bucketed pack of an ingest CSR stash (a
     `data.game_dataset.HostCSR`) on a daemon thread; the native counting
@@ -554,8 +627,8 @@ def begin_pack_async(csr, n_samples: int) -> None:
     bench host, VERDICT r05 weak #2)."""
     if getattr(csr, "pack_future", None) is not None:
         return
-    if not pack_worth_considering(n_samples):
-        return
+    if not pack_worth_considering(n_samples) or not _csr_can_pay(csr, n_samples):
+        return  # finish_pack records the decline, once, where it is consumed
     from photon_ml_tpu.data import device_pack
 
     if device_pack.enabled():
@@ -650,6 +723,8 @@ def finish_pack(csr, n_samples: int) -> Optional[BucketedSparseFeatures]:
             return None if bf is None else bucketed.upload(bf)
     from photon_ml_tpu.data import device_pack
 
+    if pack_worth_considering(n_samples) and not _csr_can_pay(csr, n_samples):
+        return _declined("pad_blowup")  # before the COO expansion
     with stage_timer("pack"):
         rows, cols, vals, dim = csr.to_coo()
         bf = host_pack_coo(

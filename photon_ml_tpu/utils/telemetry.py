@@ -184,6 +184,11 @@ METRIC_DESCRIPTIONS = {
     # coordinate=<id>,kind=fixed|random.
     "objective_evaluations": "objective evaluations the optimizers made, "
     "per coordinate (labeled coordinate=<id>,kind=fixed|random)",
+    # A sparse fixed effect whose bucketed pack was declined keeps the ELL
+    # objective through XLA (ops/pallas_sparse.pack_decline_reason).
+    "sparse_pack_declined": "bucketed packs of a sparse fixed-effect shard "
+    "declined, per reason (labeled reason=too_small|dtype|sharded|"
+    "pad_blowup)",
     # An evaluation is one compiled program and one fetch
     # (evaluation/suite.evaluate_metrics): hit share = 1 - traces / calls.
     "evaluation_calls": "evaluations made (EvaluationSuite.evaluate, "
