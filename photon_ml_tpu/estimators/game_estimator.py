@@ -643,12 +643,13 @@ class GameEstimator:
     def _publish_stages(self, stages: TimingRegistry, base: Dict[str, float]):
         """This fit's stage walls and evaluation counts, per fit and per
         process: `fit_timing["stages_s"]` (plain floats; a stage that did
-        not run reads 0.0) beside `fit_timing["fn_evals"]`, and the same
-        numbers into `telemetry.METRICS` — histogram
-        `fit_stage_s{stage=<name>}`, counter
-        `objective_evaluations{coordinate=<id>,kind=fixed|random}` — so a
-        reader that sees only the process gets a window's totals as the
-        process total less the fits made before it."""
+        not run reads 0.0) beside `fit_timing["fn_evals"]` and
+        `fit_timing["line_search_rejected"]`, and the same numbers into
+        `telemetry.METRICS` — histogram `fit_stage_s{stage=<name>}`,
+        counters `objective_evaluations` and `line_search_rejected_trials`
+        `{coordinate=<id>,kind=fixed|random}` — so a reader that sees only
+        the process gets a window's totals as the process total less the
+        fits made before it."""
         self.fit_timing["stages_s"] = {
             k: float(stages.get(k) - base.get(k, 0.0)) for k in SOLVE_STAGES
         }
@@ -656,12 +657,17 @@ class GameEstimator:
             telemetry.METRICS.observe(
                 "fit_stage_s", seconds, labels=(("stage", stage),)
             )
-        for cid, evals in self.fit_timing["fn_evals"].items():
+        def labels(cid):
             kind = "random" if self._prepared[cid].re_dataset is not None else "fixed"
+            return (("coordinate", cid), ("kind", kind))
+
+        for cid, evals in self.fit_timing["fn_evals"].items():
             telemetry.METRICS.increment(
-                "objective_evaluations",
-                evals,
-                labels=(("coordinate", cid), ("kind", kind)),
+                "objective_evaluations", evals, labels=labels(cid)
+            )
+        for cid, trials in self.fit_timing["line_search_rejected"].items():
+            telemetry.METRICS.increment(
+                "line_search_rejected_trials", trials, labels=labels(cid)
             )
 
     def _on_cd_event(self, etype: str, **fields) -> None:
@@ -757,6 +763,7 @@ class GameEstimator:
         diverged_steps = 0
         collective_bytes = 0
         fn_evals: Dict[str, int] = {}
+        line_search_rejected: Dict[str, int] = {}
         sharding_infos: Dict[str, dict] = {}
         default_cfg = CoordinateOptimizationConfig()
         for ci, cfgs in enumerate(opt_configs):
@@ -856,6 +863,8 @@ class GameEstimator:
             collective_bytes += cd.collective_bytes
             for cid, evals in cd.fn_evals.items():
                 fn_evals[cid] = fn_evals.get(cid, 0) + evals
+            for cid, trials in cd.line_search_rejected.items():
+                line_search_rejected[cid] = line_search_rejected.get(cid, 0) + trials
             self.fit_timing["solve_s"] += descent.seconds + final_evaluate.seconds
             logger.info(
                 "configuration %d/%d trained%s",
@@ -865,6 +874,7 @@ class GameEstimator:
             )
         with stage_timer("fit/publish"):
             self.fit_timing["fn_evals"] = fn_evals
+            self.fit_timing["line_search_rejected"] = line_search_rejected
             # Finalize the per-stage prepare breakdown: deltas of the timing
             # registry over this fit call. In a synchronous run the stages +
             # `other` tile `prepare_s`; in a pipelined run overlapped stages

@@ -96,9 +96,12 @@ _RE_JIT_CACHE: dict = {}
 @dataclasses.dataclass(frozen=True)
 class RandomEffectSolveStats:
     """What one random-effect sweep's solves did. `iterations` and
-    `fn_evals` (value+gradient evaluations, line-search trials included)
-    are summed over every entity; `buckets` has one record per bucket
-    shape (`capacity`, `entities`, `buckets`, `iterations`, `fn_evals`).
+    `fn_evals` (value+gradient evaluations: an L-BFGS solve makes its
+    first and one per line-search trial, 1 + iterations + rejected trials)
+    are summed over every entity, and `solves` counts the solves summed
+    (every lane of every bucket, dummy-padded slots included); `buckets`
+    has one record per bucket shape (`capacity`, `entities`, `buckets`,
+    `iterations`, `fn_evals`).
     `per_entity` keeps each dispatch's (bucket indices, iterations,
     fn_evals) with the arrays still on the device:
     `RandomEffectCoordinate.entity_counts` fetches them when a caller
@@ -107,6 +110,7 @@ class RandomEffectSolveStats:
     buckets: List[dict]
     iterations: int
     fn_evals: int
+    solves: int
     # Not compared: a scan sweep and the per-bucket loop count the same
     # solves in dispatches of different shapes.
     per_entity: List[tuple] = dataclasses.field(compare=False, repr=False)
@@ -1165,6 +1169,7 @@ class RandomEffectCoordinate:
             buckets=list(by_shape.values()),
             iterations=int(totals[:, 0].sum()),
             fn_evals=int(totals[:, 1].sum()),
+            solves=sum(int(its.size) for _, its, _ in solved),
             per_entity=solved,
         )
         # Keep the unseen-entity row pinned to zero — in BOTH matrices:

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from photon_ml_tpu.evaluation.suite import EvaluationResults, EvaluationSuite
 from photon_ml_tpu.game.model import GameModel
+from photon_ml_tpu.types import OptimizerType
 from photon_ml_tpu.utils import faults, telemetry
 from photon_ml_tpu.utils.observability import record_stage, stage_timer
 
@@ -164,18 +165,27 @@ def _recover_from_mesh_loss(
     return models, best_models, snap_best_res, snap_pass, completed_steps, "memory"
 
 
-def _fetch_verdict(ok, stats):
-    """(finite, fn_evals) of one attempted update, with the ONE
-    device-to-host fetch the divergence guard always made: the fixed
-    effect's `OptResult.fn_evals` is a device scalar, ready since its solve
-    ended, and rides along with the guard's boolean. A random effect's
-    stats hold the count on the host already (`_finish_train`'s one
-    fetch). None where the coordinate's train reports no count."""
+def _fetch_verdict(ok, stats, line_search):
+    """(finite, fn_evals, rejected trials) of one attempted update, with the
+    ONE device-to-host fetch the divergence guard always made: the fixed
+    effect's `OptResult.fn_evals` and `iterations` are device scalars, ready
+    since its solve ended, and ride along with the guard's boolean. A random
+    effect's stats hold the counts on the host already (`_finish_train`'s one
+    fetch). The rejected trials of a line-search solve are its evaluations
+    less the first and one an iteration (a search that fails outright reads
+    one short); None for TRON, whose count holds Hessian-vector products.
+    Both None where the coordinate's train reports no count."""
     evals = getattr(stats, "fn_evals", None)
+    if evals is None:
+        return bool(ok), None, None
+    iterations = stats.iterations
     if isinstance(evals, jax.Array):
-        finite, evals = jax.device_get((ok, evals))
-        return bool(finite), int(evals)
-    return bool(ok), evals
+        ok, evals, iterations = jax.device_get((ok, evals, iterations))
+    evals = int(evals)
+    rejected = (
+        evals - getattr(stats, "solves", 1) - int(iterations) if line_search else None
+    )
+    return bool(ok), evals, rejected
 
 
 def _update_all_finite(model, scores) -> bool:
@@ -203,6 +213,11 @@ class CoordinateDescentResult:
     # Hessian-vector products included). A coordinate whose train reports
     # none is absent.
     fn_evals: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # Line-search trials per coordinate that failed the Armijo test: of an
+    # L-BFGS solve's evaluations, those beyond the first and one an
+    # iteration, each a value+gradient evaluation thrown away. A TRON
+    # coordinate is absent.
+    line_search_rejected: Dict[str, int] = dataclasses.field(default_factory=dict)
     # Analytic wire bytes moved through entity-shard ring collectives by
     # the accepted coordinate updates (RandomEffectCoordinate.train sets
     # last_train_collective_bytes per sweep; 0 on the replicated path) —
@@ -307,6 +322,7 @@ def run_coordinate_descent(
     models: Dict[str, object] = dict(initial_models.models) if initial_models else {}
     timing: Dict[str, float] = {}
     fn_evals: Dict[str, int] = {}
+    line_search_rejected: Dict[str, int] = {}
     diverged_steps = 0
     collective_bytes = 0
     validation_history: List[Tuple[int, str, EvaluationResults]] = []
@@ -509,6 +525,10 @@ def run_coordinate_descent(
             new_scores = None
             new_summed = None
             update_evals: Optional[int] = None
+            update_rejected: Optional[int] = None
+            line_search = (
+                coord.config.optimizer.optimizer_type != OptimizerType.TRON
+            )
             # One stage per coordinate update, its wall the update's
             # `timing` entry; the cd/* stages inside it are declared in
             # contracts.SOLVE_STAGES (which are dispatch walls, which wait).
@@ -571,9 +591,13 @@ def run_coordinate_descent(
                                     cand_scores,
                                     _model_arrays(cand_model, cand_scores),
                                 )
-                                finite, evals = _fetch_verdict(ok, stats)
+                                finite, evals, rejected = _fetch_verdict(
+                                    ok, stats, line_search
+                                )
                             if evals is not None:
                                 update_evals = (update_evals or 0) + evals
+                            if rejected is not None:
+                                update_rejected = (update_rejected or 0) + rejected
                         except faults.MeshLoss:
                             raise
                         except BaseException as exc:
@@ -626,6 +650,10 @@ def run_coordinate_descent(
                     )
             if update_evals is not None:
                 fn_evals[cid] = fn_evals.get(cid, 0) + update_evals
+            if update_rejected is not None:
+                line_search_rejected[cid] = (
+                    line_search_rejected.get(cid, 0) + update_rejected
+                )
             timing[f"{cid}/iter{it}"] = update.seconds
             telemetry.METRICS.observe("coordinate_update_s", update.seconds)
             if on_event is not None:
@@ -814,6 +842,7 @@ def run_coordinate_descent(
         timing=timing,
         diverged_steps=diverged_steps,
         fn_evals=fn_evals,
+        line_search_rejected=line_search_rejected,
         collective_bytes=collective_bytes,
         mesh_losses=mesh_losses,
         repeated_sweeps=repeated_sweeps,

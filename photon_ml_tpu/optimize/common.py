@@ -53,9 +53,11 @@ class OptResult(NamedTuple):
     # (coefficients, loss, gradient) per iteration; here the per-iteration
     # scalars ride along as fixed-size arrays, NaN beyond `iterations`).
     gradient_norm_history: Optional[Array] = None
-    # Total objective-data passes: value/gradient evaluations plus (TRON)
-    # Hessian-vector products — each streams the design matrix once on the
-    # fused path, so wall-clock / fn_evals is the per-pass cost.
+    # Total objective-data passes: value+gradient evaluations (L-BFGS: the
+    # initial one and one per line-search trial, 1 + iterations + rejected
+    # trials; no point is evaluated twice) plus (TRON) Hessian-vector
+    # products — each streams the design matrix once on the fused path, so
+    # wall-clock / fn_evals is the per-pass cost.
     fn_evals: Optional[Array] = None
     # (max_iterations + 1, D) per-iteration coefficient snapshots when
     # track_coefficients is requested (the reference OptimizationStatesTracker

@@ -18,6 +18,17 @@ kernel is
     L-BFGS instances that stop at different iterations via the reason mask
     (the JAX batching rule for while_loop keeps finished lanes frozen).
 
+Evaluations: one value+gradient evaluation before the loop, then one per
+line-search trial and none besides. A trial computes the gradient with the
+value and the search carries the last trial's out, so the accepted point is
+never evaluated again: `fn_evals == 1 + iterations + rejected trials`. A
+first trial that is accepted, the common case, is the iteration's only
+pass over the data. A rejected trial pays for a gradient it throws away:
+nothing on the fused dense kernel, one scatter-add beside the gather on
+the sparse paths. Under `vmap` a search runs to its slowest lane's trial
+count and every lane pays each trial, so a bucket of thousands of entities
+pays the thrown-away gradients of its worst lane (PERF.md section 6, PR 30).
+
 OWLQN mode (l1_weight not None) uses the standard orthant-wise method: the
 pseudo-gradient seeds the two-loop recursion, the direction is sign-projected
 against it, steps are projected onto the orthant, and the line-search
@@ -83,7 +94,7 @@ class _Carry(NamedTuple):
     loss_history: Array
     gnorm_history: Array
     coef_history: Array
-    evals: Array  # cumulative objective evaluations (incl. line search)
+    evals: Array  # cumulative value+gradient evaluations: 1 + every trial
 
 
 def _two_loop(pg: Array, S: Array, Y: Array, rho: Array, k: Array) -> Array:
@@ -120,7 +131,6 @@ def _two_loop(pg: Array, S: Array, Y: Array, rho: Array, k: Array) -> Array:
     jax.jit,
     static_argnames=(
         "value_and_grad_fn",
-        "value_fn",
         "max_iterations",
         "history_size",
         "use_l1",
@@ -137,7 +147,6 @@ def _minimize(
     lower: Array,
     upper: Array,
     *,
-    value_fn,
     max_iterations: int,
     tolerance: float,
     history_size: int,
@@ -154,12 +163,6 @@ def _minimize(
 
     def clip_box(x: Array) -> Array:
         return jnp.clip(x, lower, upper) if use_box else x
-
-    def total_value(x: Array) -> Array:
-        # Line-search trials need the value only; the caller may supply a
-        # cheaper value_fn (otherwise XLA's DCE drops the unused gradient).
-        f = value_fn(x) if value_fn is not None else value_and_grad_fn(x)[0]
-        return f + l1 * jnp.sum(jnp.abs(x)) if use_l1 else f
 
     w0 = clip_box(w0)
     f0s, g0 = value_and_grad_fn(w0)
@@ -217,23 +220,28 @@ def _minimize(
         t0 = jnp.where(t0 > 0.0, t0, 1.0)
 
         def ls_cond(s):
-            t, f_new, x_new, tries, ok = s
+            t, f_new, x_new, g_new, tries, ok = s
             return (~ok) & (tries < max_line_search)
 
         def ls_body(s):
-            t, _, _, tries, _ = s
+            t, _, _, _, tries, _ = s
             x_new = take_step(t)
-            f_new = total_value(x_new)
+            # The body's one evaluation: the accepted trial's gradient is
+            # the next iterate's, so it leaves the search in the carry.
+            f_new, g_new = value_and_grad_fn(x_new)
+            if use_l1:
+                f_new = f_new + l1 * jnp.sum(jnp.abs(x_new))
             # Armijo on the projected step: f_new <= f + c1 * pg.(x_new - x).
             ok = f_new <= c.f + _ARMIJO_C1 * jnp.dot(c.pg, x_new - c.x)
             ok = ok & jnp.isfinite(f_new)
-            return (jnp.where(ok, t, t * 0.5), f_new, x_new, tries + 1, ok)
+            return (jnp.where(ok, t, t * 0.5), f_new, x_new, g_new, tries + 1, ok)
 
-        t, f_new, x_new, ls_tries, ls_ok = lax.while_loop(
-            ls_cond, ls_body, (t0, c.f, c.x, jnp.zeros((), jnp.int32), jnp.zeros((), bool))
+        t, f_new, x_new, g_new, ls_tries, ls_ok = lax.while_loop(
+            ls_cond,
+            ls_body,
+            (t0, c.f, c.x, c.g, jnp.zeros((), jnp.int32), jnp.zeros((), bool)),
         )
 
-        f_sm_new, g_new = value_and_grad_fn(x_new)
         pg_new = _pseudo_gradient(x_new, g_new, l1) if use_l1 else g_new
 
         s_vec = x_new - c.x
@@ -286,7 +294,7 @@ def _minimize(
                 c.gnorm_history, iteration, jnp.linalg.norm(pg_out)
             ),
             coef_history=record_coefficients(c.coef_history, iteration, x_out),
-            evals=c.evals + ls_tries + 1,
+            evals=c.evals + ls_tries,
         )
 
     final = lax.while_loop(cond, body, init)
@@ -307,7 +315,6 @@ def minimize_lbfgs(
     value_and_grad_fn: ValueAndGrad,
     w0: Array,
     *,
-    value_fn: Optional[Callable[[Array], Array]] = None,
     max_iterations: int = DEFAULT_MAX_ITERATIONS,
     tolerance: float = DEFAULT_TOLERANCE,
     history_size: int = DEFAULT_HISTORY,
@@ -341,7 +348,6 @@ def minimize_lbfgs(
         l1,
         lower,
         upper,
-        value_fn=value_fn,
         max_iterations=max_iterations,
         tolerance=tolerance,
         history_size=history_size,

@@ -103,6 +103,9 @@ FIT_TIMING_REQUIRED_KEYS = (
     # products included), counted by the optimizers themselves.
     "stages_s",
     "fn_evals",
+    # ISSUE 30: line-search trials per coordinate that failed the Armijo
+    # test (L-BFGS evaluations beyond the first and one an iteration).
+    "line_search_rejected",
 )
 
 # ------------------------------------------------------------------- ingest

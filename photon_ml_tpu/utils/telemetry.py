@@ -179,11 +179,17 @@ METRIC_DESCRIPTIONS = {
     "compile_cache_hits": "compile requests answered from the persistent "
     "compilation cache instead of compiling",
     # Objective evaluations as the optimizers count them (OptResult.fn_evals:
-    # value+gradient evaluations, line-search trials and TRON's
-    # Hessian-vector products), added once a fit, labeled
+    # value+gradient evaluations — L-BFGS's first and one per line-search
+    # trial — and TRON's Hessian-vector products), added once a fit, labeled
     # coordinate=<id>,kind=fixed|random.
     "objective_evaluations": "objective evaluations the optimizers made, "
     "per coordinate (labeled coordinate=<id>,kind=fixed|random)",
+    # How often the line search's thrown-away work engages: an L-BFGS
+    # solve's evaluations beyond its first and one an iteration, each a
+    # value+gradient evaluation at a point the Armijo test refused.
+    "line_search_rejected_trials": "line-search trials that failed the "
+    "Armijo test, per coordinate (labeled coordinate=<id>,"
+    "kind=fixed|random; L-BFGS, OWL-QN and box solves)",
     # A sparse fixed effect whose bucketed pack was declined keeps the ELL
     # objective through XLA (ops/pallas_sparse.pack_decline_reason).
     "sparse_pack_declined": "bucketed packs of a sparse fixed-effect shard "

@@ -189,6 +189,63 @@ class TestEvaluationsCountedWhereTheyHappen:
         assert all(isinstance(v, int) for v in by_coordinate.values())
 
 
+class TestRejectedTrialsRideTheOneFetch:
+    """ISSUE 30: `line_search_rejected_trials` is the solve's evaluations less
+    its first and one an iteration, read in the fetch the guard makes."""
+
+    @pytest.mark.parametrize("optimizer", [OptimizerType.LBFGS, OptimizerType.TRON])
+    def test_fixed_effect_count_and_a_single_fetch(self, monkeypatch, optimizer):
+        from photon_ml_tpu.game import coordinate_descent
+
+        train, _ = _glmix(n=2000, n_val=10)
+        cfg = CoordinateOptimizationConfig(
+            optimizer=OptimizerConfig(optimizer_type=optimizer, max_iterations=6),
+            reg_weight=1.0,
+        )
+        coord = FixedEffectCoordinate(train, "g", cfg, TASK)
+        _, res = coord.train(train.offsets)
+        fetched = []
+        device_get = jax.device_get
+        monkeypatch.setattr(
+            coordinate_descent.jax,
+            "device_get",
+            lambda tree: fetched.append(len(tree)) or device_get(tree),
+        )
+        cd = run_coordinate_descent({"global": coord}, 1)
+        assert fetched == [3]  # ok, fn_evals, iterations: one fetch an update
+        if optimizer == OptimizerType.TRON:
+            assert cd.line_search_rejected == {}
+        else:
+            rejected = int(res.fn_evals) - 1 - int(res.iterations)
+            assert cd.line_search_rejected == {"global": rejected}
+            assert rejected >= 0
+
+    def test_random_effect_count_is_by_solve(self):
+        ds, red, coord = TestRandomEffectSolveStats()._coordinate(n_entities=40)
+        _, stats = coord.train(ds.offsets)
+        assert stats.solves == sum(b.num_entities for b in red.buckets)
+        its, evals = coord.entity_counts(stats)
+        by_entity = int((evals - its).sum()) - stats.solves
+        cd = run_coordinate_descent({"per-entity": coord}, 1)
+        assert cd.line_search_rejected == {"per-entity": by_entity}
+        assert by_entity == stats.fn_evals - stats.solves - stats.iterations >= 0
+
+    def test_a_fit_publishes_the_count(self):
+        train, val = _glmix(n=1500, n_val=400)
+        est, cfg = _estimator(cd_iterations=1)
+        before = telemetry.METRICS.labeled_counters("line_search_rejected_trials")
+        est.fit(train, val, [cfg])
+        rejected = est.fit_timing["line_search_rejected"]
+        assert set(rejected) == set(est.fit_timing["fn_evals"]) == {"global", "per-e"}
+        after = telemetry.METRICS.labeled_counters("line_search_rejected_trials")
+        for cid, kind in (("global", "fixed"), ("per-e", "random")):
+            label = f"coordinate={cid},kind={kind}"
+            assert after[label] - before.get(label, 0) == rejected[cid] >= 0
+        profile = est.run_profile()
+        assert profile["fit_timing"]["line_search_rejected"] == rejected
+        assert "line_search_rejected_trials" in profile["metrics"]["labeled_counters"]
+
+
 class TestRandomEffectSolveStats:
     def _coordinate(self, n=6000, n_entities=300, seed=3):
         rng = np.random.default_rng(seed)

@@ -13,7 +13,8 @@ import pytest
 
 from photon_ml_tpu.data.containers import dense_data
 from photon_ml_tpu.ops import losses, objective
-from photon_ml_tpu.optimize.common import ConvergenceReason
+from photon_ml_tpu.optimize import lbfgs as lbfgs_module
+from photon_ml_tpu.optimize.common import ConvergenceReason, check_convergence
 from photon_ml_tpu.optimize.lbfgs import minimize_lbfgs
 from photon_ml_tpu.optimize.tron import minimize_tron
 
@@ -267,3 +268,315 @@ def test_tron_rejected_steps_preserve_diagnostics():
     assert deltas[0] == pytest.approx(g0, rel=1e-5)
     assert np.isnan(cgs[0])
     assert np.all(cgs[1 : its + 1] >= 1)
+
+
+# -- one objective evaluation an iteration (ISSUE 30) -------------------------
+# The line search evaluates value and gradient at each trial and hands the
+# accepted trial's gradient on; no point is evaluated twice.
+
+
+def _old_loop(vg, w0, *, max_iterations=100, tolerance=1e-7, l1=None, lower=None,
+              upper=None, max_line_search=30):
+    """The loop as it stood before ISSUE 30, in eager Python: a value at each
+    trial, then value and gradient again at the point the search accepted. It
+    is the statement of the iterates; the pieces ISSUE 30 left alone (two-loop
+    recursion, pseudo-gradient, convergence test) are the module's own."""
+    m = lbfgs_module.DEFAULT_HISTORY
+    use_l1 = l1 is not None
+    l1v = jnp.float32(0.0 if l1 is None else l1)
+    use_box = lower is not None or upper is not None
+    lo = -jnp.inf if lower is None else jnp.asarray(lower, jnp.float32)
+    hi = jnp.inf if upper is None else jnp.asarray(upper, jnp.float32)
+    clip = (lambda x: jnp.clip(x, lo, hi)) if use_box else (lambda x: x)
+    total = lambda x, f: f + l1v * jnp.sum(jnp.abs(x)) if use_l1 else f
+    pseudo = lambda x, g: lbfgs_module._pseudo_gradient(x, g, l1v) if use_l1 else g
+
+    x = clip(jnp.asarray(w0, jnp.float32))
+    f_smooth, g = vg(x)
+    f, pg = total(x, f_smooth), pseudo(x, g)
+    f0, gnorm0 = f, jnp.linalg.norm(pg)
+    S = jnp.zeros((m, x.shape[0]), jnp.float32)
+    Y, rho, k = S, jnp.zeros((m,), jnp.float32), 0
+    xs, losses, trials, old_evals = [x], [f], [], 1
+    reason = ConvergenceReason.GRADIENT_CONVERGED if float(gnorm0) == 0.0 else 0
+    iteration = 0
+    while reason == ConvergenceReason.NOT_CONVERGED:
+        d = -lbfgs_module._two_loop(pg, S, Y, rho, jnp.int32(k))
+        if use_l1:
+            d = jnp.where(d * pg < 0.0, d, 0.0)
+            orthant = jnp.where(x != 0.0, jnp.sign(x), jnp.sign(-pg))
+        t = 1.0 / float(jnp.linalg.norm(d)) if k == 0 and float(jnp.linalg.norm(d)) > 0 else 1.0
+        ok = False
+        trials.append(0)
+        for _ in range(max_line_search):
+            x_new = x + jnp.float32(t) * d
+            if use_l1:
+                x_new = jnp.where(x_new * orthant >= 0.0, x_new, 0.0)
+            x_new = clip(x_new)
+            f_new = total(x_new, vg(x_new)[0])
+            trials[-1] += 1
+            old_evals += 1
+            ok = bool(f_new <= f + 1e-4 * jnp.dot(pg, x_new - x)) and bool(jnp.isfinite(f_new))
+            if ok:
+                break
+            t *= 0.5
+        _, g_new = vg(x_new)
+        old_evals += 1
+        pg_new = pseudo(x_new, g_new)
+        s_vec, y_vec = x_new - x, g_new - g
+        sy = float(jnp.dot(s_vec, y_vec))
+        if ok and sy > 1e-10:
+            slot = k % m
+            S, Y, rho = S.at[slot].set(s_vec), Y.at[slot].set(y_vec), rho.at[slot].set(1.0 / sy)
+            k += 1
+        iteration += 1
+        reason = int(
+            check_convergence(
+                loss=f_new, prev_loss=f, init_loss=f0, grad_norm=jnp.linalg.norm(pg_new),
+                init_grad_norm=gnorm0, iteration=iteration, max_iterations=max_iterations,
+                tolerance=tolerance,
+            )
+        )
+        if ok:
+            x, f, g, pg = x_new, f_new, g_new, pg_new
+        else:
+            reason = int(ConvergenceReason.OBJECTIVE_NOT_IMPROVING)
+        xs.append(x)
+        losses.append(f)
+    return dict(
+        xs=np.stack([np.asarray(v) for v in xs]),
+        losses=np.asarray([float(v) for v in losses]),
+        iterations=iteration,
+        reason=int(reason),
+        trials=sum(trials),
+        old_evals=old_evals,
+    )
+
+
+def _assert_same_run(res, old, rtol=2e-5, atol=2e-6):
+    """Iterates, loss history, reason and the count of one `OptResult` against
+    the old loop's record of the same problem."""
+    its = old["iterations"]
+    assert int(res.iterations) == its
+    assert int(res.reason) == old["reason"]
+    np.testing.assert_allclose(
+        np.asarray(res.coefficients_history)[: its + 1], old["xs"], rtol=rtol, atol=atol
+    )
+    np.testing.assert_allclose(
+        np.asarray(res.loss_history)[: its + 1], old["losses"], rtol=rtol, atol=atol
+    )
+    np.testing.assert_allclose(res.coefficients, old["xs"][-1], rtol=rtol, atol=atol)
+    # The same trials, and no evaluation besides them and the first: the old
+    # loop made one more an iteration. (Every search here moves the point: a
+    # projected step that moves nothing halves until float32 rounding lets it
+    # pass, a count that differs between compiled and eager, so the box cases
+    # stop on an iteration limit before their last, motionless search.)
+    assert np.all(np.diff(old["losses"]) < 0.0)
+    assert int(res.fn_evals) == 1 + old["trials"]
+    assert old["old_evals"] == int(res.fn_evals) + its
+
+
+def _ill_scaled_quadratic(d=6):
+    """A quadratic whose unit first step overshoots: the search backtracks."""
+    scales = jnp.asarray(np.geomspace(1.0, 400.0, d), jnp.float32)
+    center = jnp.asarray(np.linspace(0.05, 0.3, d), jnp.float32)
+
+    def vg(w):
+        diff = w - center
+        return 0.5 * jnp.sum(scales * diff * diff), scales * diff
+
+    return vg
+
+
+def test_a_well_conditioned_fit_evaluates_once_an_iteration(rng):
+    _, vg, _ = _logistic_problem(rng, l2=1.0)
+    res = minimize_lbfgs(vg, jnp.zeros(8, jnp.float32), max_iterations=5, tolerance=1e-12)
+    assert int(res.iterations) == 5 and int(res.reason) == ConvergenceReason.MAX_ITERATIONS
+    assert int(res.fn_evals) == 1 + 5
+
+
+@pytest.mark.parametrize("problem", ["rosenbrock", "ill_scaled_quadratic"])
+def test_rejected_trials_are_the_only_other_evaluations(problem):
+    """Every execution of the objective is seen by a host callback: those at
+    points that never became an iterate are the rejected trials."""
+    inner = _rosenbrock_vg if problem == "rosenbrock" else _ill_scaled_quadratic(4)
+    seen = []
+
+    def instrumented(w):
+        jax.debug.callback(lambda x: seen.append(np.asarray(x)), w)
+        return inner(w)
+
+    res = minimize_lbfgs(
+        instrumented, jnp.zeros(4, jnp.float32), max_iterations=40, tolerance=1e-10,
+        track_coefficients=True,
+    )
+    jax.block_until_ready(res)
+    jax.effects_barrier()
+    its = int(res.iterations)
+    iterates = {np.asarray(x).tobytes() for x in np.asarray(res.coefficients_history)[: its + 1]}
+    rejected = sum(1 for x in seen if x.tobytes() not in iterates)
+    assert rejected > 0
+    assert len(seen) == int(res.fn_evals) == 1 + its + rejected
+
+
+def _holders(jaxpr, name, path=()):
+    """Paths (enclosing primitives and the parameter that holds the sub-jaxpr)
+    of every call of the jitted function `name` inside `jaxpr`."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.params.get("name") == name:
+            found.append(path)
+            continue
+        for key, value in eqn.params.items():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    step = eqn.params.get("name", eqn.primitive.name)
+                    found += _holders(sub, name, path + (f"{step}:{key}",))
+    return found
+
+
+@pytest.mark.parametrize("mode", ["plain", "owlqn", "box"])
+def test_the_traced_solve_holds_the_objective_twice(mode):
+    """Once before the loop, once in the line-search body, nowhere else."""
+
+    @jax.jit
+    def marked_objective(w):
+        return _rosenbrock_vg(w)
+
+    options = {
+        "plain": {},
+        "owlqn": {"l1_weight": 0.1},
+        "box": {"lower_bounds": jnp.full(4, -0.5), "upper_bounds": jnp.full(4, 0.5)},
+    }[mode]
+    closed = jax.make_jaxpr(
+        lambda w: minimize_lbfgs(marked_objective, w, max_iterations=7, **options)
+    )(jnp.zeros(4, jnp.float32))
+    holders = sorted(_holders(closed.jaxpr, "marked_objective"))
+    assert len(holders) == 2
+    before_loop, in_search = holders
+    assert before_loop == ("_minimize:jaxpr",)
+    assert in_search == ("_minimize:jaxpr", "while:body_jaxpr", "while:body_jaxpr")
+
+
+_MODES = {
+    "plain": {},
+    "owlqn": {"l1": 0.05},
+    "box": {"lower": -np.ones(8, np.float32), "upper": np.ones(8, np.float32), "max_iterations": 3},
+    "owlqn_box": {
+        "l1": 0.02,
+        "lower": -np.ones(8, np.float32),
+        "upper": np.full(8, np.inf, np.float32),
+        "max_iterations": 3,
+    },
+}
+
+
+def _solve(vg, w0, *, l1=None, lower=None, upper=None, **kw):
+    return minimize_lbfgs(
+        vg, w0, l1_weight=l1, lower_bounds=lower, upper_bounds=upper,
+        tracking=True, track_coefficients=True, **kw,
+    )
+
+
+@pytest.mark.parametrize("mode", sorted(_MODES))
+def test_iterates_equal_the_old_loops_on_a_logistic_problem(rng, mode):
+    _, vg, _ = _logistic_problem(rng, l2=1e-2)
+    kw = {"max_iterations": 25, "tolerance": 1e-5, **_MODES[mode]}
+    res = _solve(vg, jnp.zeros(8, jnp.float32), **kw)
+    old = _old_loop(vg, jnp.zeros(8, jnp.float32), **kw)
+    assert old["iterations"] >= 3
+    _assert_same_run(res, old)
+
+
+@pytest.mark.parametrize("mode", ["plain", "owlqn", "box"])
+def test_iterates_equal_the_old_loops_where_the_search_backtracks(mode):
+    options = {
+        "plain": {},
+        "owlqn": {"l1": 0.5},
+        "box": {"lower": np.full(6, -1.0, np.float32), "upper": np.full(6, 0.25, np.float32),
+                "max_iterations": 6},
+    }[mode]
+    vg = _ill_scaled_quadratic()
+    kw = {"max_iterations": 30, "tolerance": 1e-5, **options}
+    res = _solve(vg, jnp.zeros(6, jnp.float32), **kw)
+    old = _old_loop(vg, jnp.zeros(6, jnp.float32), **kw)
+    assert old["trials"] > old["iterations"]  # some trial was rejected
+    _assert_same_run(res, old)
+
+
+def test_the_plain_solve_equals_the_benchmarks_reference(rng):
+    from benchmarks.references import lbfgs as reference
+
+    _, vg, _ = _logistic_problem(rng, l2=1.0)
+    res = minimize_lbfgs(vg, jnp.zeros(8, jnp.float32), max_iterations=6, tolerance=1e-12)
+    x_ref, info = reference.minimize(
+        jax.vmap(vg), jnp.zeros((1, 8), jnp.float32), max_iterations=6, tolerance=1e-12
+    )
+    np.testing.assert_allclose(res.coefficients, x_ref[0], rtol=2e-5, atol=2e-6)
+    assert info == {"iterations": int(res.iterations), "evaluations": int(res.fn_evals)}
+
+
+def test_vmapped_lanes_keep_their_own_gradient_when_one_backtracks():
+    """Lane 2 rejects trials while the others accept their first: each lane
+    ends where it ends alone, with its own count."""
+    d = 6
+    scales = jnp.asarray(
+        np.stack([np.ones(d), np.full(d, 2.0), np.geomspace(1.0, 400.0, d), np.full(d, 0.5)]),
+        jnp.float32,
+    )
+    centers = jnp.asarray(
+        np.stack([np.linspace(1, 2, d), np.linspace(-2, 1, d), np.linspace(0.05, 0.3, d),
+                  np.linspace(3, -3, d)]),
+        jnp.float32,
+    )
+
+    def one(scale, center):
+        def vg(w):
+            diff = w - center
+            return 0.5 * jnp.sum(scale * diff * diff), scale * diff
+
+        return vg
+
+    kw = dict(max_iterations=30, tolerance=1e-9)
+    batched = jax.vmap(lambda s, c: _solve(one(s, c), jnp.zeros(d, jnp.float32), **kw))(
+        scales, centers
+    )
+    rejected = np.asarray(batched.fn_evals) - 1 - np.asarray(batched.iterations)
+    assert rejected[2] > 0 and not rejected[[0, 1, 3]].any()
+    for lane in range(4):
+        vg = one(scales[lane], centers[lane])
+        res = jax.tree_util.tree_map(lambda a: a[lane], batched)
+        _assert_same_run(res, _old_loop(vg, jnp.zeros(d, jnp.float32), **kw))
+        alone = _solve(vg, jnp.zeros(d, jnp.float32), **kw)
+        assert int(alone.fn_evals) == int(res.fn_evals)
+        assert int(alone.iterations) == int(res.iterations)
+        np.testing.assert_allclose(res.coefficients, alone.coefficients, rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("l1", [None, 0.05])
+def test_a_failed_search_keeps_the_previous_point_and_its_gradients(l1):
+    """With one trial a search, Rosenbrock's first rejected trial ends the
+    solve: the result is the iterate before it, with that iterate's loss and
+    (pseudo-)gradient, not the rejected trial's."""
+    res = minimize_lbfgs(
+        _rosenbrock_vg, jnp.full(4, -1.2, jnp.float32), max_iterations=50, tolerance=1e-12,
+        l1_weight=l1, max_line_search=1, tracking=True, track_coefficients=True,
+    )
+    its = int(res.iterations)
+    assert int(res.reason) == ConvergenceReason.OBJECTIVE_NOT_IMPROVING
+    assert its >= 2  # it failed after real progress, not at the start
+    assert int(res.fn_evals) == 1 + its  # one trial an iteration, the failed one too
+    hist = np.asarray(res.coefficients_history)
+    np.testing.assert_array_equal(hist[its], hist[its - 1])
+    np.testing.assert_array_equal(np.asarray(res.coefficients), hist[its - 1])
+    losses = np.asarray(res.loss_history)
+    assert losses[its] == losses[its - 1] == float(res.loss)
+    f, g = _rosenbrock_vg(res.coefficients)
+    if l1 is not None:
+        g = lbfgs_module._pseudo_gradient(res.coefficients, g, jnp.float32(l1))
+        f = f + l1 * jnp.sum(jnp.abs(res.coefficients))
+    np.testing.assert_allclose(float(res.loss), float(f), rtol=1e-6)
+    np.testing.assert_allclose(float(res.gradient_norm), float(jnp.linalg.norm(g)), rtol=1e-5)
+    gnorms = np.asarray(res.gradient_norm_history)
+    assert gnorms[its] == gnorms[its - 1]
