@@ -41,7 +41,8 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-# The published shape (bench.py e2e_from_disk, MovieLens-like): never cut.
+# The published shape (MovieLens-like, the one BENCH_r05.json's from-disk job
+# ran and benchmarks/configs/glmix-movielens.json states): never cut.
 D_FEATURES = 200
 NNZ_PER_ROW = 8
 FULL_ROWS = 20_000_000  # BENCH_r05's e2e scale; the smoke cuts rows only
@@ -140,7 +141,7 @@ def entity_counts(rows: int) -> "tuple[int, int]":
 
 def stage_data(args) -> dict:
     """Training + validation Avro and the JSONL request stream, all from
-    --seed, through the native columnar writer the bench uses."""
+    --seed, through the native columnar writer."""
     import numpy as np
 
     from photon_ml_tpu.native import build as native_build
@@ -232,7 +233,7 @@ def stage_data(args) -> dict:
 
 
 def train_argv(args, out_dir: str) -> list:
-    # The bench's e2e configuration (bench.py, e2e_from_disk): the reservoir
+    # The configuration of BENCH_r05.json's from-disk job: the reservoir
     # caps bound the padded per-entity blocks in HBM.
     cap_user, cap_movie = (256, 512) if args.rows <= 4_000_000 else (128, 256)
     return [

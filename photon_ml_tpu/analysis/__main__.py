@@ -28,7 +28,7 @@ def build_parser() -> argparse.ArgumentParser:
         "paths",
         nargs="*",
         help="files/directories to analyze (default: the live tree — the "
-        "package, bench.py, and tests/)",
+        "package and tests/)",
     )
     p.add_argument(
         "--list-checks",

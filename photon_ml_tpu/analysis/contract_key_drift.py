@@ -1,7 +1,7 @@
 """contract-key-drift: required-key schemas are imported, never re-typed.
 
-The bug class (PR 1/4/7): bench sections and the serving summary enforce
-loud missing-key contracts. When the required-key tuple is re-typed at
+The bug class (PR 1/4/7): fit timings, run profiles and the serving
+summary enforce loud missing-key contracts. When the required-key tuple is re-typed at
 every enforcement site, renaming a key updates the producer and N-1 of
 the N copies — the stale copy either fails a healthy run or, worse,
 keeps "passing" while no longer checking the renamed key. The schemas
@@ -70,9 +70,9 @@ def _contract_sets(reg: SourceFile) -> Dict[str, Set[str]]:
 
 @register_check(
     NAME,
-    "required-key tuples asserted by bench/tests must be imported from "
-    "utils/contracts.py, not re-typed as literals",
-    scopes=("package", "bench", "tests"),
+    "required-key tuples asserted by the package or tests must be imported "
+    "from utils/contracts.py, not re-typed as literals",
+    scopes=("package", "tests"),
 )
 def check(ctx: Context) -> List[Finding]:
     reg = ctx.find("utils/contracts.py", "contracts.py")

@@ -16,7 +16,7 @@ Vocabulary:
   import the code under analysis, so a broken tree can still be linted.
 
 * **Scopes**: in auto-discovery mode every file is categorized
-  (`package` = photon_ml_tpu/, `bench` = bench.py, `tests` = tests/),
+  (`package` = photon_ml_tpu/, `tests` = tests/),
   and each check declares which categories it scans — e.g. the
   knob-registry rule does not chase env reads through test monkeypatching,
   but contract-key-drift DOES police tests (a test re-typing a schema is
@@ -77,7 +77,7 @@ class SourceFile:
 
     path: str  # absolute
     rel: str  # display path
-    category: str  # package | bench | tests | explicit
+    category: str  # package | tests | explicit
     text: str
     lines: List[str]
     tree: ast.Module
@@ -124,7 +124,7 @@ class Check:
 def register_check(
     name: str,
     description: str,
-    scopes: Tuple[str, ...] = ("package", "bench"),
+    scopes: Tuple[str, ...] = ("package",),
 ):
     """Decorator: register `fn(ctx) -> List[Finding]` as a named check."""
 
@@ -253,16 +253,13 @@ def _walk_py(root: str, skip_dirs: Tuple[str, ...] = ()) -> List[str]:
 
 
 def discover(root: Optional[str] = None) -> Tuple[List[SourceFile], Context]:
-    """Auto-discovery over the live tree: the package, bench.py, and
-    tests/ (minus the fixture corpus, which exists to CONTAIN violations)."""
+    """Auto-discovery over the live tree: the package and tests/ (minus
+    the fixture corpus, which exists to CONTAIN violations)."""
     root = root or repo_root()
     files: List[SourceFile] = []
     pkg = os.path.join(root, "photon_ml_tpu")
     for p in _walk_py(pkg):
         files.append(load_file(p, "package", root))
-    bench = os.path.join(root, "bench.py")
-    if os.path.isfile(bench):
-        files.append(load_file(bench, "bench", root))
     tests = os.path.join(root, "tests")
     if os.path.isdir(tests):
         for p in _walk_py(tests, skip_dirs=("analysis_fixtures",)):

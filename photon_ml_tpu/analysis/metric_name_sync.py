@@ -3,11 +3,11 @@
 The bug class (ISSUE 11, the fault-site-sync argument applied to
 telemetry): a counter/histogram/gauge name incremented anywhere in the
 tree but missing from `utils/telemetry.METRIC_DESCRIPTIONS` is a metric
-no dashboard, profile, or bench contract can discover (and since the
+no dashboard, profile, or contract can discover (and since the
 registry is closed, it raises at runtime — on whatever rare path first
 increments it). The reverse is as bad: a declared-but-never-incremented
-name is advertised observability that does not exist, and a bench
-contract asserting it zero is asserting nothing.
+name is advertised observability that does not exist, and a contract
+asserting it zero is asserting nothing.
 
 Rules, mirrored from fault-site-sync:
 

@@ -18,9 +18,9 @@ bucket shape set) bound to a PLANNED-QUANTITY NAME is a finding, where
   * a call keyword (`batcher(max_wait_ms=1.0)`).
 
 Files under `planner/` and the registries (utils/knobs.py,
-utils/contracts.py) are the quantities' declared homes and exempt. Bench
-sections that deliberately pin a value for a measurement carry a
-reasoned `# photon-lint: disable=planner-constant — <why>` pragma —
+utils/contracts.py) are the quantities' declared homes and exempt. Code
+that deliberately pins a value carries a reasoned
+`# photon-lint: disable=planner-constant — <why>` pragma —
 the suppression is the documentation.
 """
 
@@ -108,7 +108,6 @@ def _finding(f: SourceFile, line: int, name: str, rendered: str) -> Finding:
     "planned runtime quantities (wait-ms, chunk rows, prefetch depth, "
     "fusion caps, bucket shape sets) must come from planner/ or the knob "
     "registry, not magic-number literals",
-    scopes=("package", "bench"),
 )
 def check(ctx: Context) -> List[Finding]:
     findings: List[Finding] = []

@@ -78,7 +78,6 @@ def _finding(f: SourceFile, line: int, where: str, rendered: str) -> Finding:
     "allclose-style parity comparisons take their rtol/atol from "
     "utils/contracts.py pinned tolerance tables, never inline numeric "
     "literals",
-    scopes=("package", "bench"),
 )
 def check(ctx: Context) -> List[Finding]:
     findings: List[Finding] = []

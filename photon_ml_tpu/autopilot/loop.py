@@ -69,8 +69,8 @@ class Autopilot:
 
     Construction arms nothing by itself: `start=True` (default) spawns
     the `photon-autopilot` worker ticking every `tick_ms`; `start=False`
-    leaves the loop inert for deterministic drive via `tick()` (tests,
-    bench). Explicit ctor args win; None defers to the PHOTON_AUTOPILOT_*
+    leaves the loop inert for deterministic drive via `tick()` (the
+    tests). Explicit ctor args win; None defers to the PHOTON_AUTOPILOT_*
     knobs — the same deferral every serving ctor uses.
 
     `probe_requests` maps tenant name -> a ScoreRequest whose answers
@@ -184,7 +184,7 @@ class Autopilot:
     def tick(self) -> SensorSnapshot:
         """One synchronous control-loop pass: read sensors, evaluate
         every rule against (current, previous). Returns the snapshot it
-        acted on — the deterministic drive for tests and bench."""
+        acted on — the deterministic drive for tests."""
         cur = self._sensor_fn(self.registry)
         prev, self._prev = self._prev, cur
         self._ticks += 1

@@ -18,7 +18,7 @@ event in `journal.jsonl` and the characterized parity trail in
 
 Data source: `--synthetic` draws a base dataset plus streamed delta
 batches (entity churn + brand-new entities) — the self-contained demo /
-smoke mode the bench's `continuous_loop` section mirrors. Batch size
+smoke mode tests/test_incremental.py mirrors. Batch size
 targets PHOTON_REFRESH_BATCH_ROWS (planner-routed: `refresh_batch_rows`)
 unless --batch-rows overrides; churn past
 PHOTON_REFRESH_MAX_DELTA_FRACTION of the merged rows escapes to one

@@ -489,7 +489,7 @@ def _run_with_bundle(args, bundle: ServingBundle) -> dict:
     n_failed = 0
     # Live reshard drill (--reshard-to): kicked on a background worker
     # once the first replay window has answered, so the generation flip
-    # happens UNDER traffic — the elastic_mesh bench contract, driveable
+    # happens UNDER traffic — the live-reshard contract, driveable
     # from the CLI. Joined before the summary so the outcome is recorded.
     reshard_to = getattr(args, "reshard_to", None)
     reshard_info: dict = {}

@@ -60,7 +60,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from photon_ml_tpu.data.containers import SparseFeatures
-from photon_ml_tpu.utils.knobs import get_knob
 
 Array = jax.Array
 
@@ -398,17 +397,13 @@ def _aligned_sp(mean1: float) -> Tuple[int, float, float]:
     return sp, sp / kept, frac
 
 
-_LAYOUT_ENV = "PHOTON_SPARSE_LAYOUT"
-
-
 def choose_layout(
     nnz: int, n_rows: int, dim: int, workload: str = "training"
 ) -> Tuple[bool, Optional[int]]:
     """Level-1 layout plan: (row_aligned, sp1 override or None).
 
-    PHOTON_SPARSE_LAYOUT=rowalign|grouped forces (legacy
-    PHOTON_SPARSE_ROWALIGN=1 == rowalign); auto picks per the measured
-    economics (ops/pallas_sparse.py r05/r06 notes): the aligned layout
+    PHOTON_SPARSE_LAYOUT=rowalign|grouped forces; auto picks per the
+    measured economics (ops/pallas_sparse.py r05/r06 notes): the aligned layout
     removes the forward z-scatter one-hot AND the backward u-select
     gather, but its per-lane collision padding scales the whole entry
     stream, so it engages only when the Poisson-estimated blowup stays
@@ -419,19 +414,12 @@ def choose_layout(
     """
     # Planned quantity (ISSUE 14): explicit PHOTON_SPARSE_LAYOUT wins,
     # else the installed plan's sparse_layout (the layout the profile's
-    # run measured on this hardware), else the legacy bool alias, else
-    # the Poisson economics below. planned_value normalizes the layout
-    # spellings to auto|rowalign|grouped.
+    # run measured on this hardware), else the Poisson economics below.
+    # planned_value normalizes the layout spellings to
+    # auto|rowalign|grouped.
     from photon_ml_tpu import planner
 
-    from photon_ml_tpu.utils.knobs import knob_is_set
-
-    if not knob_is_set(_LAYOUT_ENV) and get_knob("PHOTON_SPARSE_ROWALIGN"):
-        # The legacy bool alias is an explicit operator override too — it
-        # beats the plan, but stays subordinate to PHOTON_SPARSE_LAYOUT.
-        env = "rowalign"
-    else:
-        env = str(planner.planned_value("sparse_layout")).strip().lower()
+    env = str(planner.planned_value("sparse_layout")).strip().lower()
     if env == "rowalign":
         return True, None
     if env == "grouped":

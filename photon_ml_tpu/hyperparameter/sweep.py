@@ -43,8 +43,8 @@ and warm-starts each round's trials from the incumbent's coefficients
 (Snap ML's hierarchical pipelining framing: proposal, stacked solves and
 result streaming stay concurrent workstreams). `finalize()` re-fits the
 winning config COLD so the returned winner model is bitwise-equal to a
-standalone fit of that config regardless of warm starting — the bench
-`sweep` section's contract.
+standalone fit of that config regardless of warm starting
+(tests/test_sweep.py::TestExecutorSurface).
 """
 
 from __future__ import annotations
@@ -84,7 +84,7 @@ _STACK_BUDGET_FRACTION = 0.25
 @dataclasses.dataclass
 class TrialRecord:
     """One evaluated trial (the executor's per-trial telemetry record —
-    zipped into the bench section via contracts.SWEEP_TRIAL_KEYS)."""
+    exported through contracts.SWEEP_TRIAL_KEYS)."""
 
     trial: int
     round: int
@@ -207,7 +207,7 @@ class SweepExecutor:
 
     def reset(self) -> None:
         """Forget every evaluated trial but KEEP compiled programs and
-        group contexts — the bench warm-up hook: compile the round
+        group contexts — the warm-up hook: compile the round
         programs on throwaway candidates, reset, then run the measured
         sweep against warm programs (the standard timed-not-equal-warm-up
         protocol)."""

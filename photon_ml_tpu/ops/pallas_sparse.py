@@ -92,8 +92,8 @@ choice moved into a planner (data/bucketed.choose_layout): Poisson
 collision economics pick row-aligned level 1 only when its adaptive-width
 blowup stays under ROWALIGN_MAX_BLOWUP (bench shape: stays grouped at
 blowup 2.0 — correctly), level 2 is always grouped, and
-PHOTON_SPARSE_LAYOUT=rowalign|grouped forces either way (legacy
-PHOTON_SPARSE_ROWALIGN=1 == rowalign). Both layouts decode identically
+PHOTON_SPARSE_LAYOUT=rowalign|grouped forces either way, ahead of the
+installed plan's sparse_layout. Both layouts decode identically
 (to_coo/XLA fallbacks branch on the flag) and the fused kernel runs
 either end-to-end.
 """

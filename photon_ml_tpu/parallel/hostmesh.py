@@ -872,7 +872,7 @@ def supervise(
     attempt_timeout_s: float = 900.0,
 ) -> SuperviseResult:
     """The production relaunch loop behind `cli/train --multihost` (and
-    the serve/bench chaos drills): spawn one worker process per host,
+    the serve chaos drills): spawn one worker process per host,
     classify exits, and on a whole-host loss relaunch the SURVIVOR set —
     each attempt gets a fresh coordinator port and a fresh
     `rendezvous/attempt<k>/` namespace (barriers, heartbeats, ingest

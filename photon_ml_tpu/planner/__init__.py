@@ -29,8 +29,6 @@ from photon_ml_tpu.planner.plan import (  # noqa: F401
     inactive_block,
     install_plan,
     plan_block,
-    plan_suppressed,
-    plan_suppression_active,
     planned_value,
     uninstall_plan,
 )
@@ -63,8 +61,6 @@ __all__ = [
     "plan_from_calibration",
     "plan_from_profile",
     "plan_mode",
-    "plan_suppressed",
-    "plan_suppression_active",
     "planned_value",
     "uninstall_plan",
 ]

@@ -33,7 +33,6 @@ quantity is re-hard-coded as a magic number anywhere else in the package.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import logging
 import threading
@@ -80,9 +79,6 @@ DEFAULTS: Dict[str, object] = {
     # ladder up to it) and the micro-batcher's partial-batch flush wait.
     "serving_max_batch": 256,
     "serving_max_wait_ms": 2.0,
-    # bench.py scoring section: lax.scan rep count whose rtt correction
-    # measured <5% of wall (the adaptation result a repeat round reuses).
-    "bench_score_reps": 64,
 }
 
 # Scan-fuse cap for RE bucket shapes the plan's profile never proved on
@@ -214,39 +210,11 @@ def inactive_block() -> Dict[str, object]:
 
 
 # ------------------------------------------------------------ ambient plan
-# One plan per process, installed by the CLI drivers / bench / estimator
-# startup and consulted by the decision sites. A module global guarded by
+# One plan per process, installed by the CLI drivers / estimator startup
+# and consulted by the decision sites. A module global guarded by
 # a lock (install/uninstall only; reads are a single attribute load).
 _LOCK = threading.Lock()
 _ACTIVE: Optional[Plan] = None
-# Suppression depth (plan_suppressed): >0 forces every consult back to
-# the built-in defaults and makes ensure_ambient_plan a no-op —
-# process-wide (not thread-local) because consults happen on prepare-pool
-# worker threads too.
-_SUPPRESS = 0
-
-
-@contextlib.contextmanager
-def plan_suppressed():
-    """Scope that measures the HAND-TUNED DEFAULT config: inside it,
-    planned_value ignores any installed plan and any PHOTON_PLAN*
-    configuration (explicit per-quantity knobs still win — they are
-    operator intent, not planning), ensure_ambient_plan installs
-    nothing, and plan_block() reads inactive. The bench planner
-    section's pilot fits run under this so a repeat round with
-    PHOTON_PLAN_PROFILE set cannot silently plan its own baseline."""
-    global _SUPPRESS
-    with _LOCK:
-        _SUPPRESS += 1
-    try:
-        yield
-    finally:
-        with _LOCK:
-            _SUPPRESS -= 1
-
-
-def plan_suppression_active() -> bool:
-    return _SUPPRESS > 0
 
 
 def install_plan(plan: Plan) -> Plan:
@@ -296,16 +264,13 @@ def apply_online_decision(
     `source="autopilot"` plan when none is active) where every future
     `planned_value` consult sees it, and is journaled as a
     `plan_decision` with `source: "autopilot"` like any other decision.
-    Under `plan_suppressed` (the hand-tuned-default measurement scope)
-    this is a no-op. Returns the applied PlanDecision, whose `fallback`
-    is the value the decision displaced — what a rollback restores."""
+    Returns the applied PlanDecision, whose `fallback` is the value the
+    decision displaced — what a rollback restores."""
     global _ACTIVE
     from photon_ml_tpu.utils import telemetry
 
     knob = KNOB_FOR.get(name)
     if knob is not None and knob_is_set(knob):
-        return None
-    if plan_suppression_active():
         return None
     with _LOCK:
         plan = _ACTIVE
@@ -355,7 +320,7 @@ def plan_block(
     operator intent exactly like an env knob, and the audit trail must
     show what the run actually ran with, not what the plan proposed."""
     plan = current_plan()
-    if plan is None or plan_suppression_active():
+    if plan is None:
         return inactive_block()
     block = plan.block()
     if overrides:
@@ -393,10 +358,9 @@ def planned_value(name: str, *, default: object = _UNSET) -> object:
     knob = KNOB_FOR.get(name)
     if knob is not None and knob_is_set(knob):
         return normalize(name, get_knob(knob))
-    if not plan_suppression_active():
-        plan = current_plan()
-        if plan is not None and name in plan.decisions:
-            return plan.decisions[name].value
+    plan = current_plan()
+    if plan is not None and name in plan.decisions:
+        return plan.decisions[name].value
     if default is not _UNSET:
         return default
     return default_for(name)

@@ -22,10 +22,10 @@ prefetch is an async upload of data that uploads anyway).
 
 `plan_from_calibration` is the cold-start path for a run with no profile
 (PHOTON_PLAN=1): a fast startup probe — host parallelism, backend, a
-small host->device bandwidth / dispatch round-trip measurement, the same
-roofline vocabulary bench.py records — feeding the subset of rules that
-need no stage history. `ensure_ambient_plan` is the one gate the CLI
-drivers, bench, and the estimator call: explicit `--profile` beats
+small host->device bandwidth / dispatch round-trip measurement —
+feeding the subset of rules that need no stage history.
+`ensure_ambient_plan` is the one gate the CLI drivers and the estimator
+call: explicit `--profile` beats
 `PHOTON_PLAN_PROFILE`, `PHOTON_PLAN=0` kills everything, and an
 r06-era profile (no `plan` block) still loads — the block is provenance,
 not a requirement.
@@ -48,7 +48,6 @@ from photon_ml_tpu.planner.plan import (
     default_for,
     install_plan,
     normalize,
-    plan_suppression_active,
 )
 from photon_ml_tpu.utils.knobs import _FALSE, _TRUE, get_knob, knob_is_set
 
@@ -277,19 +276,6 @@ def plan_from_profile(
             },
         )
 
-        # -- bench scoring rep count: a prior round's rtt<5% adaptation
-        # result, persisted so repeat rounds start calibrated (recorded
-        # by bench.py into the e2e profile's dispatch block).
-        reps = dispatch.get("bench_score_reps")
-        if reps is not None:
-            _decide(
-                decisions,
-                "bench_score_reps",
-                max(1, int(reps)),  # a corrupt profile must not plan 0
-                src,
-                {"adapted_by": "bench scoring rtt<5% loop"},
-            )
-
     else:  # serve profile
         serving = dict(profile.get("serving") or {})
 
@@ -391,8 +377,8 @@ def plan_from_profile(
 def calibration_probe() -> Dict[str, object]:
     """The fast cold-start measurement (no profile): backend + effective
     host parallelism + one small host->device upload bandwidth / dispatch
-    round-trip sample — the roofline vocabulary bench.py records, cheap
-    enough for startup (<~1s, one tiny compile)."""
+    round-trip sample, cheap enough for startup (<~1s, one tiny
+    compile)."""
     from photon_ml_tpu.data.pipeline import effective_host_parallelism
     from photon_ml_tpu.utils.telemetry import device_topology
 
@@ -487,14 +473,12 @@ def plan_mode() -> Optional[bool]:
 
 
 def ensure_ambient_plan(profile_path: Optional[str] = None) -> Optional[Plan]:
-    """The one planner gate (CLI drivers / bench / estimator startup):
+    """The one planner gate (CLI drivers / estimator startup):
     install a plan if configuration asks for one and none is installed.
     Explicit `profile_path` (--profile) beats PHOTON_PLAN_PROFILE;
     PHOTON_PLAN=0 disables everything; topology mismatches and broken
     profiles refuse LOUDLY (a mis-planned run is worse than an unplanned
     one). Returns the active plan, or None when planning is off."""
-    if plan_suppression_active():
-        return None
     active = current_plan()
     if active is not None:
         return active
@@ -503,9 +487,9 @@ def ensure_ambient_plan(profile_path: Optional[str] = None) -> Optional[Plan]:
         return None
     path = profile_path or str(get_knob("PHOTON_PLAN_PROFILE")).strip()
     if path and profile_path is None and not os.path.exists(path):
-        # PHOTON_PLAN_PROFILE is a cache HANDLE, not only an input: bench
-        # (and any repeat-round workflow) points it at the path the run
-        # will WRITE its profile to, so on the first round the file does
+        # PHOTON_PLAN_PROFILE is a cache HANDLE, not only an input: a
+        # repeat-round workflow points it at the path the run will WRITE
+        # its profile to, so on the first round the file does
         # not exist yet. Run unplanned and let this round populate it —
         # but an explicit --profile argument stays loud: the operator
         # named a specific artifact, and a missing one is an error.

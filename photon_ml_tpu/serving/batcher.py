@@ -607,8 +607,8 @@ class MicroBatcher:
 
     def metrics(self) -> Dict[str, object]:
         """One snapshot: request latency percentiles + qps + admission/
-        deadline/circuit accounting + the engine's counters. Keys are the
-        serving_online bench contract."""
+        deadline/circuit accounting + the engine's counters. Keys are
+        contracts.SERVING_METRIC_KEYS and the engine's own."""
         with self._cv:
             completed = self._completed
             failed = self._failed

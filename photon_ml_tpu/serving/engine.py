@@ -816,7 +816,7 @@ class ServingEngine:
 
     def _sharding_metrics(self, state: _EngineState) -> Dict[str, object]:
         """The serving sharding decision as proper JSON keys (the
-        serving-summary/bench contract): mesh axis size, peak coefficient
+        serving-summary contract): mesh axis size, peak coefficient
         rows resident per shard, two-tier hot-set fraction, and the
         analytic collective bytes one max_batch bucket moves."""
         from photon_ml_tpu.parallel.mesh import bcast_gather_wire_bytes
@@ -847,7 +847,7 @@ class ServingEngine:
                 rows_per_shard = max(rows_per_shard, int(c.params.shape[0]))
         # Explicit keys (immune to schema-tuple reorders), checked against
         # the shared schema so the producer cannot drift from what
-        # bench/serve assert on.
+        # cli/serve and the tests assert on.
         from photon_ml_tpu.utils.contracts import SERVING_SHARDING_KEYS
 
         with self._lock:
@@ -878,7 +878,7 @@ class ServingEngine:
         """Compiles since warmup(), or None when warmup never ran — a 0
         here must MEAN zero hot-path compiles, not 'nobody measured'; an
         un-warmed engine compiling on live traffic has no baseline to
-        count from, and None trips the bench's missing-key contract."""
+        count from, and None trips the summary's missing-key contract."""
         with self._lock:
             base = self._warmup_compiles
         return None if base is None else max(0, self.compiles - base)
@@ -923,7 +923,7 @@ class ServingEngine:
             }
         # Pod-scale accounting: the sharding decision this bundle serves
         # under + the two-tier store counters (all keys always present —
-        # 0/False on a single-tier replicated bundle — so the bench/summary
+        # 0/False on a single-tier replicated bundle — so the summary's
         # missing-key contract can be loud).
         out["sharding"] = self._sharding_metrics(st)
         tier = {

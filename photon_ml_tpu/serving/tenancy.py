@@ -1549,8 +1549,8 @@ class TenantRegistry:
     def metrics(self) -> Dict[str, object]:
         """One snapshot: registry-level co-batch accounting plus a
         per-tenant block zipping TENANT_BLOCK_KEYS (the serving-summary
-        `tenants` block and the bench multi_tenant section both consume
-        it — every key always present so absence is loud)."""
+        `tenants` block consumes it:
+        every key always present so absence is loud)."""
         with self._cv:
             tenants = list(self._tenants.values())
             cobatch = self._cobatch_dispatches

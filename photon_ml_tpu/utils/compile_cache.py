@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, placed from outside or at one fixed path.
 
 Every entry point that compiles (cli/train, cli/serve, cli/score,
-cli/refresh, the bench child, chip_smoke.py) calls `enable()` before its
+cli/refresh, chip_smoke.py) calls `enable()` before its
 first compile. Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it
 itself and no directory is set here; where it is not, the cache lives at
 `<checkout>/.jax_cache` — a fixed path, because the path is part of what a

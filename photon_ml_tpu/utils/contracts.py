@@ -1,11 +1,10 @@
 """The repo's loud-contract key schemas, in one place.
 
-Every bench section and serving summary enforces a "loud missing-key"
-contract: an artifact that silently lost a metric is a measurement bug,
-so the producer fails the run rather than ship it (bench.py, PR 1/4/7).
-Until r08 the required-key tuples were re-typed at every enforcement
-site — bench, the estimator, the serving engine, and the tests each
-carried their own copy, which is exactly how a renamed key drifts out of
+Every fit timing, run profile, journal line and serving summary enforces
+a "loud missing-key" contract: an artifact that silently lost a metric
+is a measurement bug, so the producer fails the run rather than ship it.
+A required-key tuple re-typed at an enforcement site — the estimator,
+the serving engine, a test — is exactly how a renamed key drifts out of
 one copy and the contract silently stops checking it. These tuples are
 the single source of truth; the static analyzer's `contract-key-drift`
 check (photon_ml_tpu/analysis/) fails the build when any other file
@@ -15,8 +14,7 @@ Producers build dicts from these tuples (e.g. the serving engine zips
 SERVING_SHARDING_KEYS); consumers assert against them. Key ORDER in the
 zipped producers is part of the schema — append, don't reorder.
 
-Stdlib-only on purpose: bench's child processes and the analyzer both
-import this before jax is up.
+Stdlib-only on purpose: the analyzer imports this before jax is up.
 """
 
 from __future__ import annotations
@@ -126,48 +124,6 @@ INGEST_TIMING_REQUIRED_KEYS = (
     "chunks",
 )
 
-# ------------------------------------------------------------ bench sections
-# bench.py multichip section (r07): the pod-scale over-HBM certificate.
-MULTICHIP_SECTION_KEYS = (
-    "n_devices",
-    "budget_bytes_per_device",
-    "re_matrix_bytes",
-    "max_shard_bytes",
-    "per_batch_wall_ms",
-    "collective_bytes_per_batch",
-    "collective_bytes_per_sweep",
-    "sharding",
-    "serving_sharding",
-    "serve_bitwise_vs_replicated",
-    "overlap_train_max_rel_dw",
-    "overlap_serve_sharded_bitwise",
-    "overlap_serve_two_tier_bitwise",
-)
-
-# bench.py multihost_chaos section (ISSUE 17): the DCN-scale production
-# certificate — a 2-OS-process fit must be bitwise-equal to the
-# single-process fit of the same data at the same global device count,
-# each host ingesting only its own disjoint file set; SIGKILLing one host
-# mid-sweep must resume on the survivor set with exactly one repeated
-# sweep (host_losses == 1); SIGKILLing one serve host mid-replay must
-# answer every request (lost-host rows FE-only, resident rows bitwise);
-# and the DCN collective traffic the entity-sharded sweep moved is
-# reported.
-MULTIHOST_SECTION_KEYS = (
-    "n_hosts",
-    "devices_per_host",
-    "files_per_host",
-    "fit_bitwise_vs_single_process",
-    "ingest_disjoint_ok",
-    "host_losses",
-    "repeated_sweeps",
-    "survivor_hosts",
-    "failed_requests",
-    "fe_only_answers",
-    "serve_bitwise_resident",
-    "dcn_collective_bytes",
-)
-
 # ------------------------------------------------------------------- serving
 # Latency/quality metrics a serving run must report (batcher.metrics()).
 SERVING_METRIC_KEYS = (
@@ -196,7 +152,7 @@ SERVING_SHARDING_KEYS = (
 )
 
 # Robustness events that must be ZERO on a clean (un-faulted,
-# un-overloaded) serving run — bench's clean-run zero contract (PR 5).
+# un-overloaded) serving run — the clean-run zero contract (PR 5).
 SERVING_CLEAN_ZERO_KEYS = (
     "shed",
     "deadline_missed",
@@ -208,8 +164,8 @@ SERVING_CLEAN_ZERO_KEYS = (
 # must be ZERO on a clean run: collective re-dispatches, per-shard
 # staging retries, failed two-tier promotions, and watchdog trips — plus
 # the live-elasticity events (ISSUE 13): mesh losses recovered mid-fit
-# and reshard staging retries/rollbacks. The bench clean-run contract
-# reads these from faults.COUNTERS; fit_timing ("robustness") and
+# and reshard staging retries/rollbacks. The clean-run contract reads
+# these from faults.COUNTERS; fit_timing ("robustness") and
 # serving-summary.json ("robustness_counters") always carry every key so
 # absence is loud.
 ROBUSTNESS_CLEAN_ZERO_KEYS = (
@@ -244,7 +200,7 @@ ROBUSTNESS_CLEAN_ZERO_KEYS = (
     "autopilot_quarantines",
     # ISSUE 20: precision-tier ladder — a clean fit/replay never walks
     # the ladder, so demotions, restores, AND rollbacks are all zero;
-    # a bench ladder drill asserts the exact non-zero counts it caused.
+    # a ladder drill asserts the exact non-zero counts it caused.
     "tier_demotions",
     "tier_restores",
     "tier_rollbacks",
@@ -289,9 +245,8 @@ BUNDLE_PROVENANCE_KEYS = (
 
 # -------------------------------------------------------------- multi-tenant
 # Per-tenant metrics block (serving/tenancy.TenantRegistry.metrics() zips
-# exactly these per tenant — the serving-summary "tenants" block and the
-# bench multi_tenant section both consume it; every key always present so
-# absence is loud).
+# exactly these per tenant — the serving-summary "tenants" block
+# consumes it; every key always present so absence is loud).
 TENANT_BLOCK_KEYS = (
     "completed",
     "failed",
@@ -314,8 +269,8 @@ TENANT_BLOCK_KEYS = (
 
 # Per-tenant precision-ladder sub-block (ISSUE 20): nested under the
 # tenant block's "tier" key — the tenant's current rung plus its ladder
-# history, so serving-summary.json and the bench multi_tenant section can
-# audit HOW a tenant got to the precision it serves at. "tier" is the
+# history, so serving-summary.json can audit HOW a tenant got to the
+# precision it serves at. "tier" is the
 # rung name ("f32"/"bf16"/"int8"; the host rung keeps the tenant's last
 # quantized rung beside demoted=True), "quantized_coords" counts RE
 # coordinates currently serving dequantized rows, and "quant_error_max"
@@ -375,102 +330,13 @@ CHIP_SMOKE_SERVING_TOLERANCE = {"rtol": 1e-5, "atol": 1e-5}
 # iterative solve, where a last-ulp difference in one gradient is amplified
 # through line searches over the remaining iterations. "serve": one
 # bucket program's margins (a gather and a row reduce — reduction-order
-# noise only). chip_smoke.py --four-chips holds the chip to these; ROADMAP
-# D0 reuses them for the sharded-vs-replicated tests on the CPU mesh.
+# noise only). chip_smoke.py --four-chips holds the chip to these, and
+# tests/conftest.assert_sharded_close the sharded-vs-single tests on the
+# CPU mesh.
 SHARDED_VS_SINGLE_TOLERANCES = {
     "fit": {"rtol": 5e-3, "atol": 5e-4},
     "serve": {"rtol": 1e-5, "atol": 1e-5},
 }
-
-# bench.py multi_tenant section (ISSUE 15): the serving-platform
-# isolation certificate — 10 tenant bundles on one 8-virtual-device
-# fleet; injected faults, hangs, and overload confined to ONE chaos
-# tenant while every clean tenant answers with zero failed requests,
-# admitted-p99 within its deadline, and scores bitwise-equal to serving
-# that tenant alone; and a cold tenant demoted to the host tier under
-# HBM pressure (so an over-budget admission succeeds) still answers
-# bitwise.
-MULTI_TENANT_SECTION_KEYS = (
-    "n_devices",
-    "n_tenants",
-    "chaos_tenant",
-    "injected_faults",
-    "chaos_shed",
-    "chaos_hangs",
-    "clean_requests",
-    "clean_failed_requests",
-    "clean_deadline_misses",
-    "clean_degraded_batches",
-    "clean_p99_within_deadline",
-    "clean_bitwise_vs_solo",
-    "cobatch_dispatches",
-    "demoted_tenant",
-    "admitted_over_budget",
-    "evicted_bitwise",
-    "tenants",
-    # ISSUE 20: the precision-ladder HBM-squeeze drill — how many tenants
-    # the ladder fit on the same fleet vs. f32-only residency, whether
-    # quantized replay stayed within TIER_TOLERANCES, that every ladder
-    # transition completed with zero failed requests, and that a tenant
-    # walked down and back answers bitwise vs. its pre-demotion self.
-    "ladder_resident_tenants",
-    "f32_capacity_tenants",
-    "ladder_capacity_ratio",
-    "quantized_within_tolerance",
-    "ladder_failed_requests",
-    "ladder_transitions",
-    "ladder_restored_bitwise",
-)
-
-# bench.py chaos_multichip section (r10): the pod-scale chaos
-# certificate — an 8-virtual-device subprocess with every mesh fault
-# site armed must degrade/retry without failing a fit or a request, and
-# recover to bitwise serve parity.
-CHAOS_MULTICHIP_SECTION_KEYS = (
-    "n_devices",
-    "faults_armed",
-    "injected_faults",
-    "collective_retries",
-    "shard_upload_retries",
-    "promote_failures",
-    "watchdog_trips",
-    "failed_requests",
-    "hangs",
-    "train_bitwise_vs_clean",
-    "resume_bitwise_vs_train",
-    "serve_bitwise_vs_clean",
-    "shard_loss_fe_only_bitwise",
-    "post_recovery_bitwise",
-    "shard_loss_fallbacks",
-    "restaged_bytes",
-)
-
-# bench.py elastic_mesh section (ISSUE 13): the live-elasticity
-# certificate — an 8-shard serving engine shrinks to 4 and regrows to 8
-# UNDER LIVE REPLAY with zero failed requests and post-reshard scores
-# bitwise-equal to a cold start at the new shape; a hot-row rebalance
-# driven by observed promotion stats flips the same way; and a mid-fit
-# mesh loss resumes bitwise-equal to the uninterrupted fit at the cost of
-# exactly one repeated sweep. The clean (un-injected) phases must leave
-# every reshard/mesh-loss counter at zero.
-ELASTIC_MESH_SECTION_KEYS = (
-    "n_devices",
-    "shrink_to",
-    "moved_rows_shrink",
-    "moved_bytes_shrink",
-    "answered_during_shrink",
-    "answered_during_regrow",
-    "failed_requests",
-    "shrink_bitwise_vs_cold",
-    "regrow_bitwise_vs_cold",
-    "rebalanced_rows",
-    "rebalance_bitwise",
-    "cold_tier_hits_before_rebalance",
-    "cold_tier_hits_after_rebalance",
-    "midfit_repeated_sweeps",
-    "midfit_bitwise_vs_uninterrupted",
-    "clean_counters_zero",
-)
 
 # ------------------------------------------------------- incremental refresh
 # The delta-bundle manifest (serving/delta.DeltaBundle.manifest zips
@@ -486,31 +352,6 @@ DELTA_BUNDLE_KEYS = (
     "bytes",
 )
 
-# bench.py continuous_loop section (ISSUE 16): the data->served freshness
-# certificate — an 8-virtual-device subprocess runs a full fit, streams a
-# delta batch, re-solves only the changed coordinate's changed entities
-# (unchanged entities bitwise-equal to a from-scratch fit of the merged
-# data), and flips the live engine to the new generation via a delta
-# bundle UNDER LIVE REPLAY with zero failed requests — reporting the
-# data->served wall against the full-refit+full-restage baseline on the
-# same delta.
-CONTINUOUS_SECTION_KEYS = (
-    "n_devices",
-    "total_rows",
-    "delta_rows",
-    "delta_fraction",
-    "changed_coordinates",
-    "full_fit_s",
-    "incremental_fit_s",
-    "delta_apply_s",
-    "data_to_served_s",
-    "full_refresh_baseline_s",
-    "speedup_vs_full",
-    "unchanged_entities_bitwise",
-    "answered_during_refresh",
-    "failed_requests",
-    "generation",
-)
 
 # --------------------------------------------------------- shadow deployment
 # The shadow block inside serving-summary.json (ISSUE 18):
@@ -537,31 +378,6 @@ SHADOW_BLOCK_KEYS = (
     "generation",
 )
 
-# bench.py shadow_deploy section (ISSUE 18): the online-quality-gate
-# certificate — a deliberately degraded challenger (label-noised refit)
-# is detected and rolled back from shadow metrics ALONE while the
-# champion answers every request bitwise-vs-solo with zero failures; a
-# healthy challenger promotes through the atomic BundleManager
-# generation flip; mirror faults degrade to champion-only serving (never
-# a failed client request); and a SIGKILL mid-promotion leaves the old
-# champion serving its old generation bitwise.
-SHADOW_SECTION_KEYS = (
-    "n_devices",
-    "mirrored_requests",
-    "shadow_cobatched",
-    "degraded_detected",
-    "degraded_windows",
-    "degraded_rolled_back",
-    "degraded_champion_failed",
-    "degraded_champion_bitwise",
-    "healthy_promoted",
-    "promoted_generation",
-    "post_promote_bitwise",
-    "mirror_faults_injected",
-    "mirror_fault_champion_clean",
-    "sigkill_champion_bitwise",
-    "clean_counters_zero",
-)
 
 # ---------------------------------------------------------------- autopilot
 # The closed-loop controller block (ISSUE 19, photon_ml_tpu/autopilot/):
@@ -585,56 +401,9 @@ AUTOPILOT_BLOCK_KEYS = (
     "last_outcome",
 )
 
-# bench.py autopilot section (ISSUE 19): the self-operation certificate —
-# a load shift between two live tenants triggers automatic reshard +
-# hot-row rebalance with zero failed requests and recovered p99, an
-# induced HBM squeeze demotes the cold tenant and later restores it
-# bitwise, and a deliberately bad rule is rolled back and quarantined by
-# the post-action probe — every decision journaled with its evidence and
-# the clean-phase autopilot counters zero.
-AUTOPILOT_SECTION_KEYS = (
-    "n_devices",
-    "ticks",
-    "load_shift_detected",
-    "reshard_actions",
-    "rebalance_actions",
-    "failed_requests",
-    "p99_recovered",
-    "hbm_demoted",
-    "hbm_restored_bitwise",
-    "bad_rule_rolled_back",
-    "bad_rule_quarantined",
-    "decisions_journaled",
-    "decisions_valid",
-    "clean_counters_zero",
-)
-
 # -------------------------------------------------------------------- sweep
-# bench.py `sweep` section (ISSUE 12): the pod-parallel hyperparameter
-# sweep certificate — a 16-trial Bayesian sweep through the batched trial
-# executor must beat the serial estimator.fit-per-trial loop (the
-# GameTrainingDriver-inherited path) by >10x wall, with the winner's
-# refit model bitwise-equal to a standalone fit of the winning config and
-# the clean-run robustness counters all zero.
-SWEEP_SECTION_KEYS = (
-    "trials",
-    "rounds",
-    "batch_size",
-    "modes",
-    "stack_decisions",
-    "trial_timings",
-    "sweep_wall_s",
-    "winner_refit_s",
-    "serial_baseline_wall_s",
-    "speedup_vs_serial",
-    "best_point",
-    "winner_value",
-    "winner_bitwise_vs_standalone",
-    "robustness",
-)
-
-# Per-trial timing record inside the sweep section (and the shape of the
-# executor's TrialRecord export): every evaluated trial reports its round,
+# Per-trial timing record (the shape of the executor's TrialRecord export,
+# hyperparameter/sweep.py): every evaluated trial reports its round,
 # execution mode, wall seconds (stacked rounds amortize the one-dispatch
 # round wall across their trials), value, and divergence-guard count.
 SWEEP_TRIAL_KEYS = (
@@ -727,8 +496,7 @@ JOURNAL_EVENT_SCHEMAS = {
 # The persisted run profile (utils/telemetry.build_profile/read_profile):
 # the machine-readable artifact the adaptive-runtime planner consumes.
 # Every profile carries the common keys; fit and serve runs add their
-# kind's sections. read_profile enforces these loudly — bench.py writes
-# its e2e fit profile and re-reads it through the same contract.
+# kind's sections. read_profile enforces these loudly.
 PROFILE_REQUIRED_KEYS = (
     "kind",
     "wall_s",
@@ -753,22 +521,6 @@ PROFILE_SERVE_KEYS = (*PROFILE_REQUIRED_KEYS, "serving")
 PLAN_BLOCK_KEYS = ("active", "source", "profile", "decisions")
 PLAN_DECISION_KEYS = ("decision", "value", "source", "evidence", "fallback")
 
-# bench.py `planner` section (r07): the adaptive-planner certificate — a
-# pilot fit's persisted profile plans a second, planner-on fit that must
-# be no slower end-to-end than the hand-tuned default (and bitwise-equal
-# to it: every planned quantity is bitwise-neutral on a matching
-# topology), the plan block must round-trip through write_profile /
-# read_profile unchanged, and a topology-mutated profile must refuse.
-PLANNER_SECTION_KEYS = (
-    "default_wall_s",
-    "planned_wall_s",
-    "wall_ratio",
-    "decisions",
-    "sources",
-    "plan_vs_default_bitwise",
-    "profile_roundtrip_ok",
-    "topology_guard_ok",
-)
 
 # Every schema this module exports, for the analyzer's drift check and
 # for tests that want to iterate all contracts.
@@ -778,8 +530,6 @@ ALL_CONTRACTS = {
     "FIT_TIMING_REQUIRED_KEYS": FIT_TIMING_REQUIRED_KEYS,
     "INGEST_STAGES": INGEST_STAGES,
     "INGEST_TIMING_REQUIRED_KEYS": INGEST_TIMING_REQUIRED_KEYS,
-    "MULTICHIP_SECTION_KEYS": MULTICHIP_SECTION_KEYS,
-    "MULTIHOST_SECTION_KEYS": MULTIHOST_SECTION_KEYS,
     "SERVING_METRIC_KEYS": SERVING_METRIC_KEYS,
     "SERVING_SHARDING_KEYS": SERVING_SHARDING_KEYS,
     "SERVING_CLEAN_ZERO_KEYS": SERVING_CLEAN_ZERO_KEYS,
@@ -789,15 +539,8 @@ ALL_CONTRACTS = {
     "TENANT_BLOCK_KEYS": TENANT_BLOCK_KEYS,
     "TIER_BLOCK_KEYS": TIER_BLOCK_KEYS,
     "DELTA_BUNDLE_KEYS": DELTA_BUNDLE_KEYS,
-    "CONTINUOUS_SECTION_KEYS": CONTINUOUS_SECTION_KEYS,
-    "MULTI_TENANT_SECTION_KEYS": MULTI_TENANT_SECTION_KEYS,
     "SHADOW_BLOCK_KEYS": SHADOW_BLOCK_KEYS,
-    "SHADOW_SECTION_KEYS": SHADOW_SECTION_KEYS,
     "AUTOPILOT_BLOCK_KEYS": AUTOPILOT_BLOCK_KEYS,
-    "AUTOPILOT_SECTION_KEYS": AUTOPILOT_SECTION_KEYS,
-    "CHAOS_MULTICHIP_SECTION_KEYS": CHAOS_MULTICHIP_SECTION_KEYS,
-    "ELASTIC_MESH_SECTION_KEYS": ELASTIC_MESH_SECTION_KEYS,
-    "SWEEP_SECTION_KEYS": SWEEP_SECTION_KEYS,
     "SWEEP_TRIAL_KEYS": SWEEP_TRIAL_KEYS,
     "JOURNAL_LINE_KEYS": JOURNAL_LINE_KEYS,
     "PROFILE_REQUIRED_KEYS": PROFILE_REQUIRED_KEYS,
@@ -805,5 +548,4 @@ ALL_CONTRACTS = {
     "PROFILE_SERVE_KEYS": PROFILE_SERVE_KEYS,
     "PLAN_BLOCK_KEYS": PLAN_BLOCK_KEYS,
     "PLAN_DECISION_KEYS": PLAN_DECISION_KEYS,
-    "PLANNER_SECTION_KEYS": PLANNER_SECTION_KEYS,
 }

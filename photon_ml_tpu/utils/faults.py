@@ -46,11 +46,10 @@ module is the shared machinery:
   `shard_upload_retries` / `promote_failures` / `watchdog_trips` /
   `shard_loss_fallbacks` and the elastic-mesh counters `mesh_losses` /
   `reshard_retries` / `reshard_rollbacks` / `rebalanced_rows` — the ones
-  in contracts.ROBUSTNESS_CLEAN_ZERO_KEYS are additionally enforced
-  all-zero by the bench clean-run contract). Zero on a clean
-  run by construction, so a nonzero
-  value in a bench artifact (bench.py e2e_from_disk) is a loud robustness
-  regression signal, and tests assert exact counts.
+  in contracts.ROBUSTNESS_CLEAN_ZERO_KEYS are additionally carried by
+  every fit_timing and serving summary). Zero on a clean run by
+  construction, so a nonzero value in a run's artifact is a loud
+  robustness regression signal, and tests assert exact counts.
 
 Everything here changes only WHETHER work is retried/degraded, never what
 it computes: a run under injected transient faults must produce the same
@@ -404,8 +403,8 @@ class _Counters:
         return telemetry.METRICS.counters()
 
     def reset(self) -> None:
-        # Counters ONLY: bench resets fault counters at section
-        # boundaries and must not wipe unrelated histogram/gauge state.
+        # Counters ONLY: a reset between the phases of a run must not
+        # wipe unrelated histogram/gauge state.
         telemetry.METRICS.reset_counters()
 
 

@@ -4,7 +4,7 @@ The reference stack carries its configuration through typed Scala case
 classes (photon-api GameTrainingDriver params), so a knob cannot exist
 without a declared type, default, and docstring. The TPU port grew its
 knobs one `os.environ.get` at a time — ~27 raw reads scattered across the
-data plane, kernels, solver, serving tier, and bench by r07 — exactly the
+data plane, kernels, solver and serving tier by r07 — exactly the
 "untracked config knobs silently rot tuning decisions" failure mode the
 Spark-ML performance study (PAPERS.md) documents. This module is the
 single choke point:
@@ -202,13 +202,6 @@ _register(
     choices=("", "auto", "rowalign", "row_aligned", "aligned", "grouped", "feature", "legacy"),
 )
 _register(
-    "PHOTON_SPARSE_ROWALIGN",
-    bool,
-    False,
-    "Legacy alias: 1 == PHOTON_SPARSE_LAYOUT=rowalign (ignored when "
-    "PHOTON_SPARSE_LAYOUT is set).",
-)
-_register(
     "PHOTON_DISABLE_NATIVE",
     bool,
     False,
@@ -337,7 +330,7 @@ _register(
     "Hang-watchdog deadline (ms) armed around scanned-sweep and serving "
     "device dispatches; an over-deadline dispatch raises a typed "
     "DeviceHang (sweep re-dispatch / serving FE-only degradation). 0 = "
-    "off (bench arms it for its chaos sections).",
+    "off.",
 )
 _register(
     "PHOTON_COLLECTIVE_RETRIES",
@@ -601,21 +594,6 @@ _register(
     str,
     "cpu",
     "Backend the test harness forces before jax init (tests/conftest.py).",
-)
-
-# --------------------------------------------------------------------- bench
-_register(
-    "PHOTON_BENCH_E2E_ROWS",
-    int,
-    20_000_000,
-    "Row count for the bench e2e_from_disk section.",
-)
-_register(
-    "PHOTON_BENCH_VDEV_BUDGET",
-    int,
-    1 << 20,
-    "Per-virtual-device byte budget for the bench multichip over-HBM "
-    "certificate.",
 )
 
 

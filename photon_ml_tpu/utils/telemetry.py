@@ -35,8 +35,8 @@ Four coordinated parts:
   (`METRICS`). Histograms use FIXED log-spaced bucket bounds
   (`BUCKET_BOUNDS`, 16 per decade over 1e-4..1e7) shared by every
   histogram in every process, so snapshots merge associatively and
-  order-independently across threads and across the bench's multichip /
-  chaos subprocesses (`merge_histogram_snapshots`). Metric NAMES are a
+  order-independently across threads and across processes
+  (`merge_histogram_snapshots`). Metric NAMES are a
   closed registry (`METRIC_DESCRIPTIONS`, the `SITE_DESCRIPTIONS`
   discipline): incrementing an undeclared name raises, and the static
   analyzer's `metric-name-sync` check (photon_ml_tpu/analysis/) fails
@@ -59,8 +59,7 @@ Four coordinated parts:
   (stage breakdown, ingest breakdown, dispatch decisions, bucket
   shapes, roofline annotation, device topology, metrics snapshot) — the
   artifact the future planner consumes. `read_profile` enforces the
-  `PROFILE_*_KEYS` contracts loudly, and bench.py re-reads what it
-  wrote through it.
+  `PROFILE_*_KEYS` contracts loudly.
 
 Import discipline: stdlib-only at module level (utils/faults.py imports
 this, and conftest-adjacent code must not initialize a jax backend);
@@ -564,7 +563,7 @@ class MetricsRegistry:
 
     def reset_counters(self) -> None:
         """Zero the counters ONLY — the faults.reset_counters contract.
-        Callers resetting fault counters at section boundaries (bench)
+        Callers resetting fault counters between the phases of a run
         must not destroy unrelated histogram/gauge state mid-run. Labeled
         sub-counts reset with their aggregates (they are the same events)."""
         with self._lock:
@@ -1042,8 +1041,8 @@ def validate_journal(path: str) -> Tuple[int, List[str]]:
 # ------------------------------------------------------------------- profile
 
 # Peak HBM bandwidth per chip (GB/s), keyed by the `device_kind` string
-# the chip reports through JAX — the annotation bench.py carries on every
-# bandwidth figure, recorded in the profile so the planner can judge
+# the chip reports through JAX — recorded in the run profile's roofline
+# block (`run_profile`, printed by cli/obs) so the planner can judge
 # achieved bandwidth without re-deriving hardware constants. Keyed by
 # kind, not platform: every TPU generation has its own peak, and a number
 # judged against another chip's roofline is wrong without looking wrong.
@@ -1162,8 +1161,7 @@ def write_profile(path: str, profile: Mapping[str, object]) -> str:
 def read_profile(path: str, kind: Optional[str] = None) -> Dict[str, object]:
     """Read a profile back with the loud missing-key contract: a profile
     that silently lost a section is a measurement bug, so the CONSUMER
-    fails rather than plan from it (bench.py re-reads what it wrote
-    through this)."""
+    fails rather than plan from it."""
     with open(path) as f:
         profile = json.load(f)
     found_kind = profile.get("kind")

@@ -60,15 +60,14 @@ def pytest_configure(config):
     )
     config.addinivalue_line(
         "markers",
-        "multihost: OS-process jax.distributed dryruns (coordinator + "
-        "workers over virtual CPU devices); always slow-marked — tier-1 "
-        "covers the sharded code paths on the single-process 8-device mesh",
+        "multihost: OS-process jax.distributed runs (coordinator + workers "
+        "over virtual CPU devices); the SIGKILL drills are also slow-marked",
     )
     config.addinivalue_line(
         "markers",
         "elastic: live mesh elasticity (reshard under traffic, mid-fit "
-        "mesh-loss resume); the multi-device reshard drills are slow+"
-        "elastic and out of tier-1",
+        "mesh-loss resume); the SIGKILL and rollback-under-traffic drills "
+        "are also slow-marked",
     )
     _assert_fault_sites_registered()
 
@@ -103,6 +102,27 @@ def _assert_fault_sites_registered():
 @pytest.fixture
 def rng():
     return np.random.default_rng(20260729)
+
+
+@pytest.fixture
+def assert_sharded_close():
+    """`check(actual, desired, kind)`: a sharded program against the
+    single-device program of the same model. Sharding changes which partial
+    sums exist and the order they combine in, so the two agree to
+    `contracts.SHARDED_VS_SINGLE_TOLERANCES[kind]` ("fit": coefficients and
+    metrics after an iterative solve; "serve": one bucket program's
+    scores), never bitwise. The SAME program on the same inputs (restore,
+    replay, a rollback to the old generation) stays `array_equal`."""
+    from photon_ml_tpu.utils.contracts import SHARDED_VS_SINGLE_TOLERANCES
+
+    def check(actual, desired, kind):
+        np.testing.assert_allclose(
+            np.asarray(actual),
+            np.asarray(desired),
+            **SHARDED_VS_SINGLE_TOLERANCES[kind],
+        )
+
+    return check
 
 
 @pytest.fixture(autouse=True)

@@ -8,9 +8,9 @@ Three layers:
    fails here, not months later when the invariant rots.
 2. Pragma engine: reasoned pragmas suppress exactly their line; a
    reasonless or unknown-check pragma is itself a finding.
-3. The live tree: zero findings across the package, bench.py, and
-   tests/ — the machine-checked statement that every invariant photon-lint
-   encodes actually HOLDS right now (and that no disable pragma exists
+3. The live tree: zero findings across the package and tests/ — the
+   machine-checked statement that every invariant photon-lint encodes
+   actually HOLDS right now (and that no disable pragma exists
    without a reason, since pragma hygiene is unsuppressable).
 """
 
@@ -166,7 +166,7 @@ def test_reasoned_pragma_suppresses_trailing_and_comment_line():
 
 
 def test_live_tree_is_clean():
-    """THE gate: zero findings over the package, bench.py, and tests/.
+    """THE gate: zero findings over the package and tests/.
     Also proves no disable pragma anywhere lacks a reason (pragma
     hygiene cannot be suppressed)."""
     findings = run_checks()

@@ -16,6 +16,8 @@ The load-bearing contracts of the autopilot:
 
 from __future__ import annotations
 
+import time
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -26,6 +28,8 @@ from photon_ml_tpu.autopilot import (
     ControlRule,
     SensorSnapshot,
     read_sensors,
+    rebalance_rule,
+    shard_grow_rule,
 )
 from photon_ml_tpu.game.model import (
     Coefficients,
@@ -472,6 +476,69 @@ class TestRealActuators:
             assert faults.counters().get("tenant_restores") == 1
             # Restoring a tenant that is not demoted is a free no-op.
             assert reg.restore("a") == 0
+            reg.close(release_bundles=True)
+
+    def test_load_shift_reshards_and_rebalances(self, assert_sharded_close):
+        """A request burst onto a replicated tenant and cold-row traffic
+        onto a two-tier tenant: one tick of the built-in rules reshards
+        the first across the mesh and re-places the second's hot set from
+        its measured promotions — both actions applied, none rolled back,
+        zero failed requests. The rebalanced tenant answers bitwise (the
+        same rows, another tier); the resharded one is another program
+        and holds the `serve` tolerance."""
+        reqs_a = _requests(191, 16)
+        reqs_b_cold = [
+            r for r in _requests(193, 48) if int(r.entity_ids["eid"]) >= 8
+        ]  # beyond b's 8 hot rows: every one a cold-tier hit
+        with TenantRegistry(max_batch=32, max_wait_ms=2.0) as reg:
+            reg.admit("a", _bundle(1))
+            reg.admit("b", _bundle(2))
+            ref_a = _scores(reg, "a", reqs_a)
+            ref_b = _scores(reg, "b", reqs_b_cold)
+            reg.demote("b", hot_rows=8, reason="test-setup")
+            pilot = Autopilot(
+                reg,
+                rules=[
+                    shard_grow_rule(fire_above=32.0, rearm_below=4.0),
+                    rebalance_rule(fire_above=4.0, rearm_below=1.0),
+                ],
+                cooldown_s=30.0,
+                max_actions=4,
+                probe_requests={"a": reqs_a[0], "b": reqs_b_cold[0]},
+                start=False,
+            )
+            pilot.tick()  # baseline snapshot: the rules read deltas
+            for r in _requests(194, 96):
+                reg.score("a", r)
+            assert np.array_equal(_scores(reg, "b", reqs_b_cold), ref_b)
+
+            def promotions():
+                return sum(
+                    sum(c.store.promotion_stats().values())
+                    for c in reg.tenant("b")
+                    .engine._state.bundle.coordinates.values()
+                    if getattr(c, "store", None) is not None
+                )
+
+            deadline = time.monotonic() + 30.0  # the promote worker is async
+            while promotions() < 4 and time.monotonic() < deadline:
+                time.sleep(0.05)
+            pilot.tick()  # the loop reacts
+            s = pilot.summary()
+            pilot.close()
+            assert s["actions"] == 2 and s["rollbacks"] == 0, s
+            assert s["quarantined"] == []
+            assert any(
+                c.mesh is not None
+                for c in reg.tenant("a")
+                .engine._state.bundle.coordinates.values()
+            )
+            assert_sharded_close(_scores(reg, "a", reqs_a), ref_a, "serve")
+            assert np.array_equal(_scores(reg, "b", reqs_b_cold), ref_b)
+            m = reg.metrics()
+            assert m["tenants"]["a"]["failed"] == 0
+            assert m["tenants"]["b"]["failed"] == 0
+            assert faults.counters().get("autopilot_rollbacks", 0) == 0
             reg.close(release_bundles=True)
 
     def test_retune_updates_live_wait_and_round_trips(self):

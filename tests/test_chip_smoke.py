@@ -1,5 +1,4 @@
-"""chip_smoke.py off the chip, the compile cache's placement, and bench.py's
-parent without its old fallback.
+"""chip_smoke.py off the chip, and the compile cache's placement.
 
 The smoke's verdict needs a TPU, so here it must end `"ok": false` and
 non-zero — after running every stage and passing every correctness check
@@ -94,45 +93,3 @@ def test_compile_cache_directory(monkeypatch, tmp_path, placed_from_outside):
         assert path == os.path.join(REPO, ".jax_cache")
         assert updates["jax_compilation_cache_dir"] == path
         assert compile_cache.enable() == path  # fixed: the same on every call
-
-
-@pytest.mark.parametrize(
-    "child",
-    [
-        pytest.param(dict(returncode=1, stdout="", stderr="RuntimeError: no TPU\n"), id="child-fails"),
-        pytest.param(dict(returncode=0, stdout="no json here\n", stderr="quiet\n"), id="child-prints-nothing"),
-        pytest.param(dict(timeout=True), id="child-times-out"),
-        pytest.param(dict(returncode=0, stdout='warming\n{"metric": "m", "value": 1.5}\n', stderr=""), id="child-ok"),
-    ],
-)
-def test_bench_parent_has_no_fallback(monkeypatch, capsys, child):
-    """A failed accelerator child makes bench.py exit non-zero with the
-    child's stderr: one attempt, no CPU retry, no `value: 0.0` line."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-
-    calls = []
-
-    def fake_run(argv, **kwargs):
-        calls.append((argv, kwargs))
-        if child.get("timeout"):
-            raise subprocess.TimeoutExpired(argv, kwargs["timeout"], stderr="still compiling\n")
-        return subprocess.CompletedProcess(argv, child["returncode"], child["stdout"], child["stderr"])
-
-    monkeypatch.setattr(bench.subprocess, "run", fake_run)
-    monkeypatch.setattr(sys, "argv", ["bench.py"])
-    if child.get("returncode") == 0 and "{" in child.get("stdout", ""):
-        bench.main()
-        assert capsys.readouterr().out.strip() == '{"metric": "m", "value": 1.5}'
-    else:
-        with pytest.raises(SystemExit) as exc:
-            bench.main()
-        assert exc.value.code not in (0, None)
-        captured = capsys.readouterr()
-        assert captured.out == ""  # no placeholder result under a metric's name
-        assert (child.get("stderr") or "still compiling").strip() in captured.err
-    assert len(calls) == 1  # one attempt
-    assert "env" not in calls[0][1]  # on the backend JAX finds, not a forced one

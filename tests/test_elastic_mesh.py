@@ -51,8 +51,14 @@ from photon_ml_tpu.serving import (
 from photon_ml_tpu.transformers.game_transformer import CoordinateScoringSpec
 from photon_ml_tpu.types import TaskType
 from photon_ml_tpu.utils import faults, telemetry
+from photon_ml_tpu.utils.contracts import SHARDED_VS_SINGLE_TOLERANCES
 
 pytestmark = pytest.mark.serving
+
+# Live traffic crosses generations of different mesh shapes, each another
+# program than the reference's: an answer is right within this, and a
+# wrong or FE-only answer is off by orders of magnitude more.
+_SERVE_TOL = SHARDED_VS_SINGLE_TOLERANCES["serve"]
 
 TASK = TaskType.LOGISTIC_REGRESSION
 D_FE, D_RE, E = 7, 5, 24
@@ -172,24 +178,25 @@ class TestReshardPlan:
         assert len(cplan.shard_loads) == 8
 
 
-# --------------------------------------------------- live reshard (bitwise)
+# ------------------------------------------------------------ live reshard
 
 
 @pytest.mark.elastic
-@pytest.mark.slow
 class TestLiveReshard:
-    """Multi-device reshard drills: slow+elastic, out of tier-1 (the
-    plan/rollback/rebalance/mesh-loss contracts stay tier-1)."""
+    """Multi-device reshard drills on the 8-virtual-device mesh."""
 
-    def test_shrink_regrow_replicate_bitwise(self, rng):
+    def test_shrink_regrow_replicate_parity(self, rng, assert_sharded_close):
         """8 -> 4 -> 8 -> replicated, each generation bitwise-equal to a
-        cold start at that shape, zero hot-path recompiles after each
-        pre-warm, and the generation counter advancing."""
+        cold start at that shape (the same program on the same rows) and
+        within the `serve` tolerance of the replicated reference, zero
+        hot-path recompiles after each pre-warm, and the generation
+        counter advancing."""
         model, specs, reqs = _fixture(rng)
         ref = _cold_scores(model, specs, reqs)
-        assert np.array_equal(
-            ref, _cold_scores(model, specs, reqs, mesh=surviving_mesh(4))
-        )
+        cold4 = _cold_scores(model, specs, reqs, mesh=surviving_mesh(4))
+        cold8 = _cold_scores(model, specs, reqs, mesh=make_mesh())
+        assert_sharded_close(cold4, ref, "serve")
+        assert_sharded_close(cold8, ref, "serve")
         bundle = ServingBundle.from_model(model, specs, TASK, mesh=make_mesh())
         with ServingEngine(bundle, max_batch=16) as eng:
             eng.warmup()
@@ -197,11 +204,11 @@ class TestLiveReshard:
             info = orch.reshard(surviving_mesh(4))
             assert info["version"] == 1 and info["old_released"]
             assert info["old_shards"] == 8 and info["new_shards"] == 4
-            assert np.array_equal(_scores(eng.score_batch(reqs)), ref)
+            assert np.array_equal(_scores(eng.score_batch(reqs)), cold4)
             assert eng.recompiles_after_warmup == 0  # pre-warm covered it
             info2 = orch.reshard(make_mesh())
             assert info2["new_shards"] == 8
-            assert np.array_equal(_scores(eng.score_batch(reqs)), ref)
+            assert np.array_equal(_scores(eng.score_batch(reqs)), cold8)
             info3 = orch.reshard(None)  # collapse to replicated
             assert info3["new_shards"] == 1
             assert np.array_equal(_scores(eng.score_batch(reqs)), ref)
@@ -218,18 +225,19 @@ class TestLiveReshard:
             assert rows[0] == 3 and cold == 0
         assert faults.counters().get("reshard_rollbacks", 0) == 0
 
-    @pytest.mark.slow
     def test_reshard_under_live_traffic_zero_failed(self, rng):
         """The acceptance drill: shrink 8->4 and regrow 4->8 while a
         closed-loop client scores continuously through the batcher —
-        zero failed requests, every answer bitwise one of the two
-        (identical) generations' answers, post-reshard probe bitwise a
-        cold start at the new shape."""
+        zero failed requests, every answer the reference's within the
+        `serve` tolerance whichever generation gave it, post-reshard
+        probe bitwise the engine's own answers before it left that
+        shape."""
         model, specs, reqs = _fixture(rng)
         ref = _cold_scores(model, specs, reqs)
         bundle = ServingBundle.from_model(model, specs, TASK, mesh=make_mesh())
         eng = ServingEngine(bundle, max_batch=16)
         eng.warmup()
+        before = _scores(eng.score_batch(reqs))
         stop = threading.Event()
         failures: list = []
         answered = [0]
@@ -240,7 +248,9 @@ class TestLiveReshard:
                 r = reqs[j % len(reqs)]
                 try:
                     res = b.score(r)
-                    if res.score != ref[j % len(reqs)]:
+                    if not np.isclose(
+                        res.score, ref[j % len(reqs)], **_SERVE_TOL
+                    ):
                         failures.append(
                             f"answer drift at {j}: {res.score}"
                         )
@@ -266,7 +276,7 @@ class TestLiveReshard:
         assert not failures, failures[:3]
         assert answered[0] > 0
         assert info["new_shards"] == 4 and info2["new_shards"] == 8
-        assert np.array_equal(probe, ref)
+        assert np.array_equal(probe, before)
         assert faults.counters().get("reshard_rollbacks", 0) == 0
 
 
@@ -277,7 +287,7 @@ class TestLiveReshard:
 @pytest.mark.chaos
 class TestReshardRollback:
     def test_stage_failure_rolls_back_and_keeps_serving(
-        self, rng, monkeypatch
+        self, rng, monkeypatch, assert_sharded_close
     ):
         monkeypatch.setenv("PHOTON_RETRY_BASE_DELAY_S", "0.001")
         model, specs, reqs = _fixture(rng)
@@ -285,11 +295,13 @@ class TestReshardRollback:
         bundle = ServingBundle.from_model(model, specs, TASK, mesh=make_mesh())
         with ServingEngine(bundle, max_batch=16) as eng:
             eng.warmup()
+            before = _scores(eng.score_batch(reqs))
+            assert_sharded_close(before, ref, "serve")
             with faults.inject("reshard_stage:9999"):
                 with pytest.raises(faults.InjectedFault):
                     eng.reshard_orchestrator.reshard(surviving_mesh(4))
                 # Old generation NEVER stopped serving, bitwise intact.
-                assert np.array_equal(_scores(eng.score_batch(reqs)), ref)
+                assert np.array_equal(_scores(eng.score_batch(reqs)), before)
             c = faults.counters()
             assert c["reshard_rollbacks"] == 1
             assert c["reshard_retries"] > 0
@@ -300,18 +312,22 @@ class TestReshardRollback:
             # A later clean reshard still succeeds (no wedged state).
             info = eng.reshard_orchestrator.reshard(surviving_mesh(4))
             assert info["version"] == 1
-            assert np.array_equal(_scores(eng.score_batch(reqs)), ref)
+            assert_sharded_close(_scores(eng.score_batch(reqs)), ref, "serve")
 
-    def test_commit_failure_rolls_back(self, rng, monkeypatch):
+    def test_commit_failure_rolls_back(
+        self, rng, monkeypatch, assert_sharded_close
+    ):
         monkeypatch.setenv("PHOTON_RETRY_BASE_DELAY_S", "0.001")
         model, specs, reqs = _fixture(rng)
         ref = _cold_scores(model, specs, reqs)
         bundle = ServingBundle.from_model(model, specs, TASK, mesh=make_mesh())
         with ServingEngine(bundle, max_batch=16) as eng:
+            before = _scores(eng.score_batch(reqs))
+            assert_sharded_close(before, ref, "serve")
             with faults.inject("reshard_commit:1"):
                 with pytest.raises(faults.InjectedFault):
                     eng.reshard_orchestrator.reshard(surviving_mesh(4))
-            assert np.array_equal(_scores(eng.score_batch(reqs)), ref)
+            assert np.array_equal(_scores(eng.score_batch(reqs)), before)
             assert eng.bundle_version == 0
             assert faults.counters()["reshard_rollbacks"] == 1
 
@@ -320,7 +336,7 @@ class TestReshardRollback:
         self, rng, monkeypatch
     ):
         """An injected staging failure mid-traffic: every request keeps
-        answering bitwise off the old generation while the reshard dies."""
+        answering off the old generation while the reshard dies."""
         monkeypatch.setenv("PHOTON_RETRY_BASE_DELAY_S", "0.001")
         model, specs, reqs = _fixture(rng)
         ref = _cold_scores(model, specs, reqs)
@@ -336,7 +352,9 @@ class TestReshardRollback:
             while not stop.is_set():
                 try:
                     res = b.score(reqs[j % len(reqs)])
-                    if res.score != ref[j % len(reqs)]:
+                    if not np.isclose(
+                        res.score, ref[j % len(reqs)], **_SERVE_TOL
+                    ):
                         failures.append(f"drift at {j}")
                     answered[0] += 1
                 except Exception as exc:  # noqa: BLE001 - recorded
@@ -427,7 +445,9 @@ from photon_ml_tpu.serving import ScoreRequest, ServingBundle, ServingEngine
 from photon_ml_tpu.serving.reshard import MeshReshardOrchestrator
 from photon_ml_tpu.transformers.game_transformer import CoordinateScoringSpec
 from photon_ml_tpu.types import TaskType
+from photon_ml_tpu.utils.contracts import SHARDED_VS_SINGLE_TOLERANCES
 
+SERVE_TOL = SHARDED_VS_SINGLE_TOLERANCES["serve"]
 out, mode = sys.argv[1], sys.argv[2]
 TASK = TaskType.LOGISTIC_REGRESSION
 D_FE, D_RE, E = 7, 5, 24
@@ -477,7 +497,9 @@ def traffic(b):
     while not stop.is_set():
         try:
             res = b.score(reqs[j % n])
-            if res.score != probe[j % n]:
+            # The batcher's one-request bucket is another program than
+            # the probe's 16-row batch: right within the serve tolerance.
+            if not np.isclose(res.score, probe[j % n], **SERVE_TOL):
                 log["failed"] += 1
             else:
                 log["answered"] += 1

@@ -494,6 +494,47 @@ class TestApplyDelta:
             eng.close()
             eng.bundle.release()
 
+    def _apply_under_traffic(self, eng, reqs, delta, fault=None):
+        """`apply_delta` on a live engine while a closed-loop client scores
+        `reqs` through the batcher. Returns the answers as (request index,
+        score) and what `apply_delta` returned (None under `fault`, which
+        must make it raise)."""
+        stop = threading.Event()
+        failures: list = []
+        answers: list = []
+
+        def _traffic(b):
+            j = 0
+            while not stop.is_set():
+                i = j % len(reqs)
+                try:
+                    answers.append((i, b.score(reqs[i]).score))
+                except Exception as exc:  # noqa: BLE001 - recorded
+                    failures.append(repr(exc))
+                j += 1
+
+        info = None
+        with eng, eng.batcher(max_wait_ms=0.5) as batcher:
+            th = threading.Thread(
+                target=_traffic,
+                args=(batcher,),
+                name="photon-refresh-traffic",
+            )
+            th.start()
+            time.sleep(0.05)
+            if fault is None:
+                info = apply_delta(eng, delta)
+            else:
+                with faults.inject(fault), pytest.raises(faults.InjectedFault):
+                    apply_delta(eng, delta)
+            time.sleep(0.05)
+            stop.set()
+            th.join(timeout=60)
+            assert not th.is_alive()
+        assert not failures, failures[:3]
+        assert answers
+        return answers, info
+
     def test_upload_fault_mid_apply_rolls_back_under_traffic(
         self, rng, monkeypatch
     ):
@@ -506,46 +547,38 @@ class TestApplyDelta:
         eng = _live_engine(st.model, st.entity_indices)
         eng.warmup()
         ref = _scores(eng.score_batch(reqs))
-        stop = threading.Event()
-        failures: list = []
-        answered = [0]
-
-        def _traffic(b):
-            j = 0
-            while not stop.is_set():
-                try:
-                    r = b.score(reqs[j % len(reqs)])
-                    if r.score != ref[j % len(reqs)]:
-                        failures.append(f"drift at {j}")
-                    answered[0] += 1
-                except Exception as exc:  # noqa: BLE001 - recorded
-                    failures.append(repr(exc))
-                j += 1
-
         try:
-            with eng, eng.batcher(max_wait_ms=0.5) as batcher:
-                th = threading.Thread(
-                    target=_traffic,
-                    args=(batcher,),
-                    name="photon-refresh-traffic",
-                )
-                th.start()
-                time.sleep(0.05)
-                with faults.inject("shard_upload:9999"):
-                    with pytest.raises(faults.InjectedFault):
-                        apply_delta(eng, delta)
-                time.sleep(0.05)
-                stop.set()
-                th.join(timeout=60)
-                assert not th.is_alive()
-            assert not failures, failures[:3]
-            assert answered[0] > 0
+            answers, _ = self._apply_under_traffic(
+                eng, reqs, delta, fault="shard_upload:9999"
+            )
+            assert all(s == ref[i] for i, s in answers)
             assert eng.bundle_version == 0
             assert _scores(eng.score_batch(reqs)) == ref
             assert faults.counters()["delta_rollbacks"] == 1
             assert "delta_applies" not in faults.counters()
             prov = eng.bundle.provenance
             assert prov["deltas_applied"] == 0 and prov["generation"] == 0
+        finally:
+            eng.close()
+            eng.bundle.release()
+
+    def test_clean_apply_under_traffic_answers_every_request(self, rng):
+        """The freshness flip itself: a delta applied to a LIVE engine
+        while a closed-loop client scores through the batcher — zero
+        failed requests, every answer bitwise the old generation's or the
+        new one's, and the engine ends on generation 1."""
+        _, st, res, delta = _serving_state(rng)
+        reqs = _requests()
+        eng = _live_engine(st.model, st.entity_indices)
+        eng.warmup()
+        old = _scores(eng.score_batch(reqs))
+        try:
+            answers, info = self._apply_under_traffic(eng, reqs, delta)
+            new = _scores(eng.score_batch(reqs))
+            assert all(s in (old[i], new[i]) for i, s in answers)
+            assert new != old  # the delta did change answers
+            assert info["version"] == 1 and eng.bundle_version == 1
+            assert faults.counters().get("delta_rollbacks", 0) == 0
         finally:
             eng.close()
             eng.bundle.release()

@@ -22,8 +22,12 @@ got from Spark/YARN for free (PARITY.md "Mesh failure semantics"):
   survivors (PR 10 shard-loss semantics), every resident row stays
   bitwise-identical to the single-process serve.
 
-All out of tier-1 (slow + multihost): every test spawns OS processes
-that bring up their own jax runtime.
+Every test spawns OS processes that bring up their own jax runtime. The
+deterministic ones (parity, disjoint ingest, the torn checkpoint) run in
+tier-1; the two SIGKILL drills are timing drills and stay slow-marked —
+their recovery logic is held in tier-1 in one process by
+tests/test_elastic_mesh.py::TestMeshLossResume and
+tests/test_serving_two_tier.py::TestShardLossDegradation.
 """
 
 import json
@@ -36,7 +40,7 @@ import time
 import numpy as np
 import pytest
 
-pytestmark = [pytest.mark.slow, pytest.mark.multihost]
+pytestmark = pytest.mark.multihost
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -242,6 +246,7 @@ def test_disjoint_ingest_partition(corpus):
         )
 
 
+@pytest.mark.slow
 def test_sigkill_midfit_costs_one_sweep(corpus, tmp_path):
     """SIGKILL a whole worker process after the first checkpoint commit:
     the supervisor journals the typed `host_loss`, relaunches on the
@@ -348,6 +353,7 @@ def _read_scores(out):
     return recs
 
 
+@pytest.mark.slow
 def test_sigkill_midreplay_zero_failed_requests(
     corpus, fit_single, tmp_path
 ):

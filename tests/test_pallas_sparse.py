@@ -248,9 +248,6 @@ class TestLayoutPlanner:
         assert choose_layout(10**6, 10**5, 4096)[0] is True
         monkeypatch.setenv("PHOTON_SPARSE_LAYOUT", "grouped")
         assert choose_layout(10**6, 10**5, 4096)[0] is False
-        monkeypatch.delenv("PHOTON_SPARSE_LAYOUT")
-        monkeypatch.setenv("PHOTON_SPARSE_ROWALIGN", "1")  # legacy knob
-        assert choose_layout(10**6, 10**5, 4096)[0] is True
 
     def test_auto_declines_bench_shape(self, monkeypatch):
         """1M x 64 nnz into 16k dim: lane collisions force a ~2x aligned

@@ -407,12 +407,15 @@ def test_bcast_gather_rows_exact(rng):
     assert np.array_equal(got, np.asarray(M)[np.asarray(rows)])
 
 
-def test_sharded_scan_sweep_matches_bucket_loop(rng, monkeypatch):
+def test_sharded_scan_sweep_matches_bucket_loop(
+    rng, monkeypatch, assert_sharded_close
+):
     """Tier-1 pod-scale certificate on the 8-virtual-device mesh: the
     entity-sharded scan sweep (ring gather -> vmapped shard-local solves ->
-    ring scatter, all inside ONE lax.scan program per block shape) is
-    BITWISE equal to the sharded per-bucket loop, keeps the coefficient
-    store row-sharded, and reports its collective bytes."""
+    ring scatter, all inside ONE lax.scan program per block shape) matches
+    the sharded per-bucket loop — another program, so to the `fit`
+    tolerance —, keeps the coefficient store row-sharded, and reports its
+    collective bytes."""
     mesh = make_mesh()
     cfg_re = RandomEffectDataConfig("entityId", "per_entity", min_bucket=4)
 
@@ -445,7 +448,8 @@ def test_sharded_scan_sweep_matches_bucket_loop(rng, monkeypatch):
 
     W_scan = np.asarray(m_scan.coefficients_matrix)
     W_loop = np.asarray(m_loop.coefficients_matrix)
-    assert np.array_equal(W_scan, W_loop)  # bitwise: dispatch never rounds
+    assert np.abs(W_loop).max() > 0.1  # a trained matrix, not the zero start
+    assert_sharded_close(W_scan, W_loop, "fit")
 
     # The coefficient store stayed row-sharded through the scan.
     shard_bytes = [

@@ -12,8 +12,6 @@ the sprint's wins from silently regressing:
     oracle it replaced.
 """
 
-import json
-
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -37,32 +35,6 @@ def _bench_like_coo(n=131072, d=4096, k=32, seed=17):
     cols = rng.integers(0, d, size=n * k).astype(np.int64)
     vals = rng.normal(size=n * k).astype(np.float32)
     return rows, cols, vals, n, d
-
-
-class TestDispatchJson:
-    def test_dispatch_decisions_are_machine_comparable(self):
-        """Satellite: bench artifacts must carry dispatch decisions as JSON
-        booleans/objects, never repr() strings (BENCH_r05 shipped
-        "dispatch": "True")."""
-        import bench
-
-        for mode, expect in ((True, True), (False, False), (None, None)):
-            assert bench._dispatch_json(mode) is expect
-
-        class _FakeMesh:
-            class devices:
-                size = 8
-
-        class _FakeSharded:
-            axis = "batch"
-            mesh = _FakeMesh()
-
-        out = bench._dispatch_json(_FakeSharded())
-        assert out["sharded"] is True and out["devices"] == 8
-        # Every shape must survive a JSON round trip unchanged.
-        for mode in (True, False, None, _FakeSharded()):
-            enc = bench._dispatch_json(mode)
-            assert json.loads(json.dumps(enc)) == enc
 
 
 @pytest.mark.slow

@@ -203,6 +203,21 @@ class TestProfileRules:
             assert tuple(rec) == PLAN_DECISION_KEYS
             assert isinstance(rec["evidence"], dict)
 
+    def test_unknown_dispatch_key_is_ignored_not_refused(self, tmp_path):
+        # A profile written by an older tree may carry a dispatch key no
+        # rule reads any more: it loads through the contract and plans
+        # exactly what the same profile plans without it.
+        stale = _fit_profile()
+        stale["dispatch"]["retired_rule_input"] = 64
+        path = str(tmp_path / "stale.json")
+        telemetry.write_profile(path, stale)
+        plan = planner.plan_from_profile(telemetry.read_profile(path), path)
+        clean = planner.plan_from_profile(_fit_profile())
+        assert "retired_rule_input" not in plan.decisions
+        assert {k: d.value for k, d in plan.decisions.items()} == {
+            k: d.value for k, d in clean.decisions.items()
+        }
+
     def test_prefetch_deepens_on_pipelined_fit_with_host_cores(
         self, monkeypatch
     ):
@@ -478,7 +493,7 @@ class TestProfilePortability:
         self, monkeypatch, tmp_path
     ):
         """PHOTON_PLAN_PROFILE is a cache handle: pointing it at a
-        not-yet-written path (the first bench round) runs unplanned
+        not-yet-written path (a first round) runs unplanned
         instead of crashing — but an explicit --profile stays loud."""
         missing = str(tmp_path / "not_written_yet.json")
         monkeypatch.setenv("PHOTON_PLAN_PROFILE", missing)
@@ -486,24 +501,6 @@ class TestProfilePortability:
         assert planner.current_plan() is None
         with pytest.raises(FileNotFoundError):
             planner.ensure_ambient_plan(missing)  # the explicit argument
-
-    def test_plan_suppression_scopes_everything(self, monkeypatch, tmp_path):
-        path = str(tmp_path / "profile.json")
-        telemetry.write_profile(path, _fit_profile())
-        planner.install_plan(
-            planner.plan_from_profile(telemetry.read_profile(path), path)
-        )
-        monkeypatch.setenv("PHOTON_PLAN_PROFILE", path)
-        with planner.plan_suppressed():
-            # Consults fall back to defaults, the block reads inactive,
-            # and the gate installs nothing.
-            assert planner.planned_value("pack_routing") == "auto"
-            assert planner.plan_block()["active"] is False
-            planner.uninstall_plan()
-            assert planner.ensure_ambient_plan() is None
-            # Explicit per-quantity knobs still win (operator intent).
-            monkeypatch.setenv("PHOTON_DEVICE_PACK", "1")
-            assert planner.planned_value("pack_routing") == "device"
 
     def test_estimator_owns_its_env_installed_plan(
         self, monkeypatch, tmp_path

@@ -62,11 +62,11 @@ TASK = TaskType.LOGISTIC_REGRESSION
 D_FE, D_RE, E = 7, 5, 24
 
 
-def _make_model(seed: int, n_entities: int = E):
+def _make_model(seed: int, n_entities: int = E, d_re: int = D_RE):
     rng = np.random.default_rng(seed)
     w = rng.normal(size=D_FE).astype(np.float32)
-    M = np.zeros((n_entities + 1, D_RE), np.float32)
-    M[:n_entities] = rng.normal(size=(n_entities, D_RE))
+    M = np.zeros((n_entities + 1, d_re), np.float32)
+    M[:n_entities] = rng.normal(size=(n_entities, d_re))
     model = GameModel(
         {
             "fixed": FixedEffectModel(Coefficients(jnp.asarray(w)), TASK),
@@ -84,15 +84,17 @@ def _make_model(seed: int, n_entities: int = E):
     return model, specs
 
 
-def _bundle(seed: int, n_entities: int = E) -> ServingBundle:
-    model, specs = _make_model(seed, n_entities)
+def _bundle(
+    seed: int, n_entities: int = E, d_re: int = D_RE
+) -> ServingBundle:
+    model, specs = _make_model(seed, n_entities, d_re)
     return ServingBundle.from_model(model, specs, TASK)
 
 
-def _requests(seed: int, n: int, n_entities: int = E):
+def _requests(seed: int, n: int, n_entities: int = E, d_re: int = D_RE):
     rng = np.random.default_rng(seed)
     X = rng.normal(size=(n, D_FE)).astype(np.float32)
-    Xe = rng.normal(size=(n, D_RE)).astype(np.float32)
+    Xe = rng.normal(size=(n, d_re)).astype(np.float32)
     ids = rng.integers(0, n_entities + 6, size=n)  # trained + cold starts
     return [
         ScoreRequest(
@@ -287,6 +289,56 @@ class TestServingParity:
             assert m["tenants"]["warm"]["tier"]["tier"] == "f32"
             assert m["tenants"]["new"]["tier"]["tier"] == "f32"
             reg.close(release_bundles=True)
+
+    def test_ladder_keeps_three_times_the_f32_capacity_resident(
+        self, monkeypatch
+    ):
+        """The HBM squeeze: seven wide tenants under a budget that fits one
+        f32 tenant beside an int8 fleet. Without the ladder the valve
+        host-demotes whole tenants and two stay resident; with it every
+        tenant stays resident — at least 3x — each answering within its
+        rung's pinned tolerance, with no failed request anywhere."""
+        d_re, n_ent, names = 32, 64, [f"lad-{i}" for i in range(7)]
+        reqs = {
+            nm: _requests(900 + j, 8, n_ent, d_re)
+            for j, nm in enumerate(names)
+        }
+        probe = _bundle(777, n_ent, d_re)
+        per_f32 = probe.device_bytes_per_shard()
+        q_probe, _ = quantize_bundle_rows(probe, "int8")
+        per_i8 = q_probe.device_bytes_per_shard()
+        q_probe.release(close_stores=False)
+        probe.release(close_stores=False)
+        budget = per_f32 + (len(names) - 1) * per_i8 + per_i8 // 2
+
+        def squeeze(ladder_on):
+            if ladder_on:
+                monkeypatch.setenv("PHOTON_TIER_LADDER", "1")
+            else:
+                monkeypatch.delenv("PHOTON_TIER_LADDER", raising=False)
+            with TenantRegistry(
+                max_batch=16, max_wait_ms=2.0, hbm_budget_bytes=int(budget)
+            ) as reg:
+                refs = {}
+                for j, nm in enumerate(names):
+                    reg.admit(nm, _bundle(800 + j, n_ent, d_re))
+                    refs[nm] = _scores(reg, nm, reqs[nm])  # f32: just admitted
+                blocks = reg.metrics()["tenants"]
+                for nm in names:
+                    got = _scores(reg, nm, reqs[nm])
+                    assert _allclose(got, refs[nm], blocks[nm]["tier"]["tier"])
+                failed = sum(
+                    b["failed"] for b in reg.metrics()["tenants"].values()
+                )
+                reg.close(release_bundles=True)
+            resident = sum(1 for b in blocks.values() if not b["demoted"])
+            return resident, failed
+
+        f32_capacity, failed_off = squeeze(ladder_on=False)
+        ladder_resident, failed_on = squeeze(ladder_on=True)
+        assert 1 <= f32_capacity < len(names)  # the squeeze bites
+        assert ladder_resident >= 3 * f32_capacity
+        assert failed_off == 0 and failed_on == 0
 
 
 # ======================================================== fault injection

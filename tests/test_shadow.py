@@ -19,6 +19,7 @@ The load-bearing contracts:
 from __future__ import annotations
 
 import json
+import threading
 import time
 
 import numpy as np
@@ -104,6 +105,17 @@ def _solo_scores(seed: int, reqs, scale: float = 1.0) -> np.ndarray:
         return np.asarray(
             [r.score for r in eng.score_batch(reqs)], np.float64
         )
+
+
+def _champ_scores(reg, reqs) -> np.ndarray:
+    """The champion's answers through the registry's own submit path."""
+    return np.asarray(
+        [
+            reg.submit("champ", r, block=True).result(timeout=30).score
+            for r in reqs
+        ],
+        np.float64,
+    )
 
 
 def _labels_from(scores: np.ndarray) -> np.ndarray:
@@ -378,15 +390,7 @@ class TestVerdicts:
                 reg.tenant("cand")
             # Post-promotion serving: bitwise vs. the challenger solo
             # (same weights as the old champion here, so the same ref).
-            got2 = np.asarray(
-                [
-                    reg.submit("champ", r, block=True)
-                    .result(timeout=30)
-                    .score
-                    for r in reqs
-                ],
-                np.float64,
-            )
+            got2 = _champ_scores(reg, reqs)
             m = reg.metrics()
             reg.close(release_bundles=True)
         assert np.array_equal(got, ref)
@@ -424,21 +428,64 @@ class TestVerdicts:
             finally:
                 controller.close()
             assert int(reg.tenant("champ").engine._state.version) == v0
-            got = np.asarray(
-                [
-                    reg.submit("champ", r, block=True)
-                    .result(timeout=30)
-                    .score
-                    for r in reqs
-                ],
-                np.float64,
-            )
+            got = _champ_scores(reg, reqs)
             m = reg.metrics()
             reg.close(release_bundles=True)
         assert chall_bundle.released  # a failed promotion cleans up
         assert np.array_equal(got, ref)
         assert m["tenants"]["champ"]["failed"] == 0
         assert faults.COUNTERS.get("shadow_rollbacks") == 1
+
+    def test_promotion_stalled_before_commit_keeps_champion_serving(
+        self, monkeypatch
+    ):
+        """A promotion held at `swap_commit` (staged, warmed, not yet
+        flipped — where a killed promoter would die) neither blocks nor
+        changes the champion: requests submitted mid-stall answer bitwise
+        off the old generation, and the flip lands only when the commit
+        runs."""
+        reqs = _requests(45, 16)
+        ref = _solo_scores(1, reqs)
+        stalled, release = threading.Event(), threading.Event()
+        orig_fault_point = faults.fault_point
+
+        def _stalling_fault_point(site):
+            if site == "swap_commit":
+                stalled.set()
+                assert release.wait(timeout=60.0)
+            return orig_fault_point(site)
+
+        with TenantRegistry(max_batch=32, max_wait_ms=2.0) as reg:
+            reg.admit("champ", _bundle(1))
+            v0 = int(reg.tenant("champ").engine._state.version)
+            controller = ShadowController(
+                reg, "champ", "cand", _bundle(2),
+                window_size=len(reqs), min_windows=1, cooldown_s=0.0,
+                auto_actuate=False,
+            )
+            monkeypatch.setattr(faults, "fault_point", _stalling_fault_point)
+            promoter = threading.Thread(
+                target=lambda: controller.promote(raise_on_failure=False),
+                name="shadow-promote-drive",
+            )
+            try:
+                promoter.start()  # an operator's promote, no verdict needed
+                assert stalled.wait(timeout=60.0)
+                mid = _champ_scores(reg, reqs)
+                assert int(reg.tenant("champ").engine._state.version) == v0
+            finally:
+                release.set()
+                promoter.join(timeout=60.0)
+                controller.close()
+            assert not promoter.is_alive()
+            assert int(reg.tenant("champ").engine._state.version) == v0 + 1
+            after = _champ_scores(reg, reqs)
+            m = reg.metrics()
+            reg.close(release_bundles=True)
+        assert np.array_equal(mid, ref)
+        assert np.array_equal(after, _solo_scores(2, reqs))
+        assert not np.array_equal(after, ref)  # the flip did change answers
+        assert m["tenants"]["champ"]["failed"] == 0
 
 
 class TestDrain:

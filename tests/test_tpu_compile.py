@@ -33,7 +33,7 @@ from photon_ml_tpu.ops.losses import LOGISTIC
 from photon_ml_tpu.types import TaskType
 
 # README headline shape (dense fixed effect) and the e2e MovieLens shape
-# (sparse fixed effect; bench.py e2e_from_disk, chip_smoke.py).
+# (sparse fixed effect; chip_smoke.py).
 DENSE_N, DENSE_D = 1_048_576, 512
 SPARSE_N, SPARSE_D, SPARSE_NNZ = 262_144, 200, 8
 # chip_smoke's served model at 2M rows: d = 200 + intercept, rows//145
