@@ -11,9 +11,13 @@ shapes.
 
 Sparse features use an ELL-style padded layout `(indices, values)` of shape
 (N, K): K = max nonzeros per row, padding entries point at index 0 with value
-0.0. Margins are then a gather+reduce and gradients a scatter-add
-(segment-sum), both of which XLA lowers well on TPU; for dense shards the
-design matrix feeds the MXU directly.
+0.0. Margins and gradients loop over the K planes: a plane is one gather of N
+coefficients added into the margins, or one scatter-add of N entries into the
+gradient, so the coefficient vector and the gradient stay in VMEM and nothing
+of N * K elements is made. The padding invariant is what the products rest
+on: they promise XLA that every index is in [0, dim), and a padding slot
+contributes `w[0] * 0.0` to a margin and `0.0` to feature 0's gradient. For
+dense shards the design matrix feeds the MXU directly.
 """
 
 from __future__ import annotations
@@ -34,8 +38,10 @@ class SparseFeatures:
     """Padded ELL sparse matrix: row r has features indices[r, k] -> values[r, k].
 
     `dim` (the feature-space width) is static metadata so shapes stay known to
-    XLA. Padding slots must have value 0.0 (index value is then irrelevant;
-    0 by convention).
+    XLA. Every index lies in [0, dim) and a padding slot is index 0 with
+    value 0.0: `matvec` / `rmatvec` / `sq_rmatvec` promise XLA the first
+    (an index out of range reads or writes what the device finds there,
+    unchecked) and need the second for a padding slot to add nothing.
 
     Invariant: non-padding indices are unique within a row. matvec/rmatvec are
     linear so duplicates would still sum correctly there, but moment-based
@@ -65,24 +71,57 @@ class SparseFeatures:
             return (*self.values.shape[:-2], self.values.shape[-1], self.dim)
         return (*self.values.shape[:-1], self.dim)
 
-    def matvec(self, w: Array) -> Array:
-        """x @ w for every row: gather w at indices, multiply, reduce.
+    def _planes(self) -> Tuple[Array, Array]:
+        """(indices, values) with the plane axis K leading: (K, ..., N)."""
+        return (
+            jnp.moveaxis(self.indices, self.ell_axis, 0),
+            jnp.moveaxis(self.values, self.ell_axis, 0),
+        )
 
-        In the (N, K) layout the gather goes through the transposed (K, N)
-        index plane and is transposed back: the same values land in the
-        same places, but XLA's TPU compiler takes minutes over a gather
-        whose indices are a long, narrow (N, K) array (181 s at 200k x 9)
-        and seconds over its transpose."""
-        if self.ell_axis == -1 and self.indices.ndim >= 2:
-            gathered = jnp.swapaxes(
-                jnp.take(w, jnp.swapaxes(self.indices, -1, -2), axis=-1), -1, -2
-            )
-        else:
-            gathered = jnp.take(w, self.indices, axis=-1)
-        return (gathered * self.values).sum(axis=self.ell_axis)
+    def matvec(self, w: Array) -> Array:
+        """x @ w for every row, a plane at a time: K gathers of N entries
+        of `w`, each multiplied by its plane of values and added to the
+        (..., N) margins.
+
+        One gather of all N * K entries made XLA's TPU compiler read `w`
+        from HBM for every entry and write (then re-lay, then reduce) an
+        N * K temporary; a plane's gather takes its table from VMEM, and the
+        loop carries only the margins (PERF.md section 5, `lr-criteo.fit`).
+        The table is the loop's own copy of `w`, one zero longer so that it
+        is a copy: a buffer that lives as long as the loop is placed in VMEM,
+        where a `w` the caller keeps (an L-BFGS start, alive through the
+        solve) stayed in HBM, at 15 ns a gathered entry against 6.6. The
+        indices are promised in bounds, which the padding invariant gives: a
+        padding slot gathers `w[0]` and multiplies it by 0.0."""
+        w = jnp.asarray(w)
+        table = jnp.pad(w, [(0, 0)] * (w.ndim - 1) + [(0, 1)])
+        idx, val = self._planes()
+
+        def plane(z, iv):
+            i, v = iv
+            return z + table.at[..., i].get(mode="promise_in_bounds") * v, None
+
+        z0 = jnp.zeros(w.shape[:-1] + idx.shape[1:], jnp.result_type(w, val))
+        return jax.lax.scan(plane, z0, (idx, val))[0]
+
+    def _scatter_planes(self, u: Array, square: bool) -> Array:
+        """sum_k scatter-add of `values[k] * u` (`values[k]**2 * u` if
+        `square`) at `indices[k]` into one (dim,) accumulator, which stays
+        in VMEM across the loop."""
+        if self.indices.ndim != 2:
+            raise ValueError("rmatvec is per-problem; vmap over leading axes")
+
+        def plane(g, iv):
+            i, v = iv
+            v = jnp.square(v) if square else v
+            return g.at[i].add(v * u, mode="promise_in_bounds"), None
+
+        g0 = jnp.zeros((self.dim,), jnp.result_type(self.values, u))
+        return jax.lax.scan(plane, g0, self._planes())[0]
 
     def rmatvec(self, u: Array) -> Array:
-        """X^T u via scatter-add (the transpose of `matvec`).
+        """X^T u (the transpose of `matvec`), a plane at a time: K
+        scatter-adds of N entries each. A padding slot adds 0.0 to feature 0.
 
         2-D only: batched blocks go through vmap (which rewrites the scatter
         per-lane); an unbatched call on (..., N, K) data would silently sum
@@ -96,26 +135,12 @@ class SparseFeatures:
         VMEM-accumulator kernel is the remaining headroom if Mosaic grows a
         fast vector scatter.
         """
-        if self.indices.ndim != 2:
-            raise ValueError("rmatvec is per-problem; vmap over leading axes")
-        flat_idx = self.indices.reshape(-1)
-        # u broadcasts per ROW: over K in the (N, K) layout, over the
-        # trailing sample axis in the transposed (K, N) layout.
-        uv = self.values * (u if self.ell_axis == -2 else u[..., None])
-        flat_val = uv.reshape(-1)
-        return jnp.zeros((self.dim,), dtype=self.values.dtype).at[flat_idx].add(flat_val)
+        return self._scatter_planes(u, square=False)
 
     def sq_rmatvec(self, u: Array) -> Array:
         """Sum_i u_i * x_i^2 elementwise over features (for Hessian diagonals).
         2-D only, like `rmatvec`."""
-        if self.indices.ndim != 2:
-            raise ValueError("sq_rmatvec is per-problem; vmap over leading axes")
-        flat_idx = self.indices.reshape(-1)
-        uv = jnp.square(self.values) * (
-            u if self.ell_axis == -2 else u[..., None]
-        )
-        flat_val = uv.reshape(-1)
-        return jnp.zeros((self.dim,), dtype=self.values.dtype).at[flat_idx].add(flat_val)
+        return self._scatter_planes(u, square=True)
 
     def to_dense(self) -> Array:
         """Densify, batch-dim safe (one-hot contraction over the K axis)."""

@@ -62,6 +62,57 @@ def test_sparse_matvec_batched_matches_vmap(rng):
     np.testing.assert_allclose(out[1], dense1 @ np.asarray(w), rtol=1e-4, atol=1e-5)
 
 
+def _padded_blocks(rng, ell_axis, k, blocks=3, n=17, d=11):
+    """(blocks, n, k) ELL planes in the container's own form: unique ids
+    within a row, the tail of each row padding (index 0, value 0.0), one
+    row all padding where k allows a choice."""
+    indices = np.zeros((blocks, n, k), np.int32)
+    values = np.zeros((blocks, n, k), np.float32)
+    for b in range(blocks):
+        for r in range(n):
+            filled = 0 if r == 0 else int(rng.integers(0, k + 1))
+            indices[b, r, :filled] = rng.choice(d, size=filled, replace=False)
+            values[b, r, :filled] = rng.normal(size=filled)
+    if ell_axis == -2:
+        indices, values = indices.swapaxes(-1, -2), values.swapaxes(-1, -2)
+    return SparseFeatures(jnp.asarray(indices), jnp.asarray(values), d, ell_axis)
+
+
+@pytest.mark.parametrize("batching", ["plain", "vmap"])
+@pytest.mark.parametrize("k", [1, 6], ids=["k1", "k6"])
+@pytest.mark.parametrize("ell_axis", [-1, -2], ids=["nk", "kn"])
+@pytest.mark.parametrize("product", ["matvec", "rmatvec", "sq_rmatvec", "grad"])
+def test_ell_products_a_plane_at_a_time_match_dense(rng, product, ell_axis, k, batching):
+    """The plane loop against the densified matrix: the order of the sums
+    differs, so the gap is float32 rounding, not zero."""
+    sf = _padded_blocks(rng, ell_axis, k)
+    blocks, n, d = sf.shape
+    w = jnp.asarray(rng.normal(size=(blocks, d)).astype(np.float32))
+    u = jnp.asarray(rng.normal(size=(blocks, n)).astype(np.float32))
+
+    def loss(margins, c):
+        return jnp.sum(jnp.tanh(margins) * c)
+
+    sparse, dense, arg = {
+        "matvec": (lambda s, w, u: s.matvec(w), lambda X, w, u: X @ w, w),
+        "rmatvec": (lambda s, w, u: s.rmatvec(u), lambda X, w, u: u @ X, u),
+        "sq_rmatvec": (lambda s, w, u: s.sq_rmatvec(u), lambda X, w, u: u @ jnp.square(X), u),
+        "grad": (
+            jax.grad(lambda s, w, u: loss(s.matvec(w), u), argnums=1),
+            jax.grad(lambda X, w, u: loss(X @ w, u), argnums=1),
+            w,
+        ),
+    }[product]
+    X = sf.to_dense()
+    if batching == "vmap":
+        got, want = jax.vmap(sparse)(sf, w, u), jax.vmap(dense)(X, w, u)
+    else:
+        one = jax.tree.map(lambda a: a[0], sf)
+        got, want = sparse(one, w[0], u[0]), dense(X[0], w[0], u[0])
+    assert got.shape == want.shape and got.dtype == arg.dtype
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=2e-6)
+
+
 def test_summarize_dense_vs_numpy(rng):
     X = rng.normal(size=(50, 6)).astype(np.float32)
     X[:, 2] = 0.0

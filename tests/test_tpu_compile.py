@@ -18,8 +18,11 @@ back without the chip).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
+import math
+import re
 import time
 
 import jax
@@ -154,8 +157,9 @@ def test_sparse_kernels_compile_for_v5e(one_chip, kernel, layout):
 # minutes over a reshape that flattens such an array, or a gather indexed by
 # it (157-181 s each at N = 200,000; 271 s for _project_entries at 2M on the
 # v5e), and a second or two over the same work through the (9, N) transpose.
-# These programs go through the transpose; the bound is loose enough for a
-# loaded host and far below what the direct form costs.
+# These programs go through the transpose (the fixed effect's margins a plane
+# of it at a time); the bound is loose enough for a loaded host and far below
+# what the direct form costs.
 NARROW_N, NARROW_K = 200_000, 9
 NARROW_COMPILE_LIMIT_S = 60.0
 
@@ -189,6 +193,80 @@ def test_narrow_planes_compile_in_seconds_for_v5e(one_chip, program):
     t0 = time.perf_counter()
     lowered.compile()
     assert time.perf_counter() - t0 < NARROW_COMPILE_LIMIT_S
+
+
+# ------------------------------------------- the ELL objective, plane by plane
+
+# `lr-criteo.fit`'s solve (benchmarks/configs/lr-criteo.json): the ELL
+# objective written a plane at a time (`SparseFeatures.matvec` / `rmatvec`)
+# under L-BFGS's loop. Whether the mechanism engages is decided at compile
+# time and the program's text says which: every gather takes the coefficient
+# vector from memory space 1 (VMEM; `S(1)` in the layout), and nothing in the
+# program makes an array of rows x nnz elements (the one-gather form made a
+# dozen, 1.25 GB each, and gathered from HBM).
+CRITEO_ROWS, CRITEO_NNZ, CRITEO_DIM, CRITEO_ITERATIONS = 8_000_000, 39, 1_000_000, 3
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\](\{[^}]*\})? ([\w\-]+)\(([^)]*)\)(.*)$"
+)
+
+
+_Instruction = collections.namedtuple("_Instruction", "elements layout opcode operands op_name")
+
+
+def _array_instructions(text):
+    """name -> _Instruction of every instruction of a compiled program's
+    text whose result is one array (`operands` are names)."""
+    found = {}
+    for line in text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            name, dims, layout, opcode, operands, rest = m.groups()
+            op_name = re.search(r'op_name="([^"]*)"', rest)
+            found[name] = _Instruction(
+                math.prod(int(d) for d in dims.split(",") if d), layout or "", opcode,
+                [o.strip() for o in operands.split(",")], op_name.group(1) if op_name else "",
+            )
+    return found
+
+
+def test_the_plane_loop_gathers_from_vmem_at_the_criteo_shape(one_chip):
+    from photon_ml_tpu.data.containers import LabeledData, SparseFeatures
+    from photon_ml_tpu.ops import objective
+    from photon_ml_tpu.optimize.lbfgs import minimize_lbfgs
+
+    def solve(indices, values, labels, offsets, weights, w0):
+        data = LabeledData(SparseFeatures(indices, values, CRITEO_DIM), labels, offsets, weights)
+        return minimize_lbfgs(
+            lambda w: objective.value_and_gradient(LOGISTIC, w, data, None, 1.0, use_pallas=False),
+            w0, max_iterations=CRITEO_ITERATIONS, tolerance=1e-9,
+        ).coefficients
+
+    def plane(dtype):
+        return jax.ShapeDtypeStruct((CRITEO_ROWS, CRITEO_NNZ), dtype, sharding=one_chip)
+
+    rows = _vec(one_chip, CRITEO_ROWS)
+    instructions = _array_instructions(_compiled_text(jax.jit(solve).lower(
+        plane(jnp.int32), plane(jnp.float32), rows, rows, rows, _vec(one_chip, CRITEO_DIM)
+    )))
+    # Parameters, bitcasts of them and loop-carried tuple elements hold the
+    # stored planes; anything else of that size is a temporary.
+    temporaries = [
+        (name, i.opcode) for name, i in instructions.items()
+        if i.elements >= CRITEO_ROWS * CRITEO_NNZ
+        and i.opcode not in ("parameter", "bitcast", "get-tuple-element")
+    ]
+    assert not temporaries, temporaries[:5]
+    # Every plane's gather, in the first evaluation (one loop deep: the
+    # plane loop) and in the line search (under L-BFGS's loops), reads its
+    # table, the plane loop's own copy of the coefficients, from VMEM.
+    tables = {
+        i.op_name.count("while/body"): instructions[i.operands[0]]
+        for i in instructions.values()
+        if i.opcode == "fusion" and i.elements == CRITEO_ROWS and i.op_name.endswith("/gather")
+    }
+    assert min(tables) == 1 and max(tables) >= 3, sorted(tables)
+    for depth, table in tables.items():
+        assert table.elements == CRITEO_DIM + 1 and "S(1)" in table.layout, (depth, table)
 
 
 # ----------------------------------------------------------------- serving

@@ -279,5 +279,10 @@ def test_the_scopes_cover_the_ell_gather_and_scatter(problem, program, scopes, o
     else:
         text = compiled_text(_fe_margins, coordinate.training_features, w, None)
     names = [line.split('op_name="', 1)[1].split('"', 1)[0] for line in text.splitlines() if 'op_name="' in line]
-    found = [n for n in names if n.endswith(operation) and all(f"/{s}/" in n for s in scopes)]
-    assert found, f"no {operation} under {scopes} among {sorted(set(names))[:20]}"
+    # Each is one plane's, in the loop over the planes that the innermost scope holds.
+    plane_loop = f"/{scopes[-1]}/while/body/"
+    found = [
+        n for n in names
+        if n.endswith(operation) and plane_loop in n and all(f"/{s}/" in n for s in scopes)
+    ]
+    assert found, f"no {operation} under {scopes} in a plane loop among {sorted(set(names))[:20]}"
