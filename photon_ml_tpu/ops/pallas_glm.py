@@ -17,18 +17,35 @@ The kernels here stream each row-tile of X from HBM into VMEM **once** and
 run both MXU contractions on it while it is resident:
 
     per row-tile T:
-        z_T   = X_T @ w                (MXU, [TILE_N, D] @ [D, 1])
-        u_T   = weight_T * l'(z_T, y_T)   (VPU)
+        z_T   = w . X_T^T              (MXU, [k, D] . [TILE_N, D]^T -> [k, TILE_N])
+        u_T   = weight_T * l'(z_T, y_T)   (VPU, [1, TILE_N])
         val  += sum(weight_T * l(z_T, y_T))
-        g    += X_T^T @ u_T            (MXU, contraction over rows)
+        g    += u_T . X_T              (MXU, [k, TILE_N] . [TILE_N, D] -> [k, D])
 
-The Hessian-vector kernel additionally packs [w | v] into a single
-[D, 2] right-hand side so the two forward matvecs TRON needs (margins and
+The Hessian-vector kernel additionally packs [w ; v] into a single
+[2, D] left-hand side so the two forward matvecs TRON needs (margins and
 `q = X @ v`) cost one MXU pass:
 
-    zq_T  = X_T @ [w | v]              (MXU, [TILE_N, D] @ [D, 2])
+    zq_T  = [w ; v] . X_T^T            (MXU, [2, D] . [TILE_N, D]^T)
     r_T   = weight_T * l''(z_T, y_T) * q_T
-    hv   += X_T^T @ r_T
+    hv   += r_T . X_T
+
+**Layout: every per-row operand is lane-dense — rows on the lane axis.**
+Labels, offsets and weights go in as (1, N) rows blocked (1, TILE_N); the
+margins, the loss and `u` are (1, TILE_N) in the kernel; the coefficients
+and the gradient travel as (k, D) rows. No (N, 1) or (D, 1) column is made
+anywhere: the chip lays a 2-D array in (8, 128) tiles and pads its minor
+dimension to 128 lanes, so an f32[400000, 1] column is 204.8 MB for 1.6 MB
+of numbers. Until PR 35 the kernels took columns: each `reshape(n, 1)` was
+a 205 MB copy made again at every evaluation (three an evaluation, 18 a fit
+of `lr-epsilon.fit`, 5.7 ms of 61.7), the kernel read 614 MB of padding
+beside the 1,600 MB of X, every per-row quantity in it was a (520, 1) array
+of 65 vregs with 8 live numbers each, and the gradient contracted X on its
+row axis (a transposed-left matmul of the whole tile). With rows the
+margins are the `q . k^T` form and the gradient a plain matmul with X as it
+lies: X is never transposed. From an f32[N] vector a (1, N) row is the same
+numbers in the same order (XLA still writes a `reshape`: a vector's T(1024)
+tiles pad N up, a row's T(1,128) tiles do not; 1.6 MB, microseconds).
 
 Both kernels return *raw sums* (including `sum(u)` / `sum(r)`), so the
 normalization-as-coefficient-algebra trick (ops/normalization.py, mirroring
@@ -48,21 +65,22 @@ used otherwise.
 
 Precision/roofline history (v5e, 1M x 512 f32): at HIGHEST (6 bf16 MXU
   passes per matmul) the kernels were MXU-bound, not HBM-bound — the
-  width-1/2 RHS pads to the 128-lane MXU tile and HIGHEST multiplies the
+  width-1/2 thin operand pads to an MXU tile and HIGHEST multiplies the
   passes, so bf16 X (half the HBM bytes) measured the SAME wall per pass
   (r03: 179-217 GB/s effective). DEFAULT was faster but its bf16-rounded
   gradients cost ~1.5x more line-search evaluations. The current default
   'hilo' (see the PHOTON_PALLAS_PRECISION block below) computes each
-  matmul in TWO bf16 passes over a hi/lo split of X with the RHS's hi/lo
-  halves stacked along the free dimension — all four cross products, 3x
-  less MXU work than HIGHEST, at ~2e-5 agreement with a float64 host
-  reference (f32 accumulation is the shared accuracy floor).
+  matmul in TWO bf16 passes over a hi/lo split of X with the thin
+  operand's hi/lo halves stacked along its free (sublane) dimension — all
+  four cross products, 3x less MXU work than HIGHEST, at ~2e-5 agreement
+  with a float64 host reference (f32 accumulation is the shared accuracy
+  floor).
 
 bf16-STORED X (r05, `prefers_bf16_storage`): the training design matrix is
   additionally stored bf16 by the fixed-effect coordinate when the kernels
   engage — half the HBM bytes per pass AND a single MXU pass per
-  contraction (_dot_bf16x: the lo half of X is zero by construction, so
-  only the RHS is hi/lo split). Quantization is data-level (~2^-8, once);
+  contraction (_x_parts: the lo half of X is zero by construction, so
+  only the thin operand is hi/lo split). Quantization is data-level (~2^-8, once);
   the optimizer solves that problem exactly, so fn_evals stay at f32
   behavior (measured 27 -> 31 at 1M x 512, wall 0.124 -> 0.104 s/solve,
   469 -> 641 GB/s f32-normalized effective, coef diff 4e-4 relative).
@@ -89,9 +107,11 @@ from photon_ml_tpu.utils.knobs import get_knob
 
 Array = jax.Array
 
-# Row-tile height. 512 rows x 512 features x 4 B = 1 MB per X tile; with
-# double buffering and the [D, 1]/[D, 2] operands this stays well inside the
-# ~16 MB/core VMEM envelope up to D ~ 4096.
+# Row-tile height: the rows of X a grid step holds in VMEM, and the lane-axis
+# block of every per-row operand (labels, offsets, weights travel as (1, n)
+# rows, blocked (1, tile)), so it is a multiple of 128 lanes. 1024 rows x 512
+# features x 4 B = 2 MB per X tile; with double buffering and the (k, d)
+# coefficient rows this stays well inside the ~16 MB/core VMEM envelope.
 #
 # Env overrides are validated leniently: a bad value falls back to the
 # default with a warning instead of making the whole package unimportable
@@ -100,7 +120,7 @@ def _env_tile() -> int:
     raw = str(get_knob("PHOTON_PALLAS_TILE"))
     try:
         tile = int(raw)
-        if tile < 8 or tile % 8 != 0:
+        if tile < 128 or tile % 128 != 0:
             raise ValueError
         if tile > 1024:
             # A 2048-row tile at d=512 in hilo mode is exactly the 8 MB
@@ -120,8 +140,9 @@ def _env_tile() -> int:
         import logging
 
         logging.getLogger(__name__).warning(
-            "PHOTON_PALLAS_TILE=%r: must be a positive multiple of 8 (TPU "
-            "sublane alignment); using the default 1024",
+            "PHOTON_PALLAS_TILE=%r: must be a positive multiple of 128 (the "
+            "row tile is the lane-axis block of the per-row operands); "
+            "using the default 1024",
             raw,
         )
         return 1024
@@ -144,9 +165,9 @@ _MIN_COLS = 128
 _DISABLE_ENV = "PHOTON_DISABLE_PALLAS"
 
 # MXU precision for the kernels' thin matmuls. The default 'hilo' runs TWO
-# bf16 passes over a hi/lo split of X with the tiny RHS's hi/lo halves
-# stacked along the free (lane) dimension — the MXU pads that dimension to
-# 128 anyway, so the extra RHS columns are free and all four cross products
+# bf16 passes over a hi/lo split of X with the thin operand's hi/lo halves
+# stacked along its free (sublane) dimension — the MXU pads that dimension to
+# a tile anyway, so the extra rows are free and all four cross products
 # land in 2 passes instead of HIGHEST's 6 (the r03 kernels were MXU-bound
 # at HIGHEST precisely because of those passes; see the module docstring's
 # roofline note). Accuracy: each operand is represented hi+lo to ~2^-16
@@ -159,7 +180,7 @@ _PRECISION_NAMES = {
     "highest": jax.lax.Precision.HIGHEST,
     "high": jax.lax.Precision.HIGH,
     "default": jax.lax.Precision.DEFAULT,
-    "hilo": None,  # handled by _dot_hilo_parts, not lax precision
+    "hilo": None,  # handled by _rows_dot, not lax precision
 }
 _prec_name = str(get_knob("PHOTON_PALLAS_PRECISION"))
 _PREC_MODE = _prec_name
@@ -399,8 +420,8 @@ def prefers_bf16_storage(features, w: Array) -> bool:
 
     True when the fused kernels engage in hilo mode: bf16 storage halves
     the HBM bytes streamed per objective evaluation AND halves the MXU
-    passes (_dot_bf16x), while every multiply stays exact for the stored
-    data (the RHS is hi/lo split, never quantized). The quantization is
+    passes (_x_parts), while every multiply stays exact for the stored
+    data (the thin operand is hi/lo split, never quantized). The quantization is
     data-level (~2^-8 relative on X entries, once) — the optimizer then
     solves that problem EXACTLY, so line searches and fn_evals behave as
     at f32, unlike bf16-rounded arithmetic on f32 data (which the r03
@@ -419,26 +440,31 @@ def prefers_bf16_storage(features, w: Array) -> bool:
 
 
 def _tile_for(d: int) -> int:
-    """Row-tile height for feature width d: the largest multiple of 8 not
+    """Row-tile height for feature width d: the largest multiple of 128 not
     above _TILE_N whose VMEM working set (f32 tile + hilo's bf16 hi/lo
-    copies) fits the budget. Below _TILE_MIN the grid overhead dominates —
-    callers fall back to XLA (_static_checks)."""
+    copies) fits the budget — 512 at d = 2,000, 1,024 at d = 512. A multiple
+    of 128 because the tile is also the lane-axis block of the (1, n) row
+    operands, and because the gradient contracts over it in MXU chunks of
+    128 (520 rows cost five chunks where 512 cost four). Below _TILE_MIN the
+    grid overhead dominates — callers fall back to XLA (_static_checks)."""
     per_row = d * (8 if _PREC_MODE == "hilo" else 4)
     tile = min(_TILE_N, _TILE_BYTES_LIMIT // max(per_row, 1))
-    return max(8, tile - tile % 8)
+    return max(128, tile - tile % 128)
 
 
-def _row_mask(n: int, tile: int) -> Array:
-    """(tile, 1) validity mask for the current grid step's rows.
+def _row_mask(n: int, tile: int, axis: int) -> Array:
+    """Validity mask of the current grid step's rows, laid along `axis` of a
+    2-D array: (1, tile) for the lane-dense per-row operands (axis 1),
+    (tile, 1) for the X tile whose rows lie on sublanes (axis 0).
 
     Array sizes need not divide the block shape: Pallas pads boundary-block
     reads with undefined values, so every input is masked to exact zeros
     before use (a zero row contributes exactly zero to each accumulated sum —
     and masking x/y/offset as well as weight keeps NaN/Inf garbage from the
-    padded lanes out of 0*NaN traps in the losses).
+    padded rows out of 0*NaN traps in the losses and on the MXU).
     """
-    base = pl.program_id(0) * tile
-    rows = base + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
+    shape = (tile, 1) if axis == 0 else (1, tile)
+    rows = pl.program_id(0) * tile + jax.lax.broadcasted_iota(jnp.int32, shape, axis)
     return rows < n
 
 
@@ -449,121 +475,142 @@ def _hilo_split(a: Array) -> Tuple[Array, Array]:
     return hi, lo
 
 
-def _dot_hilo_parts(xhi: Array, xlo: Array, rhs: Array, dims) -> Array:
-    """f32-quality matmul in 2 bf16 MXU passes over a pre-split X.
+def _x_parts(x: Array):
+    """The X tile as the MXU operands of one contraction under the
+    configured precision mode, computed once per tile and shared by both
+    contractions: bf16-stored X as it lies (its lo half is zero by
+    construction, so hilo runs ONE pass — half the HBM bytes and half the
+    passes); f32 X as its bf16 hi/lo pair (two passes against HIGHEST's
+    six); f32 X itself under a classic lax precision."""
+    if _PREC_MODE != "hilo":
+        return (x.astype(jnp.float32),)
+    if x.dtype == jnp.bfloat16:
+        return (x,)
+    return _hilo_split(x.astype(jnp.float32))
 
-    The RHS's hi/lo halves are stacked along its free dimension, which the
-    MXU pads to 128 lanes regardless — so each X pass computes both cross
-    products for free, and hi/lo X costs 2 passes total (vs HIGHEST's 6).
-    """
-    k = rhs.shape[1]
-    rhi, rlo = _hilo_split(rhs)
-    rhs2 = jnp.concatenate([rhi, rlo], axis=1)
-    out = jax.lax.dot_general(
-        xhi, rhs2, dimension_numbers=(dims, ((), ())),
-        preferred_element_type=jnp.float32,
-    ) + jax.lax.dot_general(
-        xlo, rhs2, dimension_numbers=(dims, ((), ())),
-        preferred_element_type=jnp.float32,
+
+def _rows_dot(rows: Array, x_parts, x_axis: int) -> Array:
+    """`rows` (k, m) f32 contracted on its lane axis with axis `x_axis` of
+    the X tile -> (k, ·) f32: the margins `w_rows · X^T` (x_axis 1, the
+    q·k^T form) and the gradient `u_rows · X` (x_axis 0, a plain matmul).
+    X is the MXU's stationary operand both times and is never transposed.
+
+    In hilo mode the f32 rows are hi/lo split and stacked along their free
+    SUBLANE axis (the MXU pads k to a sublane tile anyway, so the extra rows
+    are free): each pass over an X part computes both cross products, and
+    the product is exact for bf16 data up to f32 accumulation — the rows are
+    never quantized."""
+    dims = (((1,), (x_axis,)), ((), ()))
+    if _PREC_MODE != "hilo":
+        return jax.lax.dot_general(
+            rows, x_parts[0], dimension_numbers=dims,
+            preferred_element_type=jnp.float32, precision=_PRECISION,
+        )
+    k = rows.shape[0]
+    rows2 = jnp.concatenate(_hilo_split(rows), axis=0)
+    out = sum(
+        jax.lax.dot_general(
+            rows2, part, dimension_numbers=dims,
+            preferred_element_type=jnp.float32,
+        )
+        for part in x_parts
     )
-    return out[:, :k] + out[:, k:]
+    return out[:k] + out[k:]
 
 
-def _dot_bf16x(x: Array, rhs: Array, dims) -> Array:
-    """Matmul against bf16-STORED X in ONE MXU pass.
-
-    The f32 RHS is hi/lo split and stacked along its free dimension (padded
-    to 128 MXU lanes anyway), so the product is exact for the bf16 data up
-    to f32 accumulation — no RHS quantization. Data stored bf16 halves HBM
-    bytes AND halves the hilo mode's MXU passes (the lo half of X is zero
-    by construction, so its pass is dropped)."""
-    k = rhs.shape[1]
-    rhi, rlo = _hilo_split(rhs.astype(jnp.float32))
-    rhs2 = jnp.concatenate([rhi, rlo], axis=1)
-    out = jax.lax.dot_general(
-        x, rhs2, dimension_numbers=(dims, ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    return out[:, :k] + out[:, k:]
-
-
-def _dot_pair(x, x_split, rhs, dims):
-    """One kernel matmul under the configured precision mode. `x_split` is
-    the hi/lo pair (computed once per tile, shared by both contractions);
-    it is None when X is stored bf16 (single-pass path)."""
-    if _PREC_MODE == "hilo":
-        if x.dtype == jnp.bfloat16:
-            return _dot_bf16x(x, rhs, dims)
-        return _dot_hilo_parts(x_split[0], x_split[1], rhs, dims)
-    return jax.lax.dot_general(
-        x, rhs, dimension_numbers=(dims, ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=_PRECISION,
+def _masked_tile(n: int, tile: int, x_ref, y_ref, off_ref, wt_ref):
+    """This grid step's operands with the rows beyond n zeroed: the X tile
+    as MXU operands (`_x_parts`), and labels, offsets and weights as
+    (1, tile) rows — rows on the lane axis, four vregs at tile 512 where a
+    (tile, 1) column took sixty-five. A zero in `u` does not silence a NaN
+    in a padded row of X on the MXU, so X is masked too; the whole-tile
+    select hides under the tile's DMA (2.18 ms a call with it on every
+    step, 2.17 with none, at 400,000 x 2,000 bf16 on a v5e)."""
+    rows = _row_mask(n, tile, 1)
+    return (
+        _x_parts(jnp.where(_row_mask(n, tile, 0), x_ref[:], 0)),
+        jnp.where(rows, y_ref[:], 0.0),
+        jnp.where(rows, off_ref[:], 0.0),
+        jnp.where(rows, wt_ref[:], 0.0),
     )
 
 
 def _value_grad_kernel(loss: PointwiseLoss, n: int, tile: int, x_ref, y_ref,
                        off_ref, wt_ref, w_ref, stats_ref, grad_ref):
-    i = pl.program_id(0)
-    valid = _row_mask(n, tile)
-    # bf16-stored X streams at half the HBM traffic and runs single-pass in
-    # hilo mode (_dot_bf16x); f32 X is hi/lo split once per tile. Either
-    # way compute accumulates in f32.
-    x = jnp.where(valid, x_ref[:], 0)
-    if x.dtype == jnp.bfloat16 and _PREC_MODE == "hilo":
-        x_split = None
-    else:
-        x = x.astype(jnp.float32)
-        x_split = _hilo_split(x) if _PREC_MODE == "hilo" else None
-    z = _dot_pair(
-        x, x_split, w_ref[:], (((1,), (0,)))
-    ) + jnp.where(valid, off_ref[:], 0.0)
-    y = jnp.where(valid, y_ref[:], 0.0)
-    wt = jnp.where(valid, wt_ref[:], 0.0)
-    val = jnp.sum(wt * loss.loss(z, y))
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        stats_ref[0, 0] = 0.0
+        stats_ref[0, 1] = 0.0
+        grad_ref[:] = jnp.zeros_like(grad_ref)
+
+    x_parts, y, off, wt = _masked_tile(n, tile, x_ref, y_ref, off_ref, wt_ref)
+    z = _rows_dot(w_ref[:], x_parts, 1) + off
     u = wt * loss.d1(z, y)
-    g = _dot_pair(x, x_split, u, (((0,), (0,))))
-    sum_u = jnp.sum(u)
-
-    @pl.when(i == 0)
-    def _():
-        stats_ref[0, 0] = val
-        stats_ref[0, 1] = sum_u
-        grad_ref[:] = g
-
-    @pl.when(i > 0)
-    def _():
-        stats_ref[0, 0] += val
-        stats_ref[0, 1] += sum_u
-        grad_ref[:] += g
+    stats_ref[0, 0] += jnp.sum(wt * loss.loss(z, y))
+    stats_ref[0, 1] += jnp.sum(u)
+    grad_ref[:] += _rows_dot(u, x_parts, 0)
 
 
 def _hvp_kernel(loss: PointwiseLoss, n: int, tile: int, x_ref, y_ref,
                 off_ref, wt_ref, wv_ref, vshift_ref, stats_ref, hv_ref):
-    i = pl.program_id(0)
-    valid = _row_mask(n, tile)
-    x = jnp.where(valid, x_ref[:], 0)
-    if x.dtype == jnp.bfloat16 and _PREC_MODE == "hilo":
-        x_split = None
-    else:
-        x = x.astype(jnp.float32)
-        x_split = _hilo_split(x) if _PREC_MODE == "hilo" else None
-    zq = _dot_pair(x, x_split, wv_ref[:], ((1,), (0,)))
-    z = zq[:, 0:1] + jnp.where(valid, off_ref[:], 0.0)
-    q = zq[:, 1:2] + vshift_ref[0, 0]
-    r = jnp.where(valid, wt_ref[:], 0.0) * loss.d2(z, jnp.where(valid, y_ref[:], 0.0)) * q
-    hv = _dot_pair(x, x_split, r, ((0,), (0,)))
-    sum_r = jnp.sum(r)
-
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(0) == 0)
     def _():
-        stats_ref[0, 0] = sum_r
-        hv_ref[:] = hv
+        stats_ref[0, 0] = 0.0
+        hv_ref[:] = jnp.zeros_like(hv_ref)
 
-    @pl.when(i > 0)
-    def _():
-        stats_ref[0, 0] += sum_r
-        hv_ref[:] += hv
+    x_parts, y, off, wt = _masked_tile(n, tile, x_ref, y_ref, off_ref, wt_ref)
+    zq = _rows_dot(wv_ref[:], x_parts, 1)  # rows: the margins, X @ v
+    r = wt * loss.d2(zq[0:1] + off, y) * (zq[1:2] + vshift_ref[0, 0])
+    stats_ref[0, 0] += jnp.sum(r)
+    hv_ref[:] += _rows_dot(r, x_parts, 0)
+
+
+def _dense_call(kernel, name, features, row_operands, coef_rows, scalars,
+                n_stats, flops_per_entry, interpret):
+    """One pass of `kernel` over the row tiles of X. Every per-row operand
+    goes in as a (1, n) row blocked (1, tile) — from an (n,) vector that is
+    a bitcast — and the coefficients as (k, d) rows; the results are the
+    (1, n_stats) scalar sums in SMEM and one (1, d) row. No (n, 1) or
+    (d, 1) array is made: on the chip an f32 column pads its minor
+    dimension of 1 to 128 lanes, 128 times its size, and the kernel would
+    read the padding beside X."""
+    n, d = features.shape
+    tile = _tile_for(d)
+    row = lambda a: a.reshape(1, n).astype(jnp.float32)
+    row_spec = pl.BlockSpec((1, tile), lambda i: (0, i), memory_space=_VMEM)
+    whole = lambda shape, space: pl.BlockSpec(shape, lambda i: (0, 0), memory_space=space)
+    return pl.pallas_call(
+        functools.partial(kernel, n, tile),
+        # What a device trace calls this operation, said here and not left
+        # to what JAX infers from the enclosing function: the benchmark's
+        # readers match it (benchmarks/layers/kernels.py), and count an
+        # evaluation by the (1, 2) pair that the first result is.
+        name=name,
+        grid=(pl.cdiv(n, tile),),
+        in_specs=[
+            pl.BlockSpec((tile, d), lambda i: (i, 0), memory_space=_VMEM),
+            *[row_spec] * len(row_operands),
+            whole(coef_rows.shape, _VMEM),
+            *[whole((1, 1), _SMEM)] * len(scalars),
+        ],
+        out_specs=[whole((1, n_stats), _SMEM), whole((1, d), _VMEM)],
+        out_shape=[
+            jax.ShapeDtypeStruct((1, n_stats), jnp.float32),
+            jax.ShapeDtypeStruct((1, d), jnp.float32),
+        ],
+        cost_estimate=pl.CostEstimate(
+            flops=flops_per_entry * n * d,
+            bytes_accessed=n * d * features.dtype.itemsize
+            + 4 * n * len(row_operands) + 4 * d * (coef_rows.shape[0] + 1),
+            transcendentals=2 * n,
+        ),
+        interpret=interpret,
+    )(
+        features,
+        *[row(a) for a in row_operands],
+        coef_rows.astype(jnp.float32),
+        *[jnp.asarray(s, jnp.float32).reshape(1, 1) for s in scalars],
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("loss", "interpret"))
@@ -588,51 +635,13 @@ def value_gradient_sums(
     L2 terms are the caller's job (ops/objective.py), exactly as the raw
     aggregator sums are post-processed in the reference.
     """
-    n, d = features.shape
     # Fold the scalar margin shift into offsets so the kernel sees one vector.
-    offsets = offsets + shift
-    tile = _tile_for(d)
-    grid = (pl.cdiv(n, tile),)
-
-    col = lambda a: a.reshape(n, 1).astype(jnp.float32)
-    kernel = functools.partial(_value_grad_kernel, loss, n, tile)
-    row_spec = pl.BlockSpec((tile, 1), lambda i: (i, 0), memory_space=_VMEM)
-    stats, grad = pl.pallas_call(
-        kernel,
-        # What a device trace calls this operation, said here and not left
-        # to what JAX infers from the enclosing function: the benchmark's
-        # readers match it (benchmarks/layers/kernels.py).
-        name="value_gradient_sums",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile, d), lambda i: (i, 0), memory_space=_VMEM),
-            row_spec,
-            row_spec,
-            row_spec,
-            pl.BlockSpec((d, 1), lambda i: (0, 0), memory_space=_VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 2), lambda i: (0, 0), memory_space=_SMEM),
-            pl.BlockSpec((d, 1), lambda i: (0, 0), memory_space=_VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 2), jnp.float32),
-            jax.ShapeDtypeStruct((d, 1), jnp.float32),
-        ],
-        cost_estimate=pl.CostEstimate(
-            flops=4 * n * d,
-            bytes_accessed=n * d * features.dtype.itemsize,
-            transcendentals=2 * n,
-        ),
-        interpret=interpret,
-    )(
-        features,
-        col(labels),
-        col(offsets),
-        col(weights),
-        w_eff.reshape(d, 1).astype(jnp.float32),
+    stats, grad = _dense_call(
+        functools.partial(_value_grad_kernel, loss), "value_gradient_sums",
+        features, (labels, offsets + shift, weights), w_eff[None, :], (),
+        n_stats=2, flops_per_entry=4, interpret=interpret,
     )
-    return stats[0, 0], grad[:, 0], stats[0, 1]
+    return stats[0, 0], grad[0], stats[0, 1]
 
 
 @functools.partial(jax.jit, static_argnames=("loss", "interpret"))
@@ -655,52 +664,13 @@ def hessian_vector_sums(
         hv_raw = X^T r,   r = weight * l''(z, y) * (X @ v_eff + v_shift)
         sum_r  = sum_i r_i
     """
-    n, d = features.shape
-    offsets = offsets + shift
-    tile = _tile_for(d)
-    grid = (pl.cdiv(n, tile),)
-
-    col = lambda a: a.reshape(n, 1).astype(jnp.float32)
-    wv = jnp.stack(
-        [w_eff.astype(jnp.float32), v_eff.astype(jnp.float32)], axis=1
-    )  # [D, 2]
-    kernel = functools.partial(_hvp_kernel, loss, n, tile)
-    row_spec = pl.BlockSpec((tile, 1), lambda i: (i, 0), memory_space=_VMEM)
-    stats, hv = pl.pallas_call(
-        kernel,
-        name="hessian_vector_sums",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile, d), lambda i: (i, 0), memory_space=_VMEM),
-            row_spec,
-            row_spec,
-            row_spec,
-            pl.BlockSpec((d, 2), lambda i: (0, 0), memory_space=_VMEM),
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=_SMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=_SMEM),
-            pl.BlockSpec((d, 1), lambda i: (0, 0), memory_space=_VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, 1), jnp.float32),
-            jax.ShapeDtypeStruct((d, 1), jnp.float32),
-        ],
-        cost_estimate=pl.CostEstimate(
-            flops=6 * n * d,
-            bytes_accessed=n * d * features.dtype.itemsize,
-            transcendentals=2 * n,
-        ),
-        interpret=interpret,
-    )(
-        features,
-        col(labels),
-        col(offsets),
-        col(weights),
-        wv,
-        jnp.asarray(v_shift, jnp.float32).reshape(1, 1),
+    stats, hv = _dense_call(
+        functools.partial(_hvp_kernel, loss), "hessian_vector_sums",
+        features, (labels, offsets + shift, weights),
+        jnp.stack([w_eff, v_eff]), (v_shift,),
+        n_stats=1, flops_per_entry=6, interpret=interpret,
     )
-    return hv[:, 0], stats[0, 0]
+    return hv[0], stats[0, 0]
 
 
 # ---------------------------------------------------------------- distributed

@@ -221,7 +221,7 @@ _register(
     "PHOTON_PALLAS_TILE",
     int,
     1024,
-    "Dense kernel row-tile height; multiple of 8, capped at the "
+    "Dense kernel row-tile height; multiple of 128, capped at the "
     "measured-good 1024.",
 )
 _register(

@@ -20,16 +20,18 @@ from photon_ml_tpu.ops.normalization import NormalizationContext
 LOSSES = [LOGISTIC, SQUARED, POISSON, SMOOTHED_HINGE]
 
 
-def _problem(rng, n, d, poisson_scale=False):
+def _problem(rng, n, d, poisson_scale=False, x_dtype=jnp.float32):
     X = rng.normal(size=(n, d)).astype(np.float32)
     if poisson_scale:
         X *= 0.1
+    if d > 1000:  # rows of unit norm, as the wide dense cell's (lr-epsilon)
+        X /= np.sqrt(d)
     y = (rng.uniform(size=n) > 0.5).astype(np.float32)
     offsets = rng.normal(size=n).astype(np.float32) * 0.1
     weights = rng.uniform(0.5, 2.0, size=n).astype(np.float32)
     w = (rng.normal(size=d) * 0.1).astype(np.float32)
     return (
-        jnp.asarray(X),
+        jnp.asarray(X).astype(x_dtype),
         jnp.asarray(y),
         jnp.asarray(offsets),
         jnp.asarray(weights),
@@ -37,11 +39,25 @@ def _problem(rng, n, d, poisson_scale=False):
     )
 
 
+# (rows, features, X's storage). The row tile is 1,024 at d = 64 and 512 at
+# d = 2,000 (`_tile_for`); 1,100 rows are no multiple of 128 and leave a
+# ragged last tile at either width, 1,024 fit exactly. Interpret mode pads a
+# boundary block with NaN in every operand, so each ragged case has NaNs
+# planted beyond row n (test_the_row_mask_is_what_keeps_the_padding_out
+# shows they are there).
+SHAPES = [
+    pytest.param(1024, 64, jnp.float32, id="1024"),
+    pytest.param(1100, 64, jnp.float32, id="1100"),
+    pytest.param(1100, 64, jnp.bfloat16, id="1100-bf16"),
+    pytest.param(1100, 2000, jnp.float32, id="1100x2000"),
+    pytest.param(1100, 2000, jnp.bfloat16, id="1100x2000-bf16"),
+]
+
+
 @pytest.mark.parametrize("loss", LOSSES, ids=lambda l: l.name)
-@pytest.mark.parametrize("n", [1024, 1100])  # exact tile fit + ragged remainder
-def test_value_gradient_sums_match_xla(rng, loss, n):
-    d = 64
-    X, y, off, wt, w = _problem(rng, n, d, poisson_scale=loss is POISSON)
+@pytest.mark.parametrize("n,d,x_dtype", SHAPES)
+def test_value_gradient_sums_match_xla(rng, loss, n, d, x_dtype):
+    X, y, off, wt, w = _problem(rng, n, d, poisson_scale=loss is POISSON, x_dtype=x_dtype)
     data = LabeledData(features=X, labels=y, offsets=off, weights=wt)
 
     val_ref, g_ref = objective.value_and_gradient(loss, w, data)
@@ -56,14 +72,14 @@ def test_value_gradient_sums_match_xla(rng, loss, n):
     # is accurate to ~1e-5 of the vector's scale.
     g_scale = float(np.max(np.abs(np.asarray(g_ref)))) + 1e-6
     assert float(np.max(np.abs(np.asarray(g) - np.asarray(g_ref)))) < 3e-5 * g_scale
-    u = wt * loss.d1(X @ w + off, y)
+    u = wt * loss.d1(X.astype(jnp.float32) @ w + off, y)
     np.testing.assert_allclose(float(sum_u), float(jnp.sum(u)), rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("loss", [LOGISTIC, SQUARED, POISSON], ids=lambda l: l.name)
-def test_hessian_vector_sums_match_xla(rng, loss):
-    n, d = 1100, 64
-    X, y, off, wt, w = _problem(rng, n, d, poisson_scale=loss is POISSON)
+@pytest.mark.parametrize("n,d,x_dtype", SHAPES[1:])
+def test_hessian_vector_sums_match_xla(rng, loss, n, d, x_dtype):
+    X, y, off, wt, w = _problem(rng, n, d, poisson_scale=loss is POISSON, x_dtype=x_dtype)
     v = jnp.asarray((rng.normal(size=d)).astype(np.float32))
     data = LabeledData(features=X, labels=y, offsets=off, weights=wt)
 
@@ -73,9 +89,40 @@ def test_hessian_vector_sums_match_xla(rng, loss):
     )
     hv_scale = float(np.max(np.abs(np.asarray(hv_ref)))) + 1e-6
     assert float(np.max(np.abs(np.asarray(hv) - np.asarray(hv_ref)))) < 3e-5 * hv_scale
-    z = X @ w + off
-    r = wt * loss.d2(z, y) * (X @ v)
+    Xf = X.astype(jnp.float32)
+    r = wt * loss.d2(Xf @ w + off, y) * (Xf @ v)
     np.testing.assert_allclose(float(sum_r), float(jnp.sum(r)), rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("kernel", ["value_gradient", "hessian_vector"])
+def test_the_row_mask_is_what_keeps_the_padding_out(rng, monkeypatch, kernel):
+    """The control of the ragged cases above: with the masks taken out, the
+    same calls return NaN — the padding beyond row n is NaN in interpret
+    mode, and a zero in `u` does not silence a NaN row of X in a matmul."""
+    X, y, off, wt, w = _problem(rng, 1100, 2000, x_dtype=jnp.bfloat16)
+    zero = jnp.zeros(())
+
+    def call():
+        if kernel == "value_gradient":
+            return pallas_glm.value_gradient_sums(LOGISTIC, w, zero, X, y, off, wt, interpret=True)[1]
+        return pallas_glm.hessian_vector_sums(LOGISTIC, w, zero, w, zero, X, y, off, wt, interpret=True)[0]
+
+    def retrace():  # the jitted wrappers hold the kernels they traced
+        pallas_glm.value_gradient_sums.clear_cache()
+        pallas_glm.hessian_vector_sums.clear_cache()
+
+    assert np.isfinite(np.asarray(call())).all()
+    retrace()
+    # Rows masked, X not: the per-row operands are zero beyond n, X is NaN.
+    row_mask = pallas_glm._row_mask
+    monkeypatch.setattr(
+        pallas_glm, "_row_mask",
+        lambda n, tile, axis: row_mask(n, tile, axis) | (axis == 0),
+    )
+    try:
+        assert np.isnan(np.asarray(call())).all()
+    finally:
+        retrace()
 
 
 def test_objective_dispatch_with_normalization(rng, monkeypatch):

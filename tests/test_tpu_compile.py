@@ -35,9 +35,11 @@ from photon_ml_tpu.ops import pallas_glm, pallas_sparse
 from photon_ml_tpu.ops.losses import LOGISTIC
 from photon_ml_tpu.types import TaskType
 
-# README headline shape (dense fixed effect) and the e2e MovieLens shape
-# (sparse fixed effect; chip_smoke.py).
+# README headline shape (dense fixed effect; row tile 1,024), `lr-epsilon`'s
+# (benchmarks/configs/lr-epsilon.json; row tile 512, d no multiple of 128)
+# and the e2e MovieLens shape (sparse fixed effect; chip_smoke.py).
 DENSE_N, DENSE_D = 1_048_576, 512
+EPSILON_N, EPSILON_D, EPSILON_ITERATIONS = 400_000, 2_000, 5
 SPARSE_N, SPARSE_D, SPARSE_NNZ = 262_144, 200, 8
 # chip_smoke's served model at 2M rows: d = 200 + intercept, rows//145
 # users, rows//740 movies (+ the pinned zero row each).
@@ -90,9 +92,12 @@ def _compiled_text(lowered) -> str:
 
 @pytest.mark.parametrize("x_dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("kernel", ["value_gradient", "hessian_vector"])
-def test_dense_kernels_compile_for_v5e(one_chip, kernel, x_dtype):
-    X = jax.ShapeDtypeStruct((DENSE_N, DENSE_D), x_dtype, sharding=one_chip)
-    w, n, s = _vec(one_chip, DENSE_D), _vec(one_chip, DENSE_N), _scalar(one_chip)
+@pytest.mark.parametrize(
+    "rows,dim", [(DENSE_N, DENSE_D), (EPSILON_N, EPSILON_D)], ids=["readme", "epsilon"]
+)
+def test_dense_kernels_compile_for_v5e(one_chip, rows, dim, kernel, x_dtype):
+    X = jax.ShapeDtypeStruct((rows, dim), x_dtype, sharding=one_chip)
+    w, n, s = _vec(one_chip, dim), _vec(one_chip, rows), _scalar(one_chip)
     if kernel == "value_gradient":
         lowered = pallas_glm.value_gradient_sums.lower(LOGISTIC, w, s, X, n, n, n)
     else:
@@ -210,20 +215,27 @@ _HLO_INSTRUCTION = re.compile(
 )
 
 
-_Instruction = collections.namedtuple("_Instruction", "elements layout opcode operands op_name")
+_Instruction = collections.namedtuple(
+    "_Instruction", "elements minor layout opcode operands op_name"
+)
 
 
 def _array_instructions(text):
     """name -> _Instruction of every instruction of a compiled program's
-    text whose result is one array (`operands` are names)."""
+    text whose result is one array (`operands` are names; `minor` is the
+    size of the dimension the layout puts on the lanes)."""
     found = {}
     for line in text.splitlines():
         m = _HLO_INSTRUCTION.match(line)
         if m:
             name, dims, layout, opcode, operands, rest = m.groups()
             op_name = re.search(r'op_name="([^"]*)"', rest)
+            dims = [int(d) for d in dims.split(",") if d]
+            # The minor dimension's size: the first index of `{1,0:T(8,128)}`.
+            minor = re.match(r"\{(\d+)", layout or "")
             found[name] = _Instruction(
-                math.prod(int(d) for d in dims.split(",") if d), layout or "", opcode,
+                math.prod(dims), dims[int(minor.group(1))] if minor and dims else 1,
+                layout or "", opcode,
                 [o.strip() for o in operands.split(",")], op_name.group(1) if op_name else "",
             )
     return found
@@ -267,6 +279,54 @@ def test_the_plane_loop_gathers_from_vmem_at_the_criteo_shape(one_chip):
     assert min(tables) == 1 and max(tables) >= 3, sorted(tables)
     for depth, table in tables.items():
         assert table.elements == CRITEO_DIM + 1 and "S(1)" in table.layout, (depth, table)
+
+
+# ------------------------------------- the dense objective's per-row operands
+
+# `lr-epsilon.fit`'s solve: the dense value+gradient kernel under L-BFGS's
+# loops. On the chip an array's minor dimension is padded to 128 lanes, so an
+# `f32[400000,1]` column is 204.8 MB for 1.6 MB of numbers: the kernel took
+# labels, offsets and weights as such columns until PR 35, each made by a
+# `reshape` that was a 205 MB copy at every evaluation (18 a fit), and read
+# the padding beside X. Now they go in as `f32[1,400000]` rows.
+
+
+def test_the_dense_solve_holds_no_padded_column_at_the_epsilon_shape(one_chip):
+    from photon_ml_tpu.data.containers import LabeledData
+    from photon_ml_tpu.ops import objective
+    from photon_ml_tpu.optimize.lbfgs import minimize_lbfgs
+
+    def solve(features, labels, offsets, weights, w0):
+        data = LabeledData(features, labels, offsets, weights)
+        return minimize_lbfgs(
+            lambda w: objective.value_and_gradient(LOGISTIC, w, data, None, 1.0, use_pallas=True),
+            w0, max_iterations=EPSILON_ITERATIONS, tolerance=1e-7,
+        ).coefficients
+
+    rows = _vec(one_chip, EPSILON_N)
+    instructions = _array_instructions(_compiled_text(jax.jit(solve).lower(
+        jax.ShapeDtypeStruct((EPSILON_N, EPSILON_D), jnp.bfloat16, sharding=one_chip),
+        rows, rows, rows, _vec(one_chip, EPSILON_D),
+    )))
+    # The kernel is there, before the loops and in the line search.
+    calls = {
+        i.op_name.count("while/body") for i in instructions.values()
+        if i.opcode == "get-tuple-element" and i.op_name.endswith("value_gradient_sums/pallas_call")
+    }
+    assert calls == {0, 2}, sorted(calls)
+    # No array of the rows' size puts fewer than 128 numbers on the lanes,
+    # in the loops or before them.
+    padded = [
+        (name, i.opcode, i.layout) for name, i in instructions.items()
+        if i.elements >= EPSILON_N and i.minor < 128
+    ]
+    assert not padded, padded[:5]
+    # What is left of the reshapes: XLA keeps one for each row operand (a
+    # vector's `T(1024)` tiles pad 400,000 elements to 400,384, a row's
+    # `T(1,128)` tiles do not, so the two are not one buffer), before the
+    # loops and in them, and it moves the 1.6 MB of numbers, not 205 MB.
+    reshapes = [i for i in instructions.values() if i.opcode == "reshape" and i.elements >= EPSILON_N]
+    assert len(reshapes) <= 6 and all(i.elements == i.minor == EPSILON_N for i in reshapes), reshapes
 
 
 # ----------------------------------------------------------------- serving
