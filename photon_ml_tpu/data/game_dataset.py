@@ -272,6 +272,10 @@ class GameDataset:
     # bench e2e contract fails loudly when a dataset that came from disk is
     # missing any key.
     ingest_timing: Dict[str, object] = dataclasses.field(default_factory=dict)
+    # Zero-weight rows at the end that even the sample axis out over a mesh,
+    # made by parallel/mesh.py or stated by a caller that made them itself;
+    # they are counted in `num_samples`.
+    pad_rows: int = 0
 
     @property
     def num_samples(self) -> int:
@@ -319,8 +323,10 @@ class GameDataset:
     ) -> "GameDataset":
         labels = jnp.asarray(labels, dtype)
         n = labels.shape[0]
-        offsets = jnp.zeros(n, dtype) if offsets is None else jnp.asarray(offsets, dtype)
-        weights = jnp.ones(n, dtype) if weights is None else jnp.asarray(weights, dtype)
+        # Defaults are placed as the labels are: sharded beside sharded
+        # labels, on their device beside labels another device holds.
+        offsets = jnp.zeros_like(labels) if offsets is None else jnp.asarray(offsets, dtype)
+        weights = jnp.ones_like(labels) if weights is None else jnp.asarray(weights, dtype)
         tags = {k: np.asarray(v) for k, v in (id_tags or {}).items()}
         for k, v in tags.items():
             if len(v) != n:

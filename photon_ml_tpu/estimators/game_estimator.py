@@ -643,11 +643,13 @@ class GameEstimator:
     def _publish_stages(self, stages: TimingRegistry, base: Dict[str, float]):
         """This fit's stage walls and evaluation counts, per fit and per
         process: `fit_timing["stages_s"]` (plain floats; a stage that did
-        not run reads 0.0) beside `fit_timing["fn_evals"]` and
-        `fit_timing["line_search_rejected"]`, and the same numbers into
-        `telemetry.METRICS` — histogram `fit_stage_s{stage=<name>}`,
-        counters `objective_evaluations` and `line_search_rejected_trials`
-        `{coordinate=<id>,kind=fixed|random}` — so a reader that sees only
+        not run reads 0.0) beside `fit_timing["fn_evals"]`,
+        `fit_timing["line_search_rejected"]` and
+        `fit_timing["gradient_allreduce_bytes"]` (empty on one device), and
+        the same numbers into `telemetry.METRICS` — histogram
+        `fit_stage_s{stage=<name>}`, counters `objective_evaluations` and
+        `line_search_rejected_trials` `{coordinate=<id>,kind=fixed|random}`,
+        `gradient_allreduce_bytes{coordinate=<id>}` — so a reader that sees only
         the process gets a window's totals as the process total less the
         fits made before it."""
         self.fit_timing["stages_s"] = {
@@ -668,6 +670,10 @@ class GameEstimator:
         for cid, trials in self.fit_timing["line_search_rejected"].items():
             telemetry.METRICS.increment(
                 "line_search_rejected_trials", trials, labels=labels(cid)
+            )
+        for cid, nbytes in self.fit_timing["gradient_allreduce_bytes"].items():
+            telemetry.METRICS.increment(
+                "gradient_allreduce_bytes", nbytes, labels=(("coordinate", cid),)
             )
 
     def _on_cd_event(self, etype: str, **fields) -> None:
@@ -712,7 +718,7 @@ class GameEstimator:
         # no delta, so they reset.
         self.timing_registry.clear_notes(
             "pack_path", "re_path", "sparse_layout", "sparse_objective",
-            "pack_declined",
+            "pack_declined", "sample_sharding",
         )
         # Snapshot the pod-scale robustness counters so fit_timing reports
         # THIS fit's events (the process-wide counters are cumulative).
@@ -764,6 +770,7 @@ class GameEstimator:
         collective_bytes = 0
         fn_evals: Dict[str, int] = {}
         line_search_rejected: Dict[str, int] = {}
+        allreduce_bytes: Dict[str, int] = {}
         sharding_infos: Dict[str, dict] = {}
         default_cfg = CoordinateOptimizationConfig()
         for ci, cfgs in enumerate(opt_configs):
@@ -863,6 +870,15 @@ class GameEstimator:
             collective_bytes += cd.collective_bytes
             for cid, evals in cd.fn_evals.items():
                 fn_evals[cid] = fn_evals.get(cid, 0) + evals
+                # A sample-sharded fixed effect reduces its (d,) gradient
+                # and its value over the mesh once an evaluation.
+                per_evaluation = getattr(
+                    coordinates[cid], "allreduce_bytes_per_evaluation", 0
+                )
+                if per_evaluation:
+                    allreduce_bytes[cid] = (
+                        allreduce_bytes.get(cid, 0) + evals * per_evaluation
+                    )
             for cid, trials in cd.line_search_rejected.items():
                 line_search_rejected[cid] = line_search_rejected.get(cid, 0) + trials
             self.fit_timing["solve_s"] += descent.seconds + final_evaluate.seconds
@@ -875,6 +891,7 @@ class GameEstimator:
         with stage_timer("fit/publish"):
             self.fit_timing["fn_evals"] = fn_evals
             self.fit_timing["line_search_rejected"] = line_search_rejected
+            self.fit_timing["gradient_allreduce_bytes"] = allreduce_bytes
             # Finalize the per-stage prepare breakdown: deltas of the timing
             # registry over this fit call. In a synchronous run the stages +
             # `other` tile `prepare_s`; in a pipelined run overlapped stages
@@ -1220,6 +1237,10 @@ class GameEstimator:
             )
             or "none",
             "pack_declined": self.timing_registry.get_note("pack_declined")
+            or "none",
+            # {devices, rows_per_device, pad_rows} where a fixed effect built
+            # its coordinate on sample-sharded rows in this fit, else "none".
+            "sample_sharding": self.timing_registry.get_note("sample_sharding")
             or "none",
         }
         bucket_shapes: Dict[str, object] = {}

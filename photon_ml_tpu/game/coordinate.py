@@ -299,6 +299,32 @@ class FixedEffectCoordinate:
             # ELL path it is (pack declined/ineligible): materialize the
             # device copy through the dataset so other consumers share it.
             self._features = dataset.shards[config_data_shard]
+        # Sample-sharded rows (parallel/mesh.py): noted with the fit's other
+        # dispatch decisions. An ELL shard's objective is then summed over
+        # the mesh once an evaluation by the program itself
+        # (objective._summed_over_samples); a dense shard's was decided above.
+        from photon_ml_tpu.parallel.mesh import leading_axis_mesh
+
+        mesh = None
+        if not isinstance(self._features, SparseFeatures):
+            mesh = leading_axis_mesh(self._features)
+        elif self._features.ell_axis == -1 and self._features.indices.ndim == 2:
+            mesh = leading_axis_mesh(self._features.indices, require_divisible=True)
+            if mesh is not None:
+                self._use_pallas = pallas_glm.ShardedDispatch(mesh, mesh.axis_names[0])
+        # What every device adds to the reduction of one evaluation, by the
+        # design: the (d,) gradient and the value, float32. 0 on one device.
+        self.allreduce_bytes_per_evaluation = 0
+        if mesh is not None:
+            self.allreduce_bytes_per_evaluation = 4 * (self._features.shape[-1] + 1)
+            set_stage_note(
+                "sample_sharding",
+                {
+                    "devices": mesh.devices.size,
+                    "rows_per_device": dataset.num_samples // mesh.devices.size,
+                    "pad_rows": getattr(dataset, "pad_rows", 0),
+                },
+            )
         self._build_jits()
 
     def _build_jits(self) -> None:

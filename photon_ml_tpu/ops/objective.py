@@ -98,6 +98,55 @@ def _sq_rmatvec(features, u: Array) -> Array:
     return u @ jnp.square(features)
 
 
+def _needs_row_sum(norm: Optional[NormalizationContext]) -> bool:
+    """Does the normalization's gradient algebra need sum_i u_i (shifts)?"""
+    return norm is not None and not norm.is_identity and norm.shifts is not None
+
+
+def _value_gradient_sums(loss, w_eff, shift, data: LabeledData, row_sum: bool):
+    """(value, X^T u, sum u or None) of the rows in `data`, through XLA."""
+    z = _matvec(data.features, w_eff) + shift + data.offsets
+    val = jnp.sum(data.weights * loss.loss(z, data.labels))
+    u = data.weights * loss.d1(z, data.labels)
+    return val, _rmatvec(data.features, u), jnp.sum(u) if row_sum else None
+
+
+def _hessian_vector_sums(loss, w_eff, shift, v_eff, v_shift, data: LabeledData, row_sum: bool):
+    """(X^T r, sum r or None) of the rows in `data`, through XLA."""
+    z = _matvec(data.features, w_eff) + shift + data.offsets
+    d2 = loss.d2(z, data.labels)
+    q = _matvec(data.features, v_eff) + v_shift
+    r = data.weights * d2 * q
+    return _rmatvec(data.features, r), jnp.sum(r) if row_sum else None
+
+
+def _summed_over_samples(dispatch: pallas_glm.ShardedDispatch, sums, replicated, data: LabeledData):
+    """`sums(*replicated, data)` on each device's own rows of a sample-sharded
+    ELL shard, and the devices' results added ONCE: every device runs the
+    one-device plane loops (table and accumulator in VMEM) into its own
+    partial, and one `psum` reduces value and (d,) gradient together. Left to
+    the partitioner, the loop's replicated accumulator is reduced after every
+    plane's scatter-add: K reductions of d floats an evaluation."""
+    from jax.sharding import PartitionSpec as P
+
+    from photon_ml_tpu.parallel.mesh import shard_map_compat
+
+    axis = dispatch.axis
+    rows, planes = P(axis), P(axis, None)
+
+    def per_device(replicated, features, labels, offsets, weights):
+        partial = sums(*replicated, LabeledData(features, labels, offsets, weights))
+        with jax.named_scope("allreduce"):
+            return jax.lax.psum(partial, axis)
+
+    return shard_map_compat(
+        per_device,
+        mesh=dispatch.mesh,
+        in_specs=(P(), planes, rows, rows, rows),
+        out_specs=P(),
+    )(replicated, data.features, data.labels, data.offsets, data.weights)
+
+
 def compute_margins(
     w: Array, data: LabeledData, norm: Optional[NormalizationContext] = None
 ) -> Array:
@@ -165,6 +214,14 @@ def value_and_gradient(
             loss, w_eff, shift, data.features, data.labels, data.offsets,
             data.weights, interpret=pallas_glm.FORCE_INTERPRET,
         )
+    elif isinstance(use_pallas, pallas_glm.ShardedDispatch) and isinstance(
+        data.features, SparseFeatures
+    ):
+        val, g, sum_u = _summed_over_samples(
+            use_pallas,
+            lambda w_, s_, d_: _value_gradient_sums(loss, w_, s_, d_, _needs_row_sum(norm)),
+            (w_eff, shift), data,
+        )
     elif isinstance(use_pallas, pallas_glm.ShardedDispatch):
         val, g, sum_u = pallas_glm.sharded_value_gradient_sums(
             loss, w_eff, shift, data.features, data.labels, data.offsets,
@@ -177,15 +234,11 @@ def value_and_gradient(
             data.weights, interpret=pallas_glm.FORCE_INTERPRET,
         )
     else:
-        z = _matvec(data.features, w_eff) + shift + data.offsets
-        val = jnp.sum(data.weights * loss.loss(z, data.labels))
-        u = data.weights * loss.d1(z, data.labels)
-        g = _rmatvec(data.features, u)
-        sum_u = None
+        val, g, sum_u = _value_gradient_sums(
+            loss, w_eff, shift, data, _needs_row_sum(norm)
+        )
     if norm is not None and not norm.is_identity:
         if norm.shifts is not None:
-            if sum_u is None:
-                sum_u = jnp.sum(u)
             g = g - sum_u * norm.shifts
         if norm.factors is not None:
             g = g * norm.factors
@@ -224,7 +277,17 @@ def hessian_vector(
     v_eff, v_shift = _eff(v, norm)
     if use_pallas is None:
         use_pallas = pallas_glm.should_use(data.features, w_eff)
-    if isinstance(use_pallas, pallas_glm.ShardedDispatch):
+    if isinstance(use_pallas, pallas_glm.ShardedDispatch) and isinstance(
+        data.features, SparseFeatures
+    ):
+        hv, sum_r = _summed_over_samples(
+            use_pallas,
+            lambda w_, s_, v_, vs_, d_: _hessian_vector_sums(
+                loss, w_, s_, v_, vs_, d_, _needs_row_sum(norm)
+            ),
+            (w_eff, shift, v_eff, v_shift), data,
+        )
+    elif isinstance(use_pallas, pallas_glm.ShardedDispatch):
         hv, sum_r = pallas_glm.sharded_hessian_vector_sums(
             loss, w_eff, shift, v_eff, v_shift, data.features, data.labels,
             data.offsets, data.weights, mesh=use_pallas.mesh,
@@ -236,16 +299,11 @@ def hessian_vector(
             data.offsets, data.weights, interpret=pallas_glm.FORCE_INTERPRET,
         )
     else:
-        z = _matvec(data.features, w_eff) + shift + data.offsets
-        d2 = loss.d2(z, data.labels)
-        q = _matvec(data.features, v_eff) + v_shift
-        r = data.weights * d2 * q
-        hv = _rmatvec(data.features, r)
-        sum_r = None
+        hv, sum_r = _hessian_vector_sums(
+            loss, w_eff, shift, v_eff, v_shift, data, _needs_row_sum(norm)
+        )
     if norm is not None and not norm.is_identity:
         if norm.shifts is not None:
-            if sum_r is None:
-                sum_r = jnp.sum(r)
             hv = hv - sum_r * norm.shifts
         if norm.factors is not None:
             hv = hv * norm.factors

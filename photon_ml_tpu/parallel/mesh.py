@@ -18,10 +18,15 @@ treeAggregate/broadcast/co-partitioned joins become XLA collectives over a
     sample sharding; entity-block gathers cross shard boundaries and XLA
     lowers them to all-gathers on ICI — replacing the by-uid RDD joins.
 
-Everything goes through jit with sharded inputs (GSPMD propagation); there is
-no hand-written collective in the framework. Multi-host (DCN) uses the same
-code: initialize jax.distributed and build the mesh over all processes'
-devices with the batch axis laid out so sample shards stay within a slice.
+Most of it goes through jit with sharded inputs (GSPMD propagation). The
+collectives the framework writes itself are where propagation would not
+place one well: the fixed effect's objective over sample-sharded rows
+(`shard_map` + one `psum` an evaluation: `ShardedDispatch`, dense in
+ops/pallas_glm.py, sparse ELL in ops/objective.py) and the ring and
+broadcast gathers of a row-sharded coefficient store below. Multi-host (DCN)
+uses the same code: initialize jax.distributed and build the mesh over all
+processes' devices with the batch axis laid out so sample shards stay within
+a slice.
 """
 
 from __future__ import annotations
@@ -78,6 +83,30 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
+def _sample_axis(feats) -> int:
+    """The axis of a shard's arrays that counts samples: leading, but
+    trailing in the transposed (K, N) ELL layout."""
+    return 1 if isinstance(feats, SparseFeatures) and feats.ell_axis == -2 else 0
+
+
+def _map_feature_arrays(feats, fn):
+    """`fn(array, sample_axis)` over a shard's arrays (an ELL shard's two planes)."""
+    axis = _sample_axis(feats)
+    return jax.tree.map(lambda a: fn(a, axis), feats)
+
+
+def _pad_tag(v: np.ndarray, rem: int) -> np.ndarray:
+    if v.dtype.kind == "i":
+        fill = np.full(rem, np.iinfo(v.dtype).min, dtype=v.dtype)
+    elif v.dtype.kind == "u":
+        fill = np.full(rem, np.iinfo(v.dtype).max, dtype=v.dtype)
+    elif v.dtype.kind == "f":
+        fill = np.full(rem, -np.inf, dtype=v.dtype)
+    else:
+        fill = np.full(rem, "\x00__pad__", dtype=v.dtype)
+    return np.concatenate([v, fill])
+
+
 def pad_game_dataset(dataset: GameDataset, multiple: int) -> GameDataset:
     """Pad the sample axis to a multiple with weight-0 rows (inert everywhere).
 
@@ -88,88 +117,113 @@ def pad_game_dataset(dataset: GameDataset, multiple: int) -> GameDataset:
     competes with real entities for reservoir caps. Real data using the
     sentinel value itself is the only (pathological) collision case.
     """
-    n = dataset.num_samples
-    rem = (-n) % multiple
+    return _pad_rows(dataset, (-dataset.num_samples) % multiple)
+
+
+def _pad_rows(dataset: GameDataset, rem: int) -> GameDataset:
+    """`rem` rows of index 0, value 0, label 0 and weight 0 after the last,
+    each array padded where it lies."""
     if rem == 0:
         return dataset
 
-    def pad_feat(f):
-        if isinstance(f, SparseFeatures):
-            # Pad the SAMPLE axis: trailing in the standard layout,
-            # leading-of-last in the transposed (K, N) layout.
-            widths = ((0, 0), (0, rem)) if f.ell_axis == -2 else ((0, rem), (0, 0))
-            return dataclasses.replace(
-                f,
-                indices=jnp.pad(f.indices, widths),
-                values=jnp.pad(f.values, widths),
-            )
-        return jnp.pad(f, ((0, rem), (0, 0)))
+    def pad(a, axis):
+        widths = [(0, 0)] * a.ndim
+        widths[axis] = (0, rem)
+        return jnp.pad(a, widths)
 
-    shards = {k: pad_feat(v) for k, v in dataset.shards.items()}
-    id_tags = {}
-    for k, v in dataset.id_tags.items():
-        if v.dtype.kind == "i":
-            fill = np.full(rem, np.iinfo(v.dtype).min, dtype=v.dtype)
-        elif v.dtype.kind == "u":
-            fill = np.full(rem, np.iinfo(v.dtype).max, dtype=v.dtype)
-        elif v.dtype.kind == "f":
-            fill = np.full(rem, -np.inf, dtype=v.dtype)
-        else:
-            fill = np.full(rem, "\x00__pad__", dtype=v.dtype)
-        id_tags[k] = np.concatenate([v, fill])
     # host_csr / bucketed_cache are deliberately NOT carried over: the
     # stash's row indices would be inconsistent with the padded sample
     # count, and the sharded path declines the bucketed pack anyway
     # (maybe_pack rejects multi-device arrays). Dropping them here is the
     # explicit decision, not an oversight.
     return GameDataset(
-        shards=shards,
-        labels=jnp.pad(dataset.labels, (0, rem)),
-        offsets=jnp.pad(dataset.offsets, (0, rem)),
-        weights=jnp.pad(dataset.weights, (0, rem)),  # zeros: inert
-        id_tags=id_tags,
+        shards={k: _map_feature_arrays(v, pad) for k, v in dataset.shards.items()},
+        labels=pad(dataset.labels, 0),
+        offsets=pad(dataset.offsets, 0),
+        weights=pad(dataset.weights, 0),  # zeros: inert
+        id_tags={k: _pad_tag(v, rem) for k, v in dataset.id_tags.items()},
+        pad_rows=dataset.pad_rows + rem,
     )
 
 
 def shard_game_dataset(dataset: GameDataset, mesh: Mesh) -> GameDataset:
-    """device_put the sample axis over the mesh (padding first if needed).
-    The transfers record under the `upload` stage of the ambient timing
-    scope (the multi-device counterpart of ShardDict's lazy upload)."""
-    from photon_ml_tpu.utils.observability import stage_timer
-
-    with stage_timer("upload"):
-        return _shard_game_dataset(dataset, mesh)
-
-
-def _shard_game_dataset(dataset: GameDataset, mesh: Mesh) -> GameDataset:
+    """A data set that fits one device (or the host), its sample axis cut
+    into one contiguous part a device (padding first if needed) and handed
+    to `sample_sharded_dataset`. The transfers record under the `upload`
+    stage of the ambient timing scope (the multi-device counterpart of
+    ShardDict's lazy upload)."""
     ndev = mesh.devices.size
     dataset = pad_game_dataset(dataset, ndev)
-    s1 = batch_sharding(mesh, 1)
-    s2 = batch_sharding(mesh, 2)
+    per = dataset.num_samples // ndev
 
-    def put_feat(f):
-        if isinstance(f, SparseFeatures):
-            # Shard the SAMPLE axis: leading in the standard layout,
-            # trailing in the transposed (K, N) layout.
-            sh = (
-                NamedSharding(mesh, P(None, mesh.axis_names[0]))
-                if f.ell_axis == -2
-                else s2
-            )
-            return dataclasses.replace(
-                f,
-                indices=jax.device_put(f.indices, sh),
-                values=jax.device_put(f.values, sh),
-            )
-        return jax.device_put(f, s2)
+    def part(i):
+        def cut(a, axis):
+            return jax.lax.slice_in_dim(a, i * per, (i + 1) * per, axis=axis)
 
-    # host_csr / bucketed_cache intentionally dropped — see pad_game_dataset.
+        return GameDataset(
+            shards={k: _map_feature_arrays(v, cut) for k, v in dataset.shards.items()},
+            labels=cut(dataset.labels, 0),
+            offsets=cut(dataset.offsets, 0),
+            weights=cut(dataset.weights, 0),
+            id_tags={k: v[i * per : (i + 1) * per] for k, v in dataset.id_tags.items()},
+        )
+
+    sharded = sample_sharded_dataset([part(i) for i in range(ndev)], mesh)
+    return dataclasses.replace(sharded, pad_rows=dataset.pad_rows)
+
+
+def sample_sharded_dataset(parts: Sequence[GameDataset], mesh: Mesh) -> GameDataset:
+    """The sample-sharded GameDataset whose i-th device holds `parts[i]`:
+    the way in for rows that fit no single device.
+
+    Each part is one device's contiguous run of samples (a `GameDataset` of
+    its own, `GameDataset.build` on arrays that device already holds; arrays
+    from elsewhere are moved there). A part shorter than the longest gets
+    zero-weight pad rows (index 0, value 0) after its last, on its own
+    device, and every global array is then made of the per-device arrays as
+    they lie (`jax.make_array_from_single_device_arrays`): nothing is
+    gathered, and no whole copy exists anywhere. Coefficients stay
+    replicated; `FixedEffectCoordinate` reads the sharding and reduces its
+    objective over the mesh once an evaluation. Records under the `upload`
+    stage of the ambient timing scope."""
+    from photon_ml_tpu.utils.observability import stage_timer
+
+    devices = list(mesh.devices.flat)
+    if len(parts) != len(devices):
+        raise ValueError(f"{len(parts)} parts for a mesh of {len(devices)} devices")
+    per = max(p.num_samples for p in parts)
+    with stage_timer("upload"):
+        placed = []
+        for p, device in zip(parts, devices):
+            p = _pad_rows(p, per - p.num_samples)
+            put = lambda a, _axis=None: jax.device_put(a, device)
+            placed.append(dataclasses.replace(
+                p,
+                shards={k: _map_feature_arrays(v, put) for k, v in p.shards.items()},
+                labels=put(p.labels), offsets=put(p.offsets), weights=put(p.weights),
+            ))
+
+    def whole(arrays, axis=0):
+        shape = list(arrays[0].shape)
+        shape[axis] *= len(arrays)
+        spec = [None] * len(shape)
+        spec[axis] = mesh.axis_names[0]
+        return jax.make_array_from_single_device_arrays(
+            tuple(shape), NamedSharding(mesh, P(*spec)), list(arrays)
+        )
+
+    def whole_shard(name):
+        feats = [p.shards[name] for p in placed]
+        return jax.tree.map(lambda *arrays: whole(arrays, _sample_axis(feats[0])), *feats)
+
+    first = placed[0]
     return GameDataset(
-        shards={k: put_feat(v) for k, v in dataset.shards.items()},
-        labels=jax.device_put(dataset.labels, s1),
-        offsets=jax.device_put(dataset.offsets, s1),
-        weights=jax.device_put(dataset.weights, s1),
-        id_tags=dataset.id_tags,
+        shards={name: whole_shard(name) for name in first.shards},
+        labels=whole([p.labels for p in placed]),
+        offsets=whole([p.offsets for p in placed]),
+        weights=whole([p.weights for p in placed]),
+        id_tags={k: np.concatenate([p.id_tags[k] for p in placed]) for k in first.id_tags},
+        pad_rows=sum(p.pad_rows for p in placed),
     )
 
 

@@ -189,6 +189,12 @@ METRIC_DESCRIPTIONS = {
     "line_search_rejected_trials": "line-search trials that failed the "
     "Armijo test, per coordinate (labeled coordinate=<id>,"
     "kind=fixed|random; L-BFGS, OWL-QN and box solves)",
+    # A sample-sharded fixed effect (parallel/mesh.sample_sharded_dataset)
+    # sums its objective over the mesh once an evaluation: evaluations x
+    # 4 (d + 1) bytes from each device, added once a fit. Absent on one device.
+    "gradient_allreduce_bytes": "bytes each device contributed to the "
+    "all-reduce of a sample-sharded fixed effect's value and gradient "
+    "(labeled coordinate=<id>)",
     # A sparse fixed effect whose bucketed pack was declined keeps the ELL
     # objective through XLA (ops/pallas_sparse.pack_decline_reason).
     "sparse_pack_declined": "bucketed packs of a sparse fixed-effect shard "

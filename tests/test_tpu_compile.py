@@ -47,12 +47,11 @@ SERVE_D, SERVE_USERS, SERVE_MOVIES, SERVE_BATCH = 201, 13_794, 2_703, 64
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A SingleDeviceSharding on one described v5e chip, the persistent
-    compilation cache off for as long as the module's tests run."""
+def topology():
+    """The described v5e:2x2, the persistent compilation cache off for as
+    long as the module's tests run."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(
@@ -63,9 +62,25 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    """A SingleDeviceSharding on one described v5e chip."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topology.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topology):
+    """A 1-D mesh over the four described chips."""
+    from jax.sharding import Mesh
+
+    return Mesh(np.asarray(topology.devices), ("data",))
 
 
 def _on(sharding, tree):
@@ -279,6 +294,71 @@ def test_the_plane_loop_gathers_from_vmem_at_the_criteo_shape(one_chip):
     assert min(tables) == 1 and max(tables) >= 3, sorted(tables)
     for depth, table in tables.items():
         assert table.elements == CRITEO_DIM + 1 and "S(1)" in table.layout, (depth, table)
+
+
+# `lr-criteo-full.fit`'s programs: the same solve over four chips, 11,460,155
+# rows a chip, through `ShardedDispatch`, and the scoring program of the
+# training rows. Every chip runs the one-chip plane loops on its own rows.
+FULL_ROWS_A_CHIP, CHIPS = 11_460_155, 4
+_COLLECTIVE = re.compile(r" = (.+?) (all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)(?:-start)?\(")
+
+
+@pytest.mark.parametrize("program", ["solve", "score"])
+def test_the_sharded_plane_loop_at_the_whole_criteo_shape(four_chips, program):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from photon_ml_tpu.data.containers import LabeledData, SparseFeatures
+    from photon_ml_tpu.ops import objective
+    from photon_ml_tpu.optimize.lbfgs import minimize_lbfgs
+    from photon_ml_tpu.transformers.game_transformer import _fe_margins
+
+    n = FULL_ROWS_A_CHIP * CHIPS
+    planes, rows, whole = (NamedSharding(four_chips, spec) for spec in (P("data", None), P("data"), P()))
+    indices = jax.ShapeDtypeStruct((n, CRITEO_NNZ), jnp.int32, sharding=planes)
+    values = jax.ShapeDtypeStruct((n, CRITEO_NNZ), jnp.float32, sharding=planes)
+    dispatch = pallas_glm.ShardedDispatch(four_chips, "data")
+
+    def solve(indices, values, labels, offsets, weights, w0):
+        data = LabeledData(SparseFeatures(indices, values, CRITEO_DIM), labels, offsets, weights)
+        return minimize_lbfgs(
+            lambda w: objective.value_and_gradient(LOGISTIC, w, data, None, 1.0, use_pallas=dispatch),
+            w0, max_iterations=CRITEO_ITERATIONS, tolerance=1e-9,
+        ).coefficients
+
+    if program == "solve":
+        text = _compiled_text(jax.jit(solve).lower(
+            indices, values, _vec(rows, n), _vec(rows, n), _vec(rows, n), _vec(whole, CRITEO_DIM)
+        ))
+    else:
+        text = _compiled_text(_fe_margins.lower(
+            SparseFeatures(indices, values, CRITEO_DIM), _vec(whole, CRITEO_DIM), None
+        ))
+    instructions = _array_instructions(text)
+    # No array of a chip's rows x 39 elements is made, on any chip.
+    temporaries = [
+        (name, i.opcode) for name, i in instructions.items()
+        if i.elements >= FULL_ROWS_A_CHIP * CRITEO_NNZ
+        and i.opcode not in ("parameter", "bitcast", "get-tuple-element")
+    ]
+    assert not temporaries, temporaries[:5]
+    # Every plane gather is of one chip's rows and reads its table from VMEM.
+    tables = {
+        i.op_name.count("while/body"): instructions[i.operands[0]]
+        for i in instructions.values()
+        if i.opcode == "fusion" and i.elements == FULL_ROWS_A_CHIP and i.op_name.endswith("/gather")
+    }
+    assert tables and min(tables) == 1, sorted(tables)
+    for depth, table in tables.items():
+        assert table.elements == CRITEO_DIM + 1 and "S(1)" in table.layout, (depth, table)
+    collectives = [(m.group(2), m.group(1)) for m in map(_COLLECTIVE.search, text.splitlines()) if m]
+    if program == "solve":
+        # One reduction an evaluation (the first, and the line search's), of
+        # the value and the gradient together; nothing else crosses chips.
+        assert max(tables) >= 3, sorted(tables)
+        assert [kind for kind, _ in collectives] == ["all-reduce"] * 2, collectives
+        assert all(f"f32[{CRITEO_DIM}]" in shapes for _, shapes in collectives), collectives
+    else:
+        assert not collectives, collectives
 
 
 # ------------------------------------- the dense objective's per-row operands
