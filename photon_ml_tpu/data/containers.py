@@ -167,6 +167,12 @@ class LabeledData:
     labels: Array  # (N,)
     offsets: Array  # (N,)
     weights: Array  # (N,)
+    # How a dense `features` lies on its device(s), for the fused kernels
+    # (ops/pallas_glm, "How X lies"): True where whoever holds the concrete
+    # array read it column-major (`pallas_glm.lies_row_major`), and the
+    # kernels then take (d, tile) blocks of X^T and relay nothing. Static,
+    # and a hint only: a wrong value costs a relayout, never a wrong sum.
+    column_major: bool = dataclasses.field(default=False, metadata=dict(static=True))
 
     @property
     def num_rows(self) -> int:

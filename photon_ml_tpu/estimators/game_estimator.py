@@ -718,7 +718,7 @@ class GameEstimator:
         # no delta, so they reset.
         self.timing_registry.clear_notes(
             "pack_path", "re_path", "sparse_layout", "sparse_objective",
-            "pack_declined", "sample_sharding",
+            "pack_declined", "sample_sharding", "dense_storage",
         )
         # Snapshot the pod-scale robustness counters so fit_timing reports
         # THIS fit's events (the process-wide counters are cumulative).
@@ -1241,6 +1241,15 @@ class GameEstimator:
             # {devices, rows_per_device, pad_rows} where a fixed effect built
             # its coordinate on sample-sharded rows in this fit, else "none".
             "sample_sharding": self.timing_registry.get_note("sample_sharding")
+            or "none",
+            # {layout, dtype, bytes} where a dense fixed effect the fused
+            # kernels run built its coordinate in this fit: how the matrix
+            # it keeps lies on the device ("column_major": the kernels read
+            # (d, tile) blocks of X^T; "row_major": (tile, d) blocks of X;
+            # either way nothing relays it), its dtype, and the bytes the
+            # coordinate holds beside the shard. "none" where no such
+            # coordinate was built.
+            "dense_storage": self.timing_registry.get_note("dense_storage")
             or "none",
         }
         bucket_shapes: Dict[str, object] = {}

@@ -209,7 +209,10 @@ class FixedEffectCoordinate:
             # converted array is coordinate-local and used for BOTH train
             # and score so CD residuals stay consistent; the dataset's f32
             # shard is untouched for other consumers. Cached on the dataset
-            # so sweep steps that rebuild coordinates convert once.
+            # so sweep steps that rebuild coordinates convert once. It lies
+            # as the device lays an array of its shape by default — on a
+            # v5e column-major at [400000, 2000], row-major at d = 512 —
+            # and is never relaid: the kernels read it as it lies (below).
             cache = getattr(dataset, "bucketed_cache", {})
             ckey = ("bf16x", config_data_shard)
             feats = cache.get(ckey)
@@ -223,6 +226,27 @@ class FixedEffectCoordinate:
                 feats, jnp.zeros((feats.shape[-1],), jnp.float32)
             )
         )
+        # How the matrix the fused kernels will read lies, read ONCE here
+        # from the concrete array (inside train_fn's trace it is a tracer
+        # with no layout) and handed to them with the data: where it lies
+        # column-major they take (d, tile) blocks of X^T, a bitcast, and no
+        # program relays the matrix (until PR 37 a 1.6 GB `copy` in every
+        # execution of train_fn on `lr-epsilon`; pallas_glm, "How X lies").
+        self._column_major = False
+        if self._use_pallas is not False:
+            feats = jnp.asarray(feats)
+            self._column_major = not pallas_glm.lies_row_major(feats)
+            set_stage_note(
+                "dense_storage",
+                {
+                    "layout": "column_major" if self._column_major else "row_major",
+                    "dtype": jnp.dtype(feats.dtype).name,
+                    # What the coordinate holds beside the dataset's shard.
+                    "bytes": 0
+                    if feats is dataset.shards[config_data_shard]
+                    else int(feats.nbytes),
+                },
+            )
         # Sparse shards repack once into the bucketed layout so the
         # objective's matvec/rmatvec run the Pallas sparse kernels
         # (ops/pallas_sparse.py) instead of XLA gather/scatter — the sparse
@@ -334,6 +358,7 @@ class FixedEffectCoordinate:
         task = self.task
         use_sampling = cfg.down_sampling_rate < 1.0
         use_pallas = self._use_pallas
+        column_major = self._column_major
 
         @jax.jit
         def train_fn(features, labels, offsets, weights, w0, reg_weight, key):
@@ -345,7 +370,7 @@ class FixedEffectCoordinate:
                     cfg.down_sampling_rate,
                     negatives_only=down_sampler_for_task(task),
                 )
-            data = LabeledData(features, labels, offsets, weights)
+            data = LabeledData(features, labels, offsets, weights, column_major=column_major)
             with jax.named_scope("fe_solve"):
                 res = problem.solve(
                     loss,
@@ -368,7 +393,7 @@ class FixedEffectCoordinate:
 
         @jax.jit
         def variance_fn(features, labels, offsets, weights, w, reg_weight):
-            data = LabeledData(features, labels, offsets, weights)
+            data = LabeledData(features, labels, offsets, weights, column_major=column_major)
             return problem.compute_variances(
                 loss, data, _config_with_traced_weight(cfg, reg_weight), w, norm
             )

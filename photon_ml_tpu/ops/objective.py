@@ -226,12 +226,13 @@ def value_and_gradient(
         val, g, sum_u = pallas_glm.sharded_value_gradient_sums(
             loss, w_eff, shift, data.features, data.labels, data.offsets,
             data.weights, mesh=use_pallas.mesh, axis=use_pallas.axis,
-            interpret=pallas_glm.FORCE_INTERPRET,
+            interpret=pallas_glm.FORCE_INTERPRET, column_major=data.column_major,
         )
     elif use_pallas:
         val, g, sum_u = pallas_glm.value_gradient_sums(
             loss, w_eff, shift, data.features, data.labels, data.offsets,
             data.weights, interpret=pallas_glm.FORCE_INTERPRET,
+            column_major=data.column_major,
         )
     else:
         val, g, sum_u = _value_gradient_sums(
@@ -292,11 +293,13 @@ def hessian_vector(
             loss, w_eff, shift, v_eff, v_shift, data.features, data.labels,
             data.offsets, data.weights, mesh=use_pallas.mesh,
             axis=use_pallas.axis, interpret=pallas_glm.FORCE_INTERPRET,
+            column_major=data.column_major,
         )
     elif use_pallas:
         hv, sum_r = pallas_glm.hessian_vector_sums(
             loss, w_eff, shift, v_eff, v_shift, data.features, data.labels,
             data.offsets, data.weights, interpret=pallas_glm.FORCE_INTERPRET,
+            column_major=data.column_major,
         )
     else:
         hv, sum_r = _hessian_vector_sums(

@@ -84,6 +84,31 @@ bf16-STORED X (r05, `prefers_bf16_storage`): the training design matrix is
   the optimizer solves that problem exactly, so fn_evals stay at f32
   behavior (measured 27 -> 31 at 1M x 512, wall 0.124 -> 0.104 s/solve,
   469 -> 641 GB/s f32-normalized effective, coef diff 4e-4 relative).
+
+How X lies, and how the kernels read it (PR 37, `column_major`): as it lies.
+  The TPU compiler's DEFAULT layout for a 2-D array follows its shape: it
+  lays [1048576, 512] or [400000, 2048] row-major and [400000, 2000]
+  COLUMN-major (d is no multiple of 128 and n is, so column-major pads
+  nothing where row-major pads 2,000 lanes to 2,048). Mosaic constrains a
+  kernel's operand to row-major, so a kernel handed the (n, d) matrix of
+  `lr-epsilon` made XLA relay all of it first: a `copy` of 1.6 GB read and
+  1.6 GB written in every execution of `train_fn`, 5.1 ms of a 42.5 ms fit.
+  A column-major (n, d) matrix IS a row-major (d, n) one, byte for byte, so
+  for such a matrix the kernels take X^T (a bitcast) and read (d, tile)
+  blocks of it: the margins become the plain matmul `w . X^T_tile` and the
+  gradient the `q . k^T` form `u . (X^T_tile)^T` — the two contractions of
+  the row-major kernel with their X axes exchanged, the same tile, the same
+  order of summation, the same bits (2.25 ms a call against 2.27, v5e).
+  Which it is comes from the array itself, read once where it is concrete
+  (`lies_row_major`, at the coordinate's construction) and carried with
+  the data (`LabeledData.column_major`); it is never guessed from a shape,
+  and a wrong flag costs a relayout, never a wrong number. The other cure,
+  storing X row-major by an explicit `Format`, does not survive JAX's
+  persistent compilation cache at jax 0.9.0: an executable read back from
+  the cache reports default layouts for its results whatever it was
+  compiled for, so the array that the storing program returns in a second
+  process claims to be column-major, holds row-major bytes, and the next
+  program refuses it (PERF.md section 6, PR 37).
 """
 
 from __future__ import annotations
@@ -259,7 +284,13 @@ def kernels_healthy() -> bool:
             LOGISTIC, w, zero, X.astype(jnp.bfloat16), y, off, wt,
             interpret=FORCE_INTERPRET,
         )
-        jax.block_until_ready((val, g, hv, val_bf, g_bf))
+        # and the read of a matrix that lies column-major, (d, tile) blocks
+        # of X^T (here the probe's X is relaid for it; the sums are the same).
+        val_cm, g_cm, _ = value_gradient_sums(
+            LOGISTIC, w, zero, X, y, off, wt, interpret=FORCE_INTERPRET,
+            column_major=True,
+        )
+        jax.block_until_ready((val, g, hv, val_bf, g_bf, val_cm, g_cm))
     except Exception as exc:  # compile or runtime failure
         raise RuntimeError(
             f"pallas_glm kernels do not compile or run on the "
@@ -293,6 +324,12 @@ def kernels_healthy() -> bool:
         ),
         "gradient_bf16": bool(
             jnp.max(jnp.abs(g_bf - g_ref)) < 5e-2 * g_scale + 1e-2
+        ),
+        "value_column_major": bool(
+            jnp.allclose(val_cm, val_ref, **PALLAS_GATE_TOLERANCES["f32"])
+        ),
+        "gradient_column_major": bool(
+            jnp.max(jnp.abs(g_cm - g_ref)) < 2e-2 * g_scale + 1e-3
         ),
     }
     wrong = sorted(k for k, ok in checks.items() if not ok)
@@ -428,7 +465,10 @@ def prefers_bf16_storage(features, w: Array) -> bool:
     DEFAULT-precision experiment measured at ~1.5x fn_evals). Opt out with
     PHOTON_DENSE_BF16X=0. Callers convert once at coordinate construction
     (game/coordinate.py) and train AND score on the converted array so
-    coordinate-descent residuals stay consistent."""
+    coordinate-descent residuals stay consistent. The converted array lies
+    as the device lays its shape by default (a bare `astype`) and is never
+    relaid: the coordinate reads how it lies (`lies_row_major`) and the
+    kernels read it so (module docstring, "How X lies")."""
     if not get_knob("PHOTON_DENSE_BF16X"):
         return False
     if _PREC_MODE != "hilo":
@@ -437,6 +477,15 @@ def prefers_bf16_storage(features, w: Array) -> bool:
         return False
     mode = dispatch(features, w)
     return mode is True or isinstance(mode, ShardedDispatch)
+
+
+def lies_row_major(features: Array) -> bool:
+    """Does this concrete 2-D array lie row-major on its device(s)? False
+    says: hand the kernels `column_major=True`. Read from the array, never
+    inferred from its shape: the default for a shape is the compiler's to
+    choose (column-major on a v5e where d is no multiple of 128 and n is, at
+    jax 0.9.0; tests/test_tpu_compile.py records it)."""
+    return tuple(features.format.layout.major_to_minor) == (0, 1)
 
 
 def _tile_for(d: int) -> int:
@@ -491,9 +540,12 @@ def _x_parts(x: Array):
 
 def _rows_dot(rows: Array, x_parts, x_axis: int) -> Array:
     """`rows` (k, m) f32 contracted on its lane axis with axis `x_axis` of
-    the X tile -> (k, ·) f32: the margins `w_rows · X^T` (x_axis 1, the
-    q·k^T form) and the gradient `u_rows · X` (x_axis 0, a plain matmul).
-    X is the MXU's stationary operand both times and is never transposed.
+    the X tile -> (k, ·) f32: the margins `w_rows · X^T` (the tile's
+    feature axis) and the gradient `u_rows · X` (its row axis). On a
+    (tile, d) tile of a row-major X those are the q·k^T form (x_axis 1)
+    and a plain matmul (x_axis 0); on a (d, tile) tile of X^T, what a
+    column-major X is, the other way round. X is the MXU's stationary
+    operand both times and is never transposed.
 
     In hilo mode the f32 rows are hi/lo split and stacked along their free
     SUBLANE axis (the MXU pads k to a sublane tile anyway, so the extra rows
@@ -518,9 +570,10 @@ def _rows_dot(rows: Array, x_parts, x_axis: int) -> Array:
     return out[:k] + out[k:]
 
 
-def _masked_tile(n: int, tile: int, x_ref, y_ref, off_ref, wt_ref):
+def _masked_tile(n: int, tile: int, column_major: bool, x_ref, y_ref, off_ref, wt_ref):
     """This grid step's operands with the rows beyond n zeroed: the X tile
-    as MXU operands (`_x_parts`), and labels, offsets and weights as
+    as MXU operands (`_x_parts`) — (tile, d) of a row-major X, (d, tile) of
+    the X^T that a column-major X is —, and labels, offsets and weights as
     (1, tile) rows — rows on the lane axis, four vregs at tile 512 where a
     (tile, 1) column took sixty-five. A zero in `u` does not silence a NaN
     in a padded row of X on the MXU, so X is masked too; the whole-tile
@@ -528,46 +581,52 @@ def _masked_tile(n: int, tile: int, x_ref, y_ref, off_ref, wt_ref):
     step, 2.17 with none, at 400,000 x 2,000 bf16 on a v5e)."""
     rows = _row_mask(n, tile, 1)
     return (
-        _x_parts(jnp.where(_row_mask(n, tile, 0), x_ref[:], 0)),
+        _x_parts(jnp.where(rows if column_major else _row_mask(n, tile, 0), x_ref[:], 0)),
         jnp.where(rows, y_ref[:], 0.0),
         jnp.where(rows, off_ref[:], 0.0),
         jnp.where(rows, wt_ref[:], 0.0),
     )
 
 
-def _value_grad_kernel(loss: PointwiseLoss, n: int, tile: int, x_ref, y_ref,
-                       off_ref, wt_ref, w_ref, stats_ref, grad_ref):
+def _value_grad_kernel(loss: PointwiseLoss, n: int, tile: int, column_major: bool,
+                       x_ref, y_ref, off_ref, wt_ref, w_ref, stats_ref, grad_ref):
     @pl.when(pl.program_id(0) == 0)
     def _():
         stats_ref[0, 0] = 0.0
         stats_ref[0, 1] = 0.0
         grad_ref[:] = jnp.zeros_like(grad_ref)
 
-    x_parts, y, off, wt = _masked_tile(n, tile, x_ref, y_ref, off_ref, wt_ref)
-    z = _rows_dot(w_ref[:], x_parts, 1) + off
+    # The X tile's feature axis and its row axis: (tile, d) or (d, tile).
+    feature_axis, row_axis = (0, 1) if column_major else (1, 0)
+    x_parts, y, off, wt = _masked_tile(n, tile, column_major, x_ref, y_ref, off_ref, wt_ref)
+    z = _rows_dot(w_ref[:], x_parts, feature_axis) + off
     u = wt * loss.d1(z, y)
     stats_ref[0, 0] += jnp.sum(wt * loss.loss(z, y))
     stats_ref[0, 1] += jnp.sum(u)
-    grad_ref[:] += _rows_dot(u, x_parts, 0)
+    grad_ref[:] += _rows_dot(u, x_parts, row_axis)
 
 
-def _hvp_kernel(loss: PointwiseLoss, n: int, tile: int, x_ref, y_ref,
-                off_ref, wt_ref, wv_ref, vshift_ref, stats_ref, hv_ref):
+def _hvp_kernel(loss: PointwiseLoss, n: int, tile: int, column_major: bool,
+                x_ref, y_ref, off_ref, wt_ref, wv_ref, vshift_ref, stats_ref, hv_ref):
     @pl.when(pl.program_id(0) == 0)
     def _():
         stats_ref[0, 0] = 0.0
         hv_ref[:] = jnp.zeros_like(hv_ref)
 
-    x_parts, y, off, wt = _masked_tile(n, tile, x_ref, y_ref, off_ref, wt_ref)
-    zq = _rows_dot(wv_ref[:], x_parts, 1)  # rows: the margins, X @ v
+    feature_axis, row_axis = (0, 1) if column_major else (1, 0)
+    x_parts, y, off, wt = _masked_tile(n, tile, column_major, x_ref, y_ref, off_ref, wt_ref)
+    zq = _rows_dot(wv_ref[:], x_parts, feature_axis)  # rows: the margins, X @ v
     r = wt * loss.d2(zq[0:1] + off, y) * (zq[1:2] + vshift_ref[0, 0])
     stats_ref[0, 0] += jnp.sum(r)
-    hv_ref[:] += _rows_dot(r, x_parts, 0)
+    hv_ref[:] += _rows_dot(r, x_parts, row_axis)
 
 
 def _dense_call(kernel, name, features, row_operands, coef_rows, scalars,
-                n_stats, flops_per_entry, interpret):
-    """One pass of `kernel` over the row tiles of X. Every per-row operand
+                n_stats, flops_per_entry, interpret, column_major):
+    """One pass of `kernel` over the row tiles of X, read as it lies: a
+    (tile, d) block of a row-major X, or (`column_major`) a (d, tile) block
+    of X^T, which for a matrix that lies column-major is a bitcast and no
+    relayout (module docstring, "How X lies"). Every per-row operand
     goes in as a (1, n) row blocked (1, tile) — from an (n,) vector that is
     a bitcast — and the coefficients as (k, d) rows; the results are the
     (1, n_stats) scalar sums in SMEM and one (1, d) row. No (n, 1) or
@@ -579,8 +638,12 @@ def _dense_call(kernel, name, features, row_operands, coef_rows, scalars,
     row = lambda a: a.reshape(1, n).astype(jnp.float32)
     row_spec = pl.BlockSpec((1, tile), lambda i: (0, i), memory_space=_VMEM)
     whole = lambda shape, space: pl.BlockSpec(shape, lambda i: (0, 0), memory_space=space)
+    if column_major:
+        x, x_spec = features.T, pl.BlockSpec((d, tile), lambda i: (0, i), memory_space=_VMEM)
+    else:
+        x, x_spec = features, pl.BlockSpec((tile, d), lambda i: (i, 0), memory_space=_VMEM)
     return pl.pallas_call(
-        functools.partial(kernel, n, tile),
+        functools.partial(kernel, n, tile, column_major),
         # What a device trace calls this operation, said here and not left
         # to what JAX infers from the enclosing function: the benchmark's
         # readers match it (benchmarks/layers/kernels.py), and count an
@@ -588,7 +651,7 @@ def _dense_call(kernel, name, features, row_operands, coef_rows, scalars,
         name=name,
         grid=(pl.cdiv(n, tile),),
         in_specs=[
-            pl.BlockSpec((tile, d), lambda i: (i, 0), memory_space=_VMEM),
+            x_spec,
             *[row_spec] * len(row_operands),
             whole(coef_rows.shape, _VMEM),
             *[whole((1, 1), _SMEM)] * len(scalars),
@@ -606,14 +669,14 @@ def _dense_call(kernel, name, features, row_operands, coef_rows, scalars,
         ),
         interpret=interpret,
     )(
-        features,
+        x,
         *[row(a) for a in row_operands],
         coef_rows.astype(jnp.float32),
         *[jnp.asarray(s, jnp.float32).reshape(1, 1) for s in scalars],
     )
 
 
-@functools.partial(jax.jit, static_argnames=("loss", "interpret"))
+@functools.partial(jax.jit, static_argnames=("loss", "interpret", "column_major"))
 def value_gradient_sums(
     loss: PointwiseLoss,
     w_eff: Array,
@@ -624,6 +687,7 @@ def value_gradient_sums(
     weights: Array,
     *,
     interpret: bool = False,
+    column_major: bool = False,
 ) -> Tuple[Array, Array, Array]:
     """Raw fused sums for the weighted GLM objective.
 
@@ -633,18 +697,21 @@ def value_gradient_sums(
         sum_u    = sum_i u_i
     Normalization corrections (g = factor * (grad_raw - sum_u * shifts)) and
     L2 terms are the caller's job (ops/objective.py), exactly as the raw
-    aggregator sums are post-processed in the reference.
+    aggregator sums are post-processed in the reference. `column_major`
+    says how `features` lies (`lies_row_major`): the same sums, bit for bit,
+    read without a relayout from a matrix that lies so.
     """
     # Fold the scalar margin shift into offsets so the kernel sees one vector.
     stats, grad = _dense_call(
         functools.partial(_value_grad_kernel, loss), "value_gradient_sums",
         features, (labels, offsets + shift, weights), w_eff[None, :], (),
         n_stats=2, flops_per_entry=4, interpret=interpret,
+        column_major=column_major,
     )
     return stats[0, 0], grad[0], stats[0, 1]
 
 
-@functools.partial(jax.jit, static_argnames=("loss", "interpret"))
+@functools.partial(jax.jit, static_argnames=("loss", "interpret", "column_major"))
 def hessian_vector_sums(
     loss: PointwiseLoss,
     w_eff: Array,
@@ -657,6 +724,7 @@ def hessian_vector_sums(
     weights: Array,
     *,
     interpret: bool = False,
+    column_major: bool = False,
 ) -> Tuple[Array, Array]:
     """Raw fused sums for the Gauss-Newton Hessian-vector product.
 
@@ -669,6 +737,7 @@ def hessian_vector_sums(
         features, (labels, offsets + shift, weights),
         jnp.stack([w_eff, v_eff]), (v_shift,),
         n_stats=1, flops_per_entry=6, interpret=interpret,
+        column_major=column_major,
     )
     return hv[0], stats[0, 0]
 
@@ -688,6 +757,7 @@ def sharded_value_gradient_sums(
     mesh: Mesh,
     axis: str,
     interpret: bool = False,
+    column_major: bool = False,
 ) -> Tuple[Array, Array, Array]:
     """Distributed fused objective: per-device fused kernel + psum of the
     raw sums (value, grad_raw, sum_u) over `axis`.
@@ -701,7 +771,8 @@ def sharded_value_gradient_sums(
 
     def per_device(w, s, X, y, off, wt):
         val, g, sum_u = value_gradient_sums(
-            loss, w, s, X, y, off, wt, interpret=interpret
+            loss, w, s, X, y, off, wt, interpret=interpret,
+            column_major=column_major,
         )
         stats = jax.lax.psum(jnp.stack([val, sum_u]), axis)
         return stats[0], jax.lax.psum(g, axis), stats[1]
@@ -731,6 +802,7 @@ def sharded_hessian_vector_sums(
     mesh: Mesh,
     axis: str,
     interpret: bool = False,
+    column_major: bool = False,
 ) -> Tuple[Array, Array]:
     """Distributed fused Hessian-vector product: per-device fused kernel +
     psum of (hv_raw, sum_r) — HessianVectorAggregator.scala:136-142's
@@ -738,7 +810,8 @@ def sharded_hessian_vector_sums(
 
     def per_device(w, s, v, vs, X, y, off, wt):
         hv, sum_r = hessian_vector_sums(
-            loss, w, s, v, vs, X, y, off, wt, interpret=interpret
+            loss, w, s, v, vs, X, y, off, wt, interpret=interpret,
+            column_major=column_major,
         )
         return jax.lax.psum(hv, axis), jax.lax.psum(sum_r, axis)
 

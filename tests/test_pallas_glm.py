@@ -8,6 +8,7 @@ themselves tested against finite differences in test_objective.py.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -92,6 +93,36 @@ def test_hessian_vector_sums_match_xla(rng, loss, n, d, x_dtype):
     Xf = X.astype(jnp.float32)
     r = wt * loss.d2(Xf @ w + off, y) * (Xf @ v)
     np.testing.assert_allclose(float(sum_r), float(jnp.sum(r)), rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("kernel", ["value_gradient", "hessian_vector"])
+@pytest.mark.parametrize("n,d,x_dtype", SHAPES)
+def test_a_matrix_that_lies_column_major_gives_the_same_sums_bit_for_bit(rng, kernel, n, d, x_dtype):
+    """The kernels read X as it lies: told `column_major` they take
+    (d, tile) blocks of X^T — the same tile of rows, the same two
+    contractions with X's axes exchanged — and return the sums of the
+    (tile, d) read bit for bit, ragged last tile and its NaN padding too."""
+    from jax.experimental.layout import Format, Layout
+
+    X, y, off, wt, w = _problem(rng, n, d, x_dtype=x_dtype)
+    columns = jax.device_put(X, Format(Layout((1, 0)), X.sharding))
+    assert pallas_glm.lies_row_major(X) and not pallas_glm.lies_row_major(columns)
+    zero = jnp.zeros(())
+
+    def call(features, column_major):
+        if kernel == "value_gradient":
+            return pallas_glm.value_gradient_sums(
+                LOGISTIC, w, zero, features, y, off, wt, interpret=True, column_major=column_major
+            )
+        return pallas_glm.hessian_vector_sums(
+            LOGISTIC, w, zero, w, zero, features, y, off, wt, interpret=True, column_major=column_major
+        )
+
+    as_rows = call(X, False)
+    assert all(np.isfinite(np.asarray(a)).all() for a in as_rows)
+    for features in (columns, X):  # the flag says how to read, never what
+        for a, b in zip(call(features, True), as_rows):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("kernel", ["value_gradient", "hessian_vector"])

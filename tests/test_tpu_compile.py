@@ -108,16 +108,22 @@ def _compiled_text(lowered) -> str:
 @pytest.mark.parametrize("x_dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize("kernel", ["value_gradient", "hessian_vector"])
 @pytest.mark.parametrize(
-    "rows,dim", [(DENSE_N, DENSE_D), (EPSILON_N, EPSILON_D)], ids=["readme", "epsilon"]
+    "rows,dim,column_major",
+    [(DENSE_N, DENSE_D, False), (EPSILON_N, EPSILON_D, False), (EPSILON_N, EPSILON_D, True)],
+    ids=["readme", "epsilon", "epsilon-as-it-lies"],
 )
-def test_dense_kernels_compile_for_v5e(one_chip, rows, dim, kernel, x_dtype):
+def test_dense_kernels_compile_for_v5e(one_chip, rows, dim, column_major, kernel, x_dtype):
+    """Both reads of X: (tile, d) blocks of a row-major matrix and, for one
+    that lies column-major as `lr-epsilon`'s does, (d, tile) blocks of X^T."""
     X = jax.ShapeDtypeStruct((rows, dim), x_dtype, sharding=one_chip)
     w, n, s = _vec(one_chip, dim), _vec(one_chip, rows), _scalar(one_chip)
     if kernel == "value_gradient":
-        lowered = pallas_glm.value_gradient_sums.lower(LOGISTIC, w, s, X, n, n, n)
+        lowered = pallas_glm.value_gradient_sums.lower(
+            LOGISTIC, w, s, X, n, n, n, column_major=column_major
+        )
     else:
         lowered = pallas_glm.hessian_vector_sums.lower(
-            LOGISTIC, w, s, w, s, X, n, n, n
+            LOGISTIC, w, s, w, s, X, n, n, n, column_major=column_major
         )
     assert "tpu_custom_call" in _compiled_text(lowered)
 
@@ -371,13 +377,35 @@ def test_the_sharded_plane_loop_at_the_whole_criteo_shape(four_chips, program):
 # the padding beside X. Now they go in as `f32[1,400000]` rows.
 
 
+def _default_layout(one_chip, shape, dtype):
+    """How the chip's compiler lays a program's parameter of this shape and
+    dtype when nobody says: `(0, 1)` row-major, `(1, 0)` column-major."""
+    compiled = jax.jit(lambda x: x[0, 0]).lower(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    ).compile()
+    return tuple(compiled.input_formats[0][0].layout.major_to_minor)
+
+
+def _whole_matrix_relayouts(instructions, shape):
+    return [
+        (name, i.opcode, i.layout) for name, i in instructions.items()
+        if i.opcode in ("copy", "transpose") and i.elements >= math.prod(shape)
+    ]
+
+
 def test_the_dense_solve_holds_no_padded_column_at_the_epsilon_shape(one_chip):
     from photon_ml_tpu.data.containers import LabeledData
     from photon_ml_tpu.ops import objective
     from photon_ml_tpu.optimize.lbfgs import minimize_lbfgs
 
+    # X as the coordinate hands it over since PR 37: lying as the compiler
+    # lays the shape by default, column-major here, and said to lie so.
+    shape = (EPSILON_N, EPSILON_D)
+    column_major = _default_layout(one_chip, shape, jnp.bfloat16) == (1, 0)
+    assert column_major
+
     def solve(features, labels, offsets, weights, w0):
-        data = LabeledData(features, labels, offsets, weights)
+        data = LabeledData(features, labels, offsets, weights, column_major=column_major)
         return minimize_lbfgs(
             lambda w: objective.value_and_gradient(LOGISTIC, w, data, None, 1.0, use_pallas=True),
             w0, max_iterations=EPSILON_ITERATIONS, tolerance=1e-7,
@@ -385,7 +413,7 @@ def test_the_dense_solve_holds_no_padded_column_at_the_epsilon_shape(one_chip):
 
     rows = _vec(one_chip, EPSILON_N)
     instructions = _array_instructions(_compiled_text(jax.jit(solve).lower(
-        jax.ShapeDtypeStruct((EPSILON_N, EPSILON_D), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip),
         rows, rows, rows, _vec(one_chip, EPSILON_D),
     )))
     # The kernel is there, before the loops and in the line search.
@@ -394,6 +422,17 @@ def test_the_dense_solve_holds_no_padded_column_at_the_epsilon_shape(one_chip):
         if i.opcode == "get-tuple-element" and i.op_name.endswith("value_gradient_sums/pallas_call")
     }
     assert calls == {0, 2}, sorted(calls)
+    # The program takes X as it lies, column-major, and no instruction
+    # relays it: the kernels read (d, tile) blocks of X^T, a bitcast. Read
+    # as a row-major matrix the same text began with
+    # `copy(bf16[400000,2000]{0,1:...} %features)`, 1.6 GB read and 1.6 GB
+    # written in every execution (5.1 ms of a 42.5 ms fit).
+    (x,) = [
+        i for i in instructions.values()
+        if i.opcode == "parameter" and i.elements == EPSILON_N * EPSILON_D
+    ]
+    assert x.layout.startswith("{0,1:"), x.layout
+    assert not _whole_matrix_relayouts(instructions, shape)
     # No array of the rows' size puts fewer than 128 numbers on the lanes,
     # in the loops or before them.
     padded = [
@@ -407,6 +446,38 @@ def test_the_dense_solve_holds_no_padded_column_at_the_epsilon_shape(one_chip):
     # loops and in them, and it moves the 1.6 MB of numbers, not 205 MB.
     reshapes = [i for i in instructions.values() if i.opcode == "reshape" and i.elements >= EPSILON_N]
     assert len(reshapes) <= 6 and all(i.elements == i.minor == EPSILON_N for i in reshapes), reshapes
+
+
+# The observation `column_major` rests on (PR 37): the compiler's DEFAULT
+# layout for a 2-D array follows its shape. Where the feature width is no
+# multiple of 128 and the row count is, column-major pads nothing and
+# row-major pads d up to the next 128, and the compiler takes column-major;
+# Mosaic constrains a kernel's operand to row-major. So the kernels read a
+# matrix as it lies, (d, tile) blocks of X^T where it lies column-major, and
+# nothing relays it; told the wrong way, XLA relays the whole matrix before
+# the call. A compiler that changes its default fails here, not in a
+# benchmark (the program reads the layout from the array, not from a rule).
+LAID_SHAPES = [(EPSILON_N, EPSILON_D), (100_000, EPSILON_D), (DENSE_N, DENSE_D), (EPSILON_N, 2_048)]
+
+
+@pytest.mark.parametrize("x_dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", LAID_SHAPES, ids=lambda s: f"{s[0]}x{s[1]}")
+def test_a_kernel_that_reads_x_as_it_lies_relays_nothing(one_chip, shape, x_dtype):
+    n, d = shape
+    column_major = _default_layout(one_chip, shape, x_dtype) == (1, 0)
+    assert column_major == (d % 128 != 0)
+    X = jax.ShapeDtypeStruct(shape, x_dtype, sharding=one_chip)
+    w, rows, s = _vec(one_chip, d), _vec(one_chip, n), _scalar(one_chip)
+
+    def relayouts(told):
+        text = _compiled_text(pallas_glm.value_gradient_sums.lower(
+            LOGISTIC, w, s, X, rows, rows, rows, column_major=told
+        ))
+        assert "tpu_custom_call" in text
+        return _whole_matrix_relayouts(_array_instructions(text), shape)
+
+    assert not relayouts(column_major)
+    assert relayouts(not column_major)
 
 
 # ----------------------------------------------------------------- serving
