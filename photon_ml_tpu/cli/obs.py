@@ -11,7 +11,8 @@ Three subcommands over the three file artifacts of utils/telemetry.py:
     schema and exits nonzero on any invalid line.
   * `profile <profile.json>` — pretty-print a run profile read through
     the loud `read_profile` contract (stage table, dispatch decisions,
-    topology, roofline).
+    topology, roofline, and the programs the process made ready: by
+    stage, hits, seconds by phase, every compiled one by name).
   * `decisions <journal.jsonl>` — the control-plane timeline (ISSUE 19):
     every `plan_decision`, `autopilot_decision`, and `shadow_verdict`
     event in emit order, with the evidence each decision carried and
@@ -21,7 +22,8 @@ Three subcommands over the three file artifacts of utils/telemetry.py:
   * `profile diff <a> <b>` — typed key-wise comparison of two run
     profiles: per-stage wall deltas, dispatch-decision changes,
     plan-block decision changes (added/removed/value- or source-
-    changed), and topology changes. The operator tool for "what did the
+    changed), topology changes, and the programs each run made ready
+    by stage. The operator tool for "what did the
     planner change between rounds". Exits nonzero when either profile
     violates its contract (read_profile refusal) or the kinds differ.
 
@@ -277,7 +279,31 @@ def cmd_profile(args) -> int:
     counters = (profile.get("metrics") or {}).get("counters") or {}
     nonzero = {k: v for k, v in counters.items() if v}
     print(f"  nonzero counters: {json.dumps(nonzero) if nonzero else '(none)'}")
+    _print_programs(profile.get("programs") or {})
     return 0
+
+
+def _stage_seconds(stage: dict) -> float:
+    return sum(float(v) for v in stage["seconds"].values())
+
+
+def _print_programs(block: dict) -> None:
+    """The `programs` block (utils/compile_cache.summary): what the
+    process made ready, by the stage that asked, and every miss by name."""
+    stages = block.get("stages") or {}
+    if not stages:
+        return
+    phases = list(next(iter(stages.values()))["seconds"])
+    width = max(len(k) for k in stages)
+    print("  programs made ready, by stage (seconds by phase):")
+    print(f"    {'stage'.ljust(width)}  programs  hits  " + "  ".join(p.rjust(10) for p in phases))
+    for name in sorted(stages, key=lambda k: -_stage_seconds(stages[k])):
+        st = stages[name]
+        cells = "  ".join(f"{float(st['seconds'][p]):10.3f}" for p in phases)
+        print(f"    {name.ljust(width)}  {st['programs']:8d}  {st['hits']:4d}  {cells}")
+    for miss in block.get("misses") or []:
+        cells = ", ".join(f"{p} {float(miss[p]):.3f}s" for p in phases if miss.get(p))
+        print(f"    compiled: {miss['program']} under {miss['stage']} ({cells})")
 
 
 def _plan_decisions(profile: dict) -> dict:
@@ -377,6 +403,23 @@ def cmd_profile_diff(path_a: str, path_b: str) -> int:
                 f"    ~ {k}: {json.dumps(va, default=str)} [{sa}] -> "
                 f"{json.dumps(vb, default=str)} [{sb}]"
             )
+
+    # -- programs made ready (what each run asked the backend for)
+    pr_a = (a.get("programs") or {}).get("stages") or {}
+    pr_b = (b.get("programs") or {}).get("stages") or {}
+    if pr_a or pr_b:
+        print("  programs made ready (a -> b: programs, hits, seconds):")
+        absent = {"programs": 0, "hits": 0, "seconds": {}}
+        for k in sorted({*pr_a, *pr_b}):
+            sa, sb = pr_a.get(k, absent), pr_b.get(k, absent)
+            print(
+                f"    {k}: {sa['programs']} -> {sb['programs']} programs, "
+                f"{sa['hits']} -> {sb['hits']} hits, "
+                f"{_stage_seconds(sa):.3f}s -> {_stage_seconds(sb):.3f}s"
+            )
+        for side, block in (("a", a), ("b", b)):
+            for miss in (block.get("programs") or {}).get("misses") or []:
+                print(f"    compiled in {side}: {miss['program']} under {miss['stage']}")
     return 0
 
 

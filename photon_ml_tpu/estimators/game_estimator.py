@@ -64,7 +64,7 @@ from photon_ml_tpu.transformers.game_transformer import (
     prepare_coordinate_data,
 )
 from photon_ml_tpu.types import NormalizationType, TaskType
-from photon_ml_tpu.utils import telemetry
+from photon_ml_tpu.utils import compile_cache, telemetry
 from photon_ml_tpu.utils.observability import (
     CheckpointEvent,
     CoordinateUpdateEvent,
@@ -73,6 +73,7 @@ from photon_ml_tpu.utils.observability import (
     TimingRegistry,
     TrainingFinishEvent,
     TrainingStartEvent,
+    open_stage,
     stage_scope,
     stage_timer,
 )
@@ -189,12 +190,16 @@ class GameEstimator:
         already recorded to the nested `pack`/`upload` stages (a projector
         block that faults a synchronous ShardDict upload must not count
         the same seconds twice — the sync-run breakdown tiles prepare_s).
-        Must run inside an open stage_scope on this registry."""
+        Must run inside an open stage_scope on this registry. The programs
+        made ready inside are filed under `name` (`compile` holds a
+        coordinate's construction: `compile_cache.programs()` shows what
+        that makes ready, and it compiles none of the solve)."""
         reg = self.timing_registry
         t0 = time.perf_counter()
         nested0 = reg.get("pack") + reg.get("upload")
         try:
-            yield
+            with open_stage(name):
+                yield
         finally:
             elapsed = time.perf_counter() - t0
             nested = reg.get("pack") + reg.get("upload") - nested0
@@ -1276,6 +1281,10 @@ class GameEstimator:
         # deliberately NOT a PROFILE_*_KEYS contract key: r06-era
         # profiles (pre-planner) must keep loading for the cold start.
         profile["plan"] = dict(ft["plan"])
+        # Every program this PROCESS has made ready so far, by stage, and
+        # every miss by name (utils/compile_cache.py); empty where nothing
+        # called `compile_cache.listen()`. Not a contract key either.
+        profile["programs"] = compile_cache.summary()
         return profile
 
 

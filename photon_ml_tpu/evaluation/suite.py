@@ -217,10 +217,9 @@ def evaluate_metrics(
     labels, weights and each id tag's gather are arguments, so the program
     is found again by shapes and shardings whichever suite calls — a suite
     built anew in every fit hits JAX's in-process cache from the second fit
-    on. This body runs only when JAX traces it, which is what
-    `evaluation_traces` counts.
+    on (a retrace would show as one more `jit(evaluate_metrics)` in
+    `compile_cache.programs()`).
     """
-    telemetry.METRICS.increment("evaluation_traces")
     return jnp.stack(
         [
             jnp.asarray(

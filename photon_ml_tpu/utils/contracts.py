@@ -437,6 +437,8 @@ JOURNAL_EVENT_SCHEMAS = {
     "checkpoint": ("step", "coordinate"),
     "fit_finish": ("num_configs", "best_metric"),
     "failure": ("error",),
+    # -- a program JAX compiled, none read back (utils/compile_cache.py) --
+    "program_compiled": ("program", "stage", "seconds"),
     # -- infra sites (emitted through the ambient journal) --
     "health_transition": ("from_state", "to_state", "reasons"),
     "bundle_swap": ("version", "outcome"),

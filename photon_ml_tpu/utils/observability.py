@@ -189,6 +189,35 @@ def record_stage(name: str, seconds: float) -> None:
         registry.record(name, seconds)
 
 
+def open_stages() -> List[str]:
+    """The names of this thread's open `stage_timer`s, outermost first (the
+    live list: read it, do not keep it)."""
+    names = getattr(_STAGE_TLS, "open", None)
+    if names is None:
+        names = _STAGE_TLS.open = []
+    return names
+
+
+def current_stage() -> str:
+    """This thread's innermost open stage, or "none" outside every stage:
+    what `utils/compile_cache` files a program under."""
+    names = getattr(_STAGE_TLS, "open", None)
+    return names[-1] if names else "none"
+
+
+@contextmanager
+def open_stage(name: str):
+    """Mark `name` as this thread's innermost open stage and nothing else:
+    for a block that keeps its own clock (the estimator's exclusive
+    stages) and still wants its programs filed under its name."""
+    names = open_stages()
+    names.append(name)
+    try:
+        yield
+    finally:
+        names.pop()
+
+
 def set_stage_note(name: str, value: str) -> None:
     """Attach a non-time annotation (e.g. `pack_path`) to this thread's
     innermost stage scope (no-op without one)."""
@@ -205,13 +234,14 @@ class stage_timer:
     second instrumentation pass), and a `photon/<name>` annotation lands
     on the profiler's clock, beside the device operations of the same
     `.xplane.pb`. Each is a free no-op when its sink is absent: no scope
-    open, no `Tracer` installed, no profiler session.
+    open, no `Tracer` installed, no profiler session. While the block runs
+    its name is the thread's `current_stage()`.
 
     Keyword arguments are the span's; `set(**args)` adds to them
     mid-flight. After the block `seconds` holds its wall, so a caller
     that reports the wall itself reads the one the three sinks got."""
 
-    __slots__ = ("name", "seconds", "_span", "_annotation", "_t0")
+    __slots__ = ("name", "seconds", "_span", "_annotation", "_t0", "_open")
 
     def __init__(self, name: str, **args):
         self.name = name
@@ -232,11 +262,14 @@ class stage_timer:
         self._span.__enter__()
         if self._annotation is not None:
             self._annotation.__enter__()
+        self._open = open_stages()
+        self._open.append(self.name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.seconds = time.perf_counter() - self._t0
+        self._open.pop()
         if self._annotation is not None:
             self._annotation.__exit__(exc_type, exc, tb)
         self._span.__exit__(exc_type, exc, tb)

@@ -172,11 +172,13 @@ METRIC_DESCRIPTIONS = {
     "tier_rollbacks": "ladder transitions abandoned after retry "
     "exhaustion, the old generation still serving",
     # Persistent compilation cache traffic (utils/compile_cache.py):
-    # programs compiled by this process = requests - hits.
+    # programs compiled by this process = requests - hits. Both are
+    # incremented under the label stage=<name>, the calling thread's
+    # innermost open stage_timer or "none"; the totals are the sums.
     "compile_cache_requests": "compile requests that consulted JAX's "
-    "persistent compilation cache",
+    "persistent compilation cache (labeled stage=<name>)",
     "compile_cache_hits": "compile requests answered from the persistent "
-    "compilation cache instead of compiling",
+    "compilation cache instead of compiling (labeled stage=<name>)",
     # Objective evaluations as the optimizers count them (OptResult.fn_evals:
     # value+gradient evaluations — L-BFGS's first and one per line-search
     # trial — and TRON's Hessian-vector products), added once a fit, labeled
@@ -201,13 +203,11 @@ METRIC_DESCRIPTIONS = {
     "declined, per reason (labeled reason=too_small|dtype|sharded|"
     "pad_blowup)",
     # An evaluation is one compiled program and one fetch
-    # (evaluation/suite.evaluate_metrics): hit share = 1 - traces / calls.
+    # (evaluation/suite.evaluate_metrics); whether JAX made that program
+    # ready anew is in compile_cache.programs(), as for every program.
     "evaluation_calls": "evaluations made (EvaluationSuite.evaluate, "
     "StreamingWindowEvaluator.evaluate_window): one device program and "
     "one fetch each",
-    "evaluation_traces": "times JAX traced the evaluation program anew "
-    "(a new evaluator tuple, row count, dtype or sharding); 0 in a "
-    "steady refit loop",
     # -- histograms (fixed log-spaced buckets, mergeable) --
     "serving_latency_ms": "per-request wall latency through the batcher",
     "serving_queue_wait_ms": "submit-to-claim queue wait per request",
@@ -216,6 +216,10 @@ METRIC_DESCRIPTIONS = {
     "fit_stage_s": "wall seconds per fit of each contracts.SOLVE_STAGES "
     "stage (labeled stage=<name>); the unlabeled aggregate mixes stages "
     "and means nothing",
+    "program_ready_s": "self seconds of every trace, lowering and backend "
+    "step JAX reported while making a program ready (labeled "
+    "phase=trace|lower|cache_read|compile,stage=<name>; "
+    "utils/compile_cache.py); the unlabeled aggregate mixes phases",
     "shadow_score_drift": "per-request |champion - challenger| mean-score "
     "drift observed at window evaluation",
     "shadow_calibration_champion": "per-request |champion mean - label| "

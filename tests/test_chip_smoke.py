@@ -78,8 +78,7 @@ def test_compile_cache_directory(monkeypatch, tmp_path, placed_from_outside):
 
     updates = {}
     monkeypatch.setattr(jax.config, "update", lambda k, v: updates.__setitem__(k, v))
-    monkeypatch.setattr(jax.monitoring, "register_event_listener", lambda cb: None)
-    monkeypatch.setattr(compile_cache, "_listening", False)
+    monkeypatch.setattr(compile_cache, "listen", lambda: None)
     if placed_from_outside:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     else:

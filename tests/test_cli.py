@@ -582,8 +582,13 @@ class TestTuneDriver:
         meta = json.load(open(os.path.join(best, "model-metadata.json")))
         tuned_rw = meta["optimizationConfigurations"]["global"]["reg_weight"]
         assert tuned_rw == summary["best_point"][0]
-        # Journal: every line valid, one start + one finish per trial.
+        # Journal: every line valid, one start + one finish per trial (and a
+        # `program_compiled` line for every program the job compiled).
         n_ok, errors = telemetry.validate_journal(
             os.path.join(out, "journal.jsonl")
         )
-        assert errors == [] and n_ok == 8
+        with open(os.path.join(out, "journal.jsonl")) as f:
+            types = [json.loads(line)["type"] for line in f]
+        trials = [t for t in types if t.startswith("trial_")]
+        assert errors == [] and n_ok == len(types) and len(trials) == 8
+        assert set(types) <= {"trial_start", "trial_finish", "program_compiled"}

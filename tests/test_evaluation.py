@@ -23,7 +23,7 @@ from photon_ml_tpu.optimize.config import (
     OptimizerConfig,
 )
 from photon_ml_tpu.types import TaskType
-from photon_ml_tpu.utils import telemetry
+from photon_ml_tpu.utils import compile_cache, telemetry
 
 
 def test_auc_matches_sklearn(rng):
@@ -292,8 +292,11 @@ def test_suite_evaluate_equals_bare_metric(spec):
 
 
 def _evaluation_counts():
-    c = telemetry.METRICS.counters()
-    return c.get("evaluation_calls", 0), c.get("evaluation_traces", 0)
+    """Evaluations made, and the times JAX made the evaluation program ready
+    anew: its entries in the record of every program of the process."""
+    compile_cache.listen()
+    made = [r for r in compile_cache.programs() if r["program"] == "jit(evaluate_metrics)"]
+    return telemetry.METRICS.get_counter("evaluation_calls"), len(made)
 
 
 def test_evaluation_program_is_keyed_on_shapes_not_on_the_suite(rng):

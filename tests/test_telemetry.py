@@ -431,6 +431,8 @@ class TestJournal:
         "num_configs": 2,
         "best_metric": 0.91,
         "error": "RuntimeError('x')",
+        "program": "jit(train_fn)",
+        "stage": "cd/train",
         "from_state": "READY",
         "to_state": "DEGRADED",
         "reasons": ["circuit_open"],
