@@ -18,6 +18,13 @@ of N * K elements is made. The padding invariant is what the products rest
 on: they promise XLA that every index is in [0, dim), and a padding slot
 contributes `w[0] * 0.0` to a margin and `0.0` to feature 0's gradient. For
 dense shards the design matrix feeds the MXU directly.
+
+A plane whose ids are all neighbours (one small field of a field-major design
+matrix: a weekday, a device type, the intercept) need not be gathered at all:
+`annotate_spans` reads each plane's least and greatest id from the concrete
+arrays, and `matvec` multiplies such a plane against that span of the
+coefficients by compare, select and reduce on the vector unit ("dense span",
+below); the wide planes keep the gather, and every plane the scatter-add.
 """
 
 from __future__ import annotations
@@ -31,8 +38,20 @@ import numpy as np
 
 Array = jax.Array
 
+# The widest span class whose plane's margins are a dense span; a plane whose
+# non-padding ids span more is gathered. Measured, not tuned
+# (`examples/probe_dense_span.py` on a v5e; PERF.md section 3, the probe's
+# table, PR 39): the largest class at which the dense-span product takes less
+# than half of the gather's time at both sparse cells' row counts. A plane of
+# 8,000,000 entries into 1,000,000 features: 20.2 ms at 2,048 against 54.2,
+# 72.8 ms at 4,096; of 11,460,155 entries: 24.5 ms against 77.7, 43.9 ms.
+DENSE_SPAN_LIMIT = 2048
+# The narrowest class: one full row of lanes. Classes are powers of two from
+# here to the limit, so that a field whose rarest ids a seed may or may not
+# draw still compiles one program.
+DENSE_SPAN_FLOOR = 128
 
-@jax.tree_util.register_dataclass
+
 @dataclasses.dataclass(frozen=True)
 class SparseFeatures:
     """Padded ELL sparse matrix: row r has features indices[r, k] -> values[r, k].
@@ -64,6 +83,18 @@ class SparseFeatures:
     values: Array  # float, same shape as indices
     dim: int = dataclasses.field(metadata=dict(static=True))
     ell_axis: int = dataclasses.field(default=-1, metadata=dict(static=True))
+    # The dense-span annotation, made by `annotate_spans` from the concrete
+    # arrays and by nothing else: no argument of the constructor, so whatever
+    # builds a shard from other arrays (`dataclasses.replace(feats,
+    # indices=...)` too) builds it unannotated, and an annotation cannot
+    # outlive the indices it was read from. `span_classes` is static: a
+    # plane's class is 0 (wide: its margins are gathered) or the power of two,
+    # DENSE_SPAN_FLOOR to DENSE_SPAN_LIMIT, that holds its non-padding ids'
+    # span. `span_lo` is data: the (K,) int32 least ids, so two seeds of one
+    # field layout run one program. Unannotated, `matvec` traces what it
+    # traced before there was an annotation.
+    span_lo: Optional[Array] = dataclasses.field(default=None, init=False)
+    span_classes: Tuple[int, ...] = dataclasses.field(default=(), init=False)
 
     @property
     def shape(self) -> Tuple[int, ...]:
@@ -92,22 +123,58 @@ class SparseFeatures:
         where a `w` the caller keeps (an L-BFGS start, alive through the
         solve) stayed in HBM, at 15 ns a gathered entry against 6.6. The
         indices are promised in bounds, which the padding invariant gives: a
-        padding slot gathers `w[0]` and multiplies it by 0.0."""
+        padding slot gathers `w[0]` and multiplies it by 0.0.
+
+        An annotated plane of class S is no gather: its margins are
+        `sum_s where(i - lo == s, w[lo + s], 0) * v` over the S lanes of its
+        span, one reduce fusion with the rows on the lanes, exact in float32
+        (one term is non-zero) and so bit-equal to the gathered product. An id
+        outside [lo, lo + S), a padding slot's among them, matches no lane.
+        The narrow planes go first, a rolled loop a class; then the scan over
+        the wide planes, each taken out of the stored arrays by index."""
         w = jnp.asarray(w)
-        table = jnp.pad(w, [(0, 0)] * (w.ndim - 1) + [(0, 1)])
+        # (class, its planes) in ascending class, planes in stored order.
+        narrow = [
+            (c, [k for k, ck in enumerate(self.span_classes) if ck == c])
+            for c in sorted(set(self.span_classes) - {0})
+        ]
+        if not narrow or w.ndim != 1:
+            table = jnp.pad(w, [(0, 0)] * (w.ndim - 1) + [(0, 1)])
+            idx, val = self._planes()
+
+            def plane(z, iv):
+                i, v = iv
+                return z + table.at[..., i].get(mode="promise_in_bounds") * v, None
+
+            z0 = jnp.zeros(w.shape[:-1] + idx.shape[1:], jnp.result_type(w, val))
+            return jax.lax.scan(plane, z0, (idx, val))[0]
+        # Padded by the widest class: a span at the end of `w` is sliced whole,
+        # not clamped down onto its neighbours.
+        table = jnp.pad(w, (0, narrow[-1][0]))
         idx, val = self._planes()
+        z = jnp.zeros(idx.shape[1:], jnp.result_type(w, val))
+        for span, planes in narrow:
+            lanes = jnp.arange(span, dtype=jnp.int32)[:, None]
 
-        def plane(z, iv):
-            i, v = iv
-            return z + table.at[..., i].get(mode="promise_in_bounds") * v, None
+            def dense_span(z, k):
+                i, v, lo = _plane(idx, k), _plane(val, k), self.span_lo[k]
+                w_span = jax.lax.dynamic_slice(table, (lo,), (span,))
+                hit = (i - lo)[None, :] == lanes
+                return z + jnp.sum(jnp.where(hit, w_span[:, None], 0), axis=0) * v, None
 
-        z0 = jnp.zeros(w.shape[:-1] + idx.shape[1:], jnp.result_type(w, val))
-        return jax.lax.scan(plane, z0, (idx, val))[0]
+            z = jax.lax.scan(dense_span, z, jnp.asarray(planes, jnp.int32))[0]
+
+        def gathered(z, k):
+            return z + table.at[_plane(idx, k)].get(mode="promise_in_bounds") * _plane(val, k), None
+
+        wide = [k for k, c in enumerate(self.span_classes) if not c]
+        return jax.lax.scan(gathered, z, jnp.asarray(wide, jnp.int32))[0] if wide else z
 
     def _scatter_planes(self, u: Array, square: bool) -> Array:
         """sum_k scatter-add of `values[k] * u` (`values[k]**2 * u` if
         `square`) at `indices[k]` into one (dim,) accumulator, which stays
-        in VMEM across the loop."""
+        in VMEM across the loop. Every plane, annotated or not: see
+        `rmatvec` for why the transpose has no dense-span form."""
         if self.indices.ndim != 2:
             raise ValueError("rmatvec is per-problem; vmap over leading axes")
 
@@ -127,13 +194,12 @@ class SparseFeatures:
         per-lane); an unbatched call on (..., N, K) data would silently sum
         across batch members, so it is rejected.
 
-        Scatter-add is the measured-best TPU primitive for this (v5e,
-        1M x 64 nnz into dim 16384: scatter 565 ms vs sorted segment-sum
-        1581 ms vs static-permutation cumsum-diff 1013 ms) — sort-based
-        reformulations pay more for the 67M-element random gather than the
-        scatter costs. The op remains far from HBM roofline; a Pallas
-        VMEM-accumulator kernel is the remaining headroom if Mosaic grows a
-        fast vector scatter.
+        What the scatter-add costs (v5e, one plane of 8,000,000 entries into
+        1,000,000 features; PERF.md section 3, PR 39): 54-71 ms a plane,
+        6.8-8.9 ns an entry, the more the more neighbouring ids repeat. It
+        adds an id's entries one after another in float32 (what that rounds
+        to on a hot feature, and why the transpose has no dense-span form
+        yet: PERF.md section 7).
         """
         return self._scatter_planes(u, square=False)
 
@@ -148,6 +214,101 @@ class SparseFeatures:
         if self.ell_axis == -2:
             return jnp.einsum("...kn,...knd->...nd", self.values, onehot)
         return jnp.einsum("...nk,...nkd->...nd", self.values, onehot)
+
+
+def _with_spans(features: SparseFeatures, span_lo, span_classes) -> SparseFeatures:
+    """A copy of `features` (the same arrays) that carries the annotation: for
+    `annotate_spans`, which read it from those arrays, and for the pytree's
+    way back from its leaves."""
+    out = dataclasses.replace(features)
+    object.__setattr__(out, "span_lo", span_lo)
+    object.__setattr__(out, "span_classes", tuple(span_classes))
+    return out
+
+
+# What `register_dataclass` would make of it, had the annotation been an
+# argument of the constructor: the arrays and the least ids are the children,
+# under their attribute names; the rest is static.
+jax.tree_util.register_pytree_with_keys(
+    SparseFeatures,
+    lambda f: (
+        tuple((jax.tree_util.GetAttrKey(n), getattr(f, n)) for n in ("indices", "values", "span_lo")),
+        (f.dim, f.ell_axis, f.span_classes),
+    ),
+    lambda static, arrays: _with_spans(
+        SparseFeatures(arrays[0], arrays[1], static[0], static[1]), arrays[2], static[2]
+    ),
+    flatten_func=lambda f: ((f.indices, f.values, f.span_lo), (f.dim, f.ell_axis, f.span_classes)),
+)
+
+
+def _plane(planes: Array, k) -> Array:
+    """Plane k of a (K, N) view of the stored arrays: the dynamic slice a scan
+    over the planes makes, by an index the caller chose."""
+    return jax.lax.dynamic_index_in_dim(planes, k, 0, keepdims=False)
+
+
+@jax.jit
+def _plane_spans(indices: Array, values: Array) -> Array:
+    """(2, K): each plane's least and greatest id over its non-padding
+    entries (value != 0; a padding slot is index 0 and would stretch every
+    span down to id 0). A plane with no entry reads (int32 max, -1)."""
+    live = values != 0
+    lo = jnp.min(jnp.where(live, indices, jnp.iinfo(jnp.int32).max), axis=0)
+    hi = jnp.max(jnp.where(live, indices, -1), axis=0)
+    return jnp.stack([lo, hi])
+
+
+def span_class(lo: int, hi: int) -> int:
+    """The class of a plane whose non-padding ids are [lo, hi]: the power of
+    two from DENSE_SPAN_FLOOR up that holds the span, or 0 (wide) where that
+    passes DENSE_SPAN_LIMIT. A plane with no entry is the narrowest class."""
+    span = max(hi - lo + 1, 1)
+    if span > DENSE_SPAN_LIMIT:
+        return 0
+    return max(DENSE_SPAN_FLOOR, 1 << (span - 1).bit_length())
+
+
+def annotate_spans(features: SparseFeatures) -> SparseFeatures:
+    """`features` with its planes' span classes and least ids, READ from the
+    concrete arrays: one jitted reduction over them (on a sample-sharded shard
+    over the global array, so every device compiles the one program) and one
+    fetch of 2 K integers. The only maker of the annotation; callers keep the
+    result for as long as they keep the shard (`GameDataset.annotated_shard`).
+    A shard with no narrow plane, a batched or (K, N) one, or host arrays come
+    back as they are: unannotated, the products are what they were."""
+    if (
+        features.ell_axis != -1
+        or not isinstance(features.indices, jax.Array)
+        or features.indices.ndim != 2
+    ):
+        return features
+    lo, hi = np.asarray(_plane_spans(features.indices, features.values)).astype(np.int64)
+    classes = tuple(span_class(int(l), int(h)) for l, h in zip(lo, hi))
+    if not any(classes):
+        return features
+    # An empty plane's least id is 0: whatever lane its padding slots match
+    # is multiplied by their 0.0.
+    lo = np.where(hi < lo, 0, lo).astype(np.int32)
+    # The least ids lie replicated beside sample-sharded planes.
+    sharding = features.indices.sharding
+    if isinstance(sharding, jax.sharding.NamedSharding):
+        sharding = jax.sharding.NamedSharding(sharding.mesh, jax.sharding.PartitionSpec())
+    elif len(sharding.device_set) != 1:
+        return features
+    return _with_spans(features, jax.device_put(lo, sharding), classes)
+
+
+def span_note(features: SparseFeatures) -> dict:
+    """How many of a shard's planes take the dense-span product, for the run
+    profile (stage note `ell_planes`)."""
+    classes = features.span_classes
+    return {
+        "planes": int(features.indices.shape[features.ell_axis]),
+        "dense_span": sum(1 for c in classes if c),
+        "classes": sorted({c for c in classes if c}),
+        "limit": DENSE_SPAN_LIMIT,
+    }
 
 
 Features = Union[Array, SparseFeatures]

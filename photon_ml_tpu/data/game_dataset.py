@@ -44,7 +44,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from photon_ml_tpu.data.containers import Features, LabeledData, SparseFeatures
+from photon_ml_tpu.data.containers import (
+    Features,
+    LabeledData,
+    SparseFeatures,
+    annotate_spans,
+)
 from photon_ml_tpu.types import ProjectorType
 from photon_ml_tpu.utils import faults
 
@@ -289,6 +294,22 @@ class GameDataset:
         if hasattr(shards, "host_view"):
             return shards.host_view(name)
         return shards[name]
+
+    def annotated_shard(self, name: str) -> Features:
+        """The shard as scoring and the ELL objective read it: an ELL shard
+        with its planes' dense-span annotation (`containers.annotate_spans`:
+        one reduction over the arrays and one fetch), made at the first call
+        and kept with the data set's other per-shard decisions, so no later
+        fit or scoring of these rows reads the spans again. Anything else, and
+        a shard with no narrow plane, as `shards[name]` holds it."""
+        feats = self.shards[name]
+        if not isinstance(feats, SparseFeatures):
+            return feats
+        key = ("ell_spans", name)
+        cached = self.bucketed_cache.get(key)
+        if cached is None or cached.indices is not feats.indices:
+            cached = self.bucketed_cache[key] = annotate_spans(feats)
+        return cached
 
     def release_stash(self) -> None:
         """Drop the ingest CSR stash when no coordinate will consume it

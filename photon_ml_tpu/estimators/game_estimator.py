@@ -724,6 +724,7 @@ class GameEstimator:
         self.timing_registry.clear_notes(
             "pack_path", "re_path", "sparse_layout", "sparse_objective",
             "pack_declined", "sample_sharding", "dense_storage",
+            "ell_planes", "ell_planes_scored",
         )
         # Snapshot the pod-scale robustness counters so fit_timing reports
         # THIS fit's events (the process-wide counters are cumulative).
@@ -1255,6 +1256,17 @@ class GameEstimator:
             # coordinate holds beside the shard. "none" where no such
             # coordinate was built.
             "dense_storage": self.timing_registry.get_note("dense_storage")
+            or "none",
+            # {planes, dense_span, classes, limit} where a fixed effect built
+            # its coordinate on an ELL shard in this fit: how many of the
+            # shard's planes have their margins multiplied as a dense span of
+            # the coefficients (data/containers, "dense span": the planes
+            # whose ids the shard shows to be neighbours), in which span
+            # classes, under which limit; the other planes' are gathered.
+            # `ell_planes_scored` is the same of the ELL shard scoring last
+            # prepared: in a fit, the validation rows'.
+            "ell_planes": self.timing_registry.get_note("ell_planes") or "none",
+            "ell_planes_scored": self.timing_registry.get_note("ell_planes_scored")
             or "none",
         }
         bucket_shapes: Dict[str, object] = {}

@@ -52,7 +52,7 @@ import numpy as np
 
 logger = logging.getLogger(__name__)
 
-from photon_ml_tpu.data.containers import LabeledData, SparseFeatures
+from photon_ml_tpu.data.containers import LabeledData, SparseFeatures, span_note
 from photon_ml_tpu.data.game_dataset import (
     GameDataset,
     RandomEffectDataset,
@@ -323,6 +323,14 @@ class FixedEffectCoordinate:
             # ELL path it is (pack declined/ineligible): materialize the
             # device copy through the dataset so other consumers share it.
             self._features = dataset.shards[config_data_shard]
+        if isinstance(self._features, SparseFeatures):
+            # Which planes are narrow enough for their margins to be a dense
+            # span of the coefficients is read from the concrete arrays, once a
+            # data set (the data set keeps the annotated shard: a rebuilt
+            # coordinate, the next fit and scoring fetch nothing), and noted:
+            # how many planes of how many, in which classes, under which limit.
+            self._features = dataset.annotated_shard(config_data_shard)
+            set_stage_note("ell_planes", span_note(self._features))
         # Sample-sharded rows (parallel/mesh.py): noted with the fit's other
         # dispatch decisions. An ELL shard's objective is then summed over
         # the mesh once an evaluation by the program itself

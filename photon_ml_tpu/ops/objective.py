@@ -132,7 +132,10 @@ def _summed_over_samples(dispatch: pallas_glm.ShardedDispatch, sums, replicated,
     from photon_ml_tpu.parallel.mesh import shard_map_compat
 
     axis = dispatch.axis
-    rows, planes = P(axis), P(axis, None)
+    rows = P(axis)
+    # The shard's (N, K) planes are cut by sample; what else it carries (the
+    # planes' least ids, where it is annotated) is every device's.
+    features = jax.tree.map(lambda a: P(axis, None) if a.ndim == 2 else P(), data.features)
 
     def per_device(replicated, features, labels, offsets, weights):
         partial = sums(*replicated, LabeledData(features, labels, offsets, weights))
@@ -142,7 +145,7 @@ def _summed_over_samples(dispatch: pallas_glm.ShardedDispatch, sums, replicated,
     return shard_map_compat(
         per_device,
         mesh=dispatch.mesh,
-        in_specs=(P(), planes, rows, rows, rows),
+        in_specs=(P(), features, rows, rows, rows),
         out_specs=P(),
     )(replicated, data.features, data.labels, data.offsets, data.weights)
 

@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from photon_ml_tpu.data.containers import Features, LabeledData, SparseFeatures
+from photon_ml_tpu.data.containers import Features, LabeledData, SparseFeatures, span_note
 from photon_ml_tpu.data.game_dataset import GameDataset
 from photon_ml_tpu.evaluation.suite import EvaluationResults, EvaluationSuite
 from photon_ml_tpu.game.model import (
@@ -47,6 +47,7 @@ from photon_ml_tpu.ops import objective
 from photon_ml_tpu.ops.losses import mean_for_task
 from photon_ml_tpu.ops.normalization import NormalizationContext
 from photon_ml_tpu.types import TaskType
+from photon_ml_tpu.utils.observability import set_stage_note
 
 Array = jax.Array
 
@@ -166,7 +167,12 @@ def prepare_coordinate_data(
     """Host-side, once per (coordinate, dataset): resolve entity rows and run
     the projector. Everything downstream is pure device compute."""
     if not spec.is_random_effect:
-        return PreparedCoordinateData(dataset.shards[spec.shard], None)
+        # An ELL shard is scored with its dense-span annotation, which the
+        # data set reads once and keeps (`GameDataset.annotated_shard`).
+        feats = dataset.annotated_shard(spec.shard)
+        if isinstance(feats, SparseFeatures):
+            set_stage_note("ell_planes_scored", span_note(feats))
+        return PreparedCoordinateData(feats, None)
     rows = entity_rows_for_dataset(dataset, spec)
     host_planes = getattr(dataset, "host_ell", {}).get(spec.shard)
     if spec.projector is not None and host_planes is not None:

@@ -193,23 +193,32 @@ def test_the_sharded_fit_equals_the_one_device_fit(sharded_fit, single_fit, asse
 # -- the devices' shares and the pad rows -----------------------------------
 
 
-def value_and_gradient(data, w, dispatch):
-    feats = data.shards["g"]
+def value_and_gradient(data, w, dispatch, annotated=False):
+    feats = data.annotated_shard("g") if annotated else data.shards["g"]
+    assert bool(feats.span_classes) == annotated
     rows = LabeledData(feats, data.labels, data.offsets, data.weights)
     return jax.jit(lambda w: objective.value_and_gradient(LOGISTIC, w, rows, None, 1.0, use_pallas=dispatch))(w)
 
 
+@pytest.mark.parametrize("planes", ["gathered", "dense_span"])
 @pytest.mark.parametrize("pads_by", ["the_entry", "the_caller"])
-def test_the_devices_shares_add_up_to_the_one_device_objective(problem, sharded_data, mesh, pads_by):
+def test_the_devices_shares_add_up_to_the_one_device_objective(problem, sharded_data, mesh, pads_by, planes):
     """Value and gradient summed over the four devices' rows, pad rows among
-    them, are the one-device objective over the real rows alone."""
+    them, are the one-device objective over the real rows alone: with every
+    plane gathered, and with the narrow planes' spans read from the global
+    arrays (the pad rows' index 0 stretches no span: 30 of 39 are narrow) and
+    their least ids riding replicated into every device's loops."""
     if pads_by == "the_entry":
         train = sharded_data[0]
     else:
         train = sample_sharded_dataset(device_parts(problem["train"], real_rows_only=False), mesh)
         assert train.num_samples == DEVICES * PER_DEVICE
     w = jax.random.normal(jax.random.PRNGKey(3), (DIM,), jnp.float32)
-    f4, g4 = value_and_gradient(train, w, pallas_glm.ShardedDispatch(mesh, "data"))
+    f4, g4 = value_and_gradient(train, w, pallas_glm.ShardedDispatch(mesh, "data"), planes == "dense_span")
+    if planes == "dense_span":
+        feats = train.annotated_shard("g")
+        assert sum(1 for c in feats.span_classes if c) == 30 and feats.span_classes[13] == 128
+        assert feats.span_lo.sharding.is_fully_replicated and len(feats.span_lo.sharding.device_set) == DEVICES
     f1, g1 = value_and_gradient(one_device(problem["train"]), w, False)
     np.testing.assert_allclose(float(f4), float(f1), rtol=2e-6)
     np.testing.assert_allclose(np.asarray(g4), np.asarray(g1), rtol=1e-4, atol=2e-5)
@@ -254,6 +263,7 @@ def test_the_solve_reduces_once_an_evaluation_and_gathers_no_plane(sharded_data)
     _, opt = estimator(small_config())
     coordinate = FixedEffectCoordinate(train, "g", opt["global"], TaskType.LOGISTIC_REGRESSION)
     assert isinstance(coordinate._use_pallas, pallas_glm.ShardedDispatch)
+    assert sum(1 for c in coordinate.training_features.span_classes if c) == 30  # the dense-span loops are in it
     text = coordinate._train_fn.lower(
         coordinate.training_features, train.labels, train.offsets, train.weights,
         jnp.zeros((DIM,), jnp.float32), jnp.float32(1.0), jax.random.PRNGKey(0),
@@ -267,14 +277,17 @@ def test_the_solve_reduces_once_an_evaluation_and_gathers_no_plane(sharded_data)
     assert depths[0] == 0 and depths[1] >= 2, depths  # before the loops; inside the line search
 
 
-def test_scoring_sharded_rows_crosses_no_device(sharded_data):
+@pytest.mark.parametrize("planes", ["gathered", "dense_span"])
+def test_scoring_sharded_rows_crosses_no_device(sharded_data, planes):
     from photon_ml_tpu.transformers.game_transformer import _fe_margins
 
     validation = sharded_data[1]
+    feats = validation.annotated_shard("g") if planes == "dense_span" else validation.shards["g"]
+    assert bool(feats.span_classes) == (planes == "dense_span")
     w = jnp.zeros((DIM,), jnp.float32)
-    compiled = _fe_margins.lower(validation.shards["g"], w, None).compile()
+    compiled = _fe_margins.lower(feats, w, None).compile()
     assert not COLLECTIVE.search(compiled.as_text())
-    scores = _fe_margins(validation.shards["g"], w, None)
+    scores = _fe_margins(feats, w, None)
     assert scores.sharding.is_equivalent_to(validation.labels.sharding, 1)
 
 

@@ -237,16 +237,22 @@ _HLO_INSTRUCTION = re.compile(
 
 
 _Instruction = collections.namedtuple(
-    "_Instruction", "elements minor layout opcode operands op_name"
+    "_Instruction", "elements minor layout opcode operands op_name computation calls"
 )
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?(%[\w.\-]+) \(.*\) -> .* \{$")
 
 
 def _array_instructions(text):
     """name -> _Instruction of every instruction of a compiled program's
     text whose result is one array (`operands` are names; `minor` is the
-    size of the dimension the layout puts on the lanes)."""
+    size of the dimension the layout puts on the lanes; `computation` is the
+    computation that holds it and `calls` the one a fusion runs)."""
     found = {}
+    computation = ""
     for line in text.splitlines():
+        c = _HLO_COMPUTATION.match(line)
+        if c:
+            computation = c.group(1)
         m = _HLO_INSTRUCTION.match(line)
         if m:
             name, dims, layout, opcode, operands, rest = m.groups()
@@ -258,17 +264,74 @@ def _array_instructions(text):
                 math.prod(dims), dims[int(minor.group(1))] if minor and dims else 1,
                 layout or "", opcode,
                 [o.strip() for o in operands.split(",")], op_name.group(1) if op_name else "",
+                computation, (re.search(r"calls=(%[\w.\-]+)", rest) or [None, ""])[1],
             )
     return found
 
 
-def test_the_plane_loop_gathers_from_vmem_at_the_criteo_shape(one_chip):
-    from photon_ml_tpu.data.containers import LabeledData, SparseFeatures
+def _buffers(instructions):
+    """The instructions that are arrays in memory: those outside the fusions'
+    own computations, where an instruction is a value in flight."""
+    fused = {i.calls for i in instructions.values() if i.opcode == "fusion"}
+    return {name: i for name, i in instructions.items() if i.computation not in fused}
+
+
+def _criteo_span_classes():
+    """The classes `annotate_spans` reads on a shard of `lr-criteo`'s fields."""
+    import json
+    import os
+
+    from photon_ml_tpu.data.containers import span_class
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs", "lr-criteo.json")) as f:
+        sizes = json.load(f)["generator"]["field_sizes"]
+    assert len(sizes) == CRITEO_NNZ and sum(sizes) == CRITEO_DIM
+    return tuple(span_class(0, size - 1) for size in sizes)
+
+
+def _assert_the_dense_span_loops(instructions, rows, classes, evaluations):
+    """What the program of an annotated shard must hold: for every class one
+    rolled loop an evaluation of the margins, whose body multiplies a plane by
+    compare, select and reduce inside one fusion (an array of class x rows
+    elements is a value in flight, never a buffer) and holds no gather."""
+    buffers = _buffers(instructions)
+    narrow = sorted({c for c in classes if c})
+    in_flight = collections.defaultdict(set)  # computation -> the classes of its class x rows values
+    for i in instructions.values():
+        for c in narrow:
+            if i.elements == c * rows:
+                in_flight[i.computation].add(c)
+    assert set().union(*in_flight.values()) == set(narrow), sorted(in_flight.values())
+    assert not [n for n, i in buffers.items() if i.elements >= min(narrow) * rows]
+    dense_span = [i for i in buffers.values() if i.opcode == "fusion" and i.calls in in_flight]
+    # A rolled loop holds one fusion a class; unrolled, a class of 21 planes
+    # would hold 21.
+    per_class = collections.Counter(c for i in dense_span for c in in_flight[i.calls])
+    assert all(per_class[c] == evaluations for c in narrow), per_class
+    bodies = {i.computation for i in dense_span}
+    strays = [
+        (n, i.op_name) for n, i in buffers.items()
+        if i.computation in bodies and i.op_name.endswith("/gather")
+    ]
+    assert not strays, strays[:5]
+
+
+@pytest.mark.parametrize("planes", ["gathered", "dense_span"])
+def test_the_plane_loop_gathers_from_vmem_at_the_criteo_shape(one_chip, planes):
+    """`planes` is what the shard shows: `gathered`, no narrow plane (the
+    program of PR 32); `dense_span`, `lr-criteo`'s own fields, 27 of whose 39
+    planes' margins are dense spans and 12 gathered; all 39 are scatter-added."""
+    from photon_ml_tpu.data.containers import LabeledData, SparseFeatures, _with_spans
     from photon_ml_tpu.ops import objective
     from photon_ml_tpu.optimize.lbfgs import minimize_lbfgs
 
-    def solve(indices, values, labels, offsets, weights, w0):
-        data = LabeledData(SparseFeatures(indices, values, CRITEO_DIM), labels, offsets, weights)
+    classes = _criteo_span_classes() if planes == "dense_span" else ()
+    table_pad = max(classes) if classes else 1
+
+    def solve(indices, values, span_lo, labels, offsets, weights, w0):
+        feats = _with_spans(SparseFeatures(indices, values, CRITEO_DIM), span_lo, classes)
+        data = LabeledData(feats, labels, offsets, weights)
         return minimize_lbfgs(
             lambda w: objective.value_and_gradient(LOGISTIC, w, data, None, 1.0, use_pallas=False),
             w0, max_iterations=CRITEO_ITERATIONS, tolerance=1e-9,
@@ -279,16 +342,30 @@ def test_the_plane_loop_gathers_from_vmem_at_the_criteo_shape(one_chip):
 
     rows = _vec(one_chip, CRITEO_ROWS)
     instructions = _array_instructions(_compiled_text(jax.jit(solve).lower(
-        plane(jnp.int32), plane(jnp.float32), rows, rows, rows, _vec(one_chip, CRITEO_DIM)
+        plane(jnp.int32), plane(jnp.float32), _vec(one_chip, CRITEO_NNZ, jnp.int32) if classes else None,
+        rows, rows, rows, _vec(one_chip, CRITEO_DIM)
     )))
     # Parameters, bitcasts of them and loop-carried tuple elements hold the
     # stored planes; anything else of that size is a temporary.
+    # Without a narrow plane every instruction is scanned, the fusions' own
+    # too, as before there was an annotation; a dense span's class x rows
+    # values, in flight inside its fusion, are more than rows x nnz.
+    scanned = _buffers(instructions) if classes else instructions
     temporaries = [
-        (name, i.opcode) for name, i in instructions.items()
+        (name, i.opcode) for name, i in scanned.items()
         if i.elements >= CRITEO_ROWS * CRITEO_NNZ
         and i.opcode not in ("parameter", "bitcast", "get-tuple-element")
     ]
     assert not temporaries, temporaries[:5]
+    if classes:
+        assert sum(1 for c in classes if c) == 27 and sorted(set(classes)) == [0, 128, 256, 512, 1024, 2048]
+        _assert_the_dense_span_loops(instructions, CRITEO_ROWS, classes, evaluations=2)
+        # The planes' scatter-add still sums into an accumulator in VMEM.
+        accumulators = [
+            i for i in instructions.values()
+            if i.opcode == "fusion" and i.elements == CRITEO_DIM and i.op_name.endswith("/scatter-add")
+        ]
+        assert len(accumulators) == 2 and all("S(1)" in i.layout for i in accumulators), accumulators
     # Every plane's gather, in the first evaluation (one loop deep: the
     # plane loop) and in the line search (under L-BFGS's loops), reads its
     # table, the plane loop's own copy of the coefficients, from VMEM.
@@ -299,7 +376,7 @@ def test_the_plane_loop_gathers_from_vmem_at_the_criteo_shape(one_chip):
     }
     assert min(tables) == 1 and max(tables) >= 3, sorted(tables)
     for depth, table in tables.items():
-        assert table.elements == CRITEO_DIM + 1 and "S(1)" in table.layout, (depth, table)
+        assert table.elements == CRITEO_DIM + table_pad and "S(1)" in table.layout, (depth, table)
 
 
 # `lr-criteo-full.fit`'s programs: the same solve over four chips, 11,460,155
@@ -309,11 +386,12 @@ FULL_ROWS_A_CHIP, CHIPS = 11_460_155, 4
 _COLLECTIVE = re.compile(r" = (.+?) (all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)(?:-start)?\(")
 
 
+@pytest.mark.parametrize("planes", ["gathered", "dense_span"])
 @pytest.mark.parametrize("program", ["solve", "score"])
-def test_the_sharded_plane_loop_at_the_whole_criteo_shape(four_chips, program):
+def test_the_sharded_plane_loop_at_the_whole_criteo_shape(four_chips, program, planes):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from photon_ml_tpu.data.containers import LabeledData, SparseFeatures
+    from photon_ml_tpu.data.containers import LabeledData, SparseFeatures, _with_spans
     from photon_ml_tpu.ops import objective
     from photon_ml_tpu.optimize.lbfgs import minimize_lbfgs
     from photon_ml_tpu.transformers.game_transformer import _fe_margins
@@ -323,9 +401,15 @@ def test_the_sharded_plane_loop_at_the_whole_criteo_shape(four_chips, program):
     indices = jax.ShapeDtypeStruct((n, CRITEO_NNZ), jnp.int32, sharding=planes)
     values = jax.ShapeDtypeStruct((n, CRITEO_NNZ), jnp.float32, sharding=planes)
     dispatch = pallas_glm.ShardedDispatch(four_chips, "data")
+    # As `annotate_spans` hands them on: the classes static, the least ids
+    # replicated beside the sharded planes.
+    classes = _criteo_span_classes() if planes == "dense_span" else ()
+    span_lo = _vec(whole, CRITEO_NNZ, jnp.int32) if classes else None
+    table_pad = max(classes) if classes else 1
 
-    def solve(indices, values, labels, offsets, weights, w0):
-        data = LabeledData(SparseFeatures(indices, values, CRITEO_DIM), labels, offsets, weights)
+    def solve(indices, values, span_lo, labels, offsets, weights, w0):
+        feats = _with_spans(SparseFeatures(indices, values, CRITEO_DIM), span_lo, classes)
+        data = LabeledData(feats, labels, offsets, weights)
         return minimize_lbfgs(
             lambda w: objective.value_and_gradient(LOGISTIC, w, data, None, 1.0, use_pallas=dispatch),
             w0, max_iterations=CRITEO_ITERATIONS, tolerance=1e-9,
@@ -333,20 +417,24 @@ def test_the_sharded_plane_loop_at_the_whole_criteo_shape(four_chips, program):
 
     if program == "solve":
         text = _compiled_text(jax.jit(solve).lower(
-            indices, values, _vec(rows, n), _vec(rows, n), _vec(rows, n), _vec(whole, CRITEO_DIM)
+            indices, values, span_lo, _vec(rows, n), _vec(rows, n), _vec(rows, n), _vec(whole, CRITEO_DIM)
         ))
     else:
         text = _compiled_text(_fe_margins.lower(
-            SparseFeatures(indices, values, CRITEO_DIM), _vec(whole, CRITEO_DIM), None
+            _with_spans(SparseFeatures(indices, values, CRITEO_DIM), span_lo, classes),
+            _vec(whole, CRITEO_DIM), None,
         ))
     instructions = _array_instructions(text)
     # No array of a chip's rows x 39 elements is made, on any chip.
+    scanned = _buffers(instructions) if classes else instructions
     temporaries = [
-        (name, i.opcode) for name, i in instructions.items()
+        (name, i.opcode) for name, i in scanned.items()
         if i.elements >= FULL_ROWS_A_CHIP * CRITEO_NNZ
         and i.opcode not in ("parameter", "bitcast", "get-tuple-element")
     ]
     assert not temporaries, temporaries[:5]
+    if classes:  # every chip runs the one-chip dense-span loops on its own rows
+        _assert_the_dense_span_loops(instructions, FULL_ROWS_A_CHIP, classes, evaluations=2 if program == "solve" else 1)
     # Every plane gather is of one chip's rows and reads its table from VMEM.
     tables = {
         i.op_name.count("while/body"): instructions[i.operands[0]]
@@ -355,7 +443,7 @@ def test_the_sharded_plane_loop_at_the_whole_criteo_shape(four_chips, program):
     }
     assert tables and min(tables) == 1, sorted(tables)
     for depth, table in tables.items():
-        assert table.elements == CRITEO_DIM + 1 and "S(1)" in table.layout, (depth, table)
+        assert table.elements == CRITEO_DIM + table_pad and "S(1)" in table.layout, (depth, table)
     collectives = [(m.group(2), m.group(1)) for m in map(_COLLECTIVE.search, text.splitlines()) if m]
     if program == "solve":
         # One reduction an evaluation (the first, and the line search's), of

@@ -8,6 +8,7 @@ leaves is recorded by name with the reason; shards that packed before still
 pack, and into the planes they gave before.
 """
 
+import dataclasses
 import json
 import os
 
@@ -18,8 +19,8 @@ import pytest
 
 from benchmarks.generators import criteo_shape
 from benchmarks.references import glm_sparse_lbfgs
-from photon_ml_tpu.data import bucketed
-from photon_ml_tpu.data.containers import SparseFeatures
+from photon_ml_tpu.data import bucketed, containers
+from photon_ml_tpu.data.containers import SparseFeatures, annotate_spans, span_note
 from photon_ml_tpu.data.game_dataset import FixedEffectDataConfig, GameDataset
 from photon_ml_tpu.estimators.game_estimator import GameEstimator
 from photon_ml_tpu.evaluation.suite import EvaluatorType
@@ -286,3 +287,319 @@ def test_the_scopes_cover_the_ell_gather_and_scatter(problem, program, scopes, o
         if n.endswith(operation) and plane_loop in n and all(f"/{s}/" in n for s in scopes)
     ]
     assert found, f"no {operation} under {scopes} in a plane loop among {sorted(set(names))[:20]}"
+
+
+# -- a narrow plane is a dense span, not a gather ------------------------------
+
+def field_major_shard(sizes, n, seed, lows=None, pad_share=0.0, dim=None):
+    """(n, K) ids, a field a plane: plane k's ids uniform over
+    [lows[k], lows[k] + sizes[k]) with both ends present, fields back to back
+    unless `lows` says otherwise; a `pad_share` of the slots padding."""
+    rng = np.random.default_rng(seed)
+    lows = np.cumsum([0] + list(sizes[:-1])) if lows is None else np.asarray(lows)
+    idx = np.stack([lo + rng.integers(0, size, n) for lo, size in zip(lows, sizes)], 1).astype(np.int32)
+    idx[0], idx[1] = lows, lows + np.asarray(sizes) - 1
+    val = rng.normal(size=idx.shape).astype(np.float32)
+    pad = rng.random(idx.shape) < pad_share
+    pad[:2] = False
+    idx[pad], val[pad] = 0, 0.0
+    dim = int(lows[-1] + sizes[-1]) if dim is None else dim
+    return SparseFeatures(jnp.asarray(idx), jnp.asarray(val), dim)
+
+
+# name -> (shard, the classes its planes must read)
+SPAN_CASES = {
+    # the intercept every Photon ML row carries: one id
+    "intercept": lambda: (field_major_shard([1, 3000], 700, 1), (128, 0)),
+    # 130 ids from id 100 on: over one row of lanes, and across ids 128 and 256
+    "crosses_a_class_boundary": lambda: (field_major_shard([130, 4000], 700, 2, lows=[100, 230]), (256, 0)),
+    # the last field ends at dim - 1 and its class reaches past the end of the coefficients
+    "ends_at_dim_minus_1": lambda: (field_major_shard([5000, 70], 700, 3), (0, 128)),
+    # padding slots (index 0, value 0.0) in planes whose spans do not hold id 0
+    "padding_outside_the_span": lambda: (
+        field_major_shard([40, 300, 5000], 900, 4, lows=[5, 45, 345], pad_share=0.2, dim=5400), (128, 512, 0)),
+    # the ladder of classes, narrow planes between wide ones, a plane that is all padding
+    "every_class_interleaved": lambda: (
+        with_an_empty_last_plane(field_major_shard([3000, 100, 129, 5000, 500, 1000, 1025, 7], 600, 5, pad_share=0.1)),
+        (0, 128, 256, 0, 512, 1024, 2048, 128)),
+    # a field wider than the limit by one id keeps the gather
+    "one_id_over_the_limit": lambda: (field_major_shard([2049, 2048, 3000], 700, 9), (0, 2048, 0)),
+}
+
+
+def with_an_empty_last_plane(feats):
+    idx, val = np.array(feats.indices), np.array(feats.values)
+    idx[:, -1], val[:, -1] = 0, 0.0
+    return SparseFeatures(jnp.asarray(idx), jnp.asarray(val), feats.dim)
+
+
+@pytest.mark.parametrize("product", ["matvec", "rmatvec", "sq_rmatvec"])
+@pytest.mark.parametrize("case", sorted(SPAN_CASES))
+def test_the_dense_span_products_equal_the_gathered_ones(case, product):
+    plain, classes = SPAN_CASES[case]()
+    annotated = annotate_spans(plain)
+    assert annotated.span_classes == classes
+    assert plain.span_classes == () and plain.span_lo is None
+    rng = np.random.default_rng(11)
+    n, dim = plain.shape
+    operand = jnp.asarray(rng.normal(size=dim if product == "matvec" else n).astype(np.float32))
+    run = jax.jit(lambda feats, x: getattr(feats, product)(x))
+    want, got = np.asarray(run(plain, operand)), np.asarray(run(annotated, operand))
+    if product == "matvec":
+        # One term of a span's sum is non-zero, so a narrow plane's margins are
+        # the gathered ones to the bit; the planes are added narrow first, so
+        # the row sums may differ in the last place.
+        np.testing.assert_allclose(got, want, rtol=0, atol=4e-6)
+        one = jax.jit(lambda feats, x, k: containers._with_spans(
+            dataclasses.replace(feats, indices=feats.indices[:, k:k + 1], values=feats.values[:, k:k + 1]),
+            None if feats.span_lo is None else feats.span_lo[k:k + 1], feats.span_classes[k:k + 1],
+        ).matvec(x), static_argnums=2)
+        for k, c in enumerate(classes):
+            if c:
+                assert np.array_equal(np.asarray(one(annotated, operand, k)), np.asarray(one(plain, operand, k))), k
+    else:
+        # The transpose scatter-adds every plane, annotated or not (`rmatvec`).
+        assert np.array_equal(got, want)
+
+
+def test_field_major_planes_in_stored_order_give_the_margins_to_the_bit():
+    """Where the narrow planes come first and in ascending class, as the
+    benchmark's fields do, the planes are added in the stored order."""
+    plain = field_major_shard([40, 40, 4, 100, 200, 600, 5000, 9000], 800, 6)
+    annotated = annotate_spans(plain)
+    assert annotated.span_classes == (128, 128, 128, 128, 256, 1024, 0, 0) and containers.DENSE_SPAN_LIMIT == 2048
+    w = jnp.asarray(np.random.default_rng(12).normal(size=plain.dim).astype(np.float32))
+    run = jax.jit(lambda feats, x: feats.matvec(x))
+    assert np.array_equal(np.asarray(run(annotated, w)), np.asarray(run(plain, w)))
+
+
+def hashed_shard(n, k, dim, seed):
+    """Every field hashed into one space, as the LIBSVM copy of `criteo` is."""
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, dim, (n, k)).astype(np.int32)
+    return SparseFeatures(jnp.asarray(idx), jnp.asarray(rng.normal(size=idx.shape).astype(np.float32)), dim)
+
+
+@pytest.mark.parametrize("product", ["matvec", "rmatvec"])
+def test_a_shard_with_no_narrow_plane_traces_the_program_it_traced(product):
+    plain = hashed_shard(500, 6, 50_000, 7)
+    annotated = annotate_spans(plain)
+    assert annotated is plain and span_note(annotated) == {
+        "planes": 6, "dense_span": 0, "classes": [], "limit": containers.DENSE_SPAN_LIMIT}
+    # ... and is, operation for operation, the loop over all the planes that
+    # an explicit "every plane wide" annotation would also have to be.
+    operand = jnp.zeros((plain.dim if product == "matvec" else 500,), jnp.float32)
+    text = str(jax.make_jaxpr(lambda f, x: getattr(f, product)(x))(plain, operand))
+    assert text.count("scan") == 1 and "dynamic_slice" not in text
+    narrow = annotate_spans(field_major_shard([40, 3000], 500, 8))
+    narrow_text = str(jax.make_jaxpr(lambda f, x: getattr(f, product)(x))(
+        narrow, jnp.zeros((narrow.dim if product == "matvec" else 500,), jnp.float32)))
+    if product == "matvec":  # a loop a class, then the wide planes'
+        assert narrow_text.count("scan") == 2 and "dynamic_slice" in narrow_text
+    else:
+        assert narrow_text.count("scan") == 1 and "dynamic_slice" not in narrow_text
+
+
+def test_two_seeds_of_one_field_layout_compile_one_program():
+    """The rarest ids of a field may or may not occur: the classes are the
+    same, the least ids are data, and the second seed traces nothing."""
+    sizes = [40, 97, 300, 900, 5000]
+    traces = []
+
+    @jax.jit
+    def margins(feats, w):
+        traces.append(1)
+        return feats.matvec(w)
+
+    shards = []
+    for seed in (21, 22):
+        idx = np.array(field_major_shard(sizes, 400, seed).indices)
+        idx[:, 1] = np.clip(idx[:, 1], 40 + seed % 3, 40 + 90 + seed % 5)  # the ends of a field, by the seed
+        shards.append(annotate_spans(SparseFeatures(jnp.asarray(idx), jnp.ones(idx.shape, jnp.float32), sum(sizes))))
+    assert shards[0].span_classes == shards[1].span_classes == (128, 128, 512, 1024, 0)  # 5,000 ids: wide
+    assert not np.array_equal(np.asarray(shards[0].span_lo), np.asarray(shards[1].span_lo))
+    for shard in shards:
+        margins(shard, jnp.ones((sum(sizes),), jnp.float32))
+    assert len(traces) == 1
+
+
+def test_only_a_flat_device_shard_is_annotated():
+    block = SparseFeatures(jnp.zeros((3, 8, 4), jnp.int32), jnp.ones((3, 8, 4)), 16)  # entity blocks, under vmap
+    transposed = SparseFeatures(jnp.zeros((4, 8), jnp.int32), jnp.ones((4, 8)), 16, ell_axis=-2)
+    host = SparseFeatures(np.zeros((8, 4), np.int32), np.ones((8, 4), np.float32), 16)
+    for feats in (block, transposed, host):
+        assert annotate_spans(feats) is feats
+
+
+def test_an_annotation_does_not_outlive_the_indices_it_was_read_from():
+    """The annotation is no argument of the constructor: whatever builds a
+    shard from other arrays builds it unannotated (stale least ids would make
+    `matvec` drop the ids outside them), and only a pytree's way back from
+    its own leaves carries it on."""
+    annotated = annotate_spans(field_major_shard([40, 300, 5000], 400, 31))
+    assert annotated.span_classes == (128, 512, 0)
+    moved = dataclasses.replace(annotated, indices=annotated.indices + 1, dim=annotated.dim + 1)
+    assert moved.span_classes == () and moved.span_lo is None
+    assert dataclasses.replace(annotated, values=annotated.values * 2).span_classes == ()
+    with pytest.raises(TypeError):
+        SparseFeatures(annotated.indices, annotated.values, annotated.dim, span_lo=annotated.span_lo)
+    with pytest.raises(ValueError):
+        dataclasses.replace(annotated, span_classes=(0, 0, 0))
+    paths, tree = jax.tree_util.tree_flatten_with_path(annotated)
+    assert [jax.tree_util.keystr(path) for path, _ in paths] == [".indices", ".values", ".span_lo"]
+    back = jax.tree_util.tree_unflatten(tree, [leaf for _, leaf in paths])
+    assert back.span_classes == annotated.span_classes and back.span_lo is annotated.span_lo
+    assert (back.dim, back.ell_axis) == (annotated.dim, annotated.ell_axis)
+    plain = dataclasses.replace(annotated)
+    assert [jax.tree_util.keystr(path) for path, _ in jax.tree_util.tree_flatten_with_path(plain)[0]] == [".indices", ".values"]
+    assert jax.tree_util.tree_structure(plain) != tree
+
+
+# Whose shards have narrow planes. (1) This repository's own: `IndexMap` sorts
+# the `name\x01term` keys, so a name's terms own one contiguous id range, and
+# the reader keeps a record's features in the record's order; records that list
+# their columns in one order give a field a plane. (2) The public click
+# pipelines that index a field at a time: `rixwew/pytorch-fm`
+# (`torchfm/layer.py`, `FeaturesLinear`: the input is a `(batch, num_fields)`
+# id matrix to which `offsets = (0, *cumsum(field_dims)[:-1])` is added, its
+# `LogisticRegressionModel` is that layer and a sigmoid;
+# `torchfm/dataset/criteo.py`: 39 fields, a count v > 2 becomes
+# `int(log(v) ** 2)`) and `facebookresearch/dlrm` (`data_utils.py`: one
+# contiguous id space a categorical column). The 26 categorical cardinalities
+# below are the Criteo Display Advertising Challenge's as DLRM's preprocessing
+# counts them on the Kaggle `train.txt` (its `--arch-embedding-size`); a
+# 31-bit count gives a numeric field under 465 ids by pytorch-fm's rule.
+CRITEO_KAGGLE_CATEGORICAL = (
+    1460, 583, 10131227, 2202608, 305, 24, 12517, 633, 3, 93145, 5683, 8351593, 3194,
+    27, 14992, 5461306, 10, 5652, 2173, 4, 7046547, 18, 15, 286181, 105, 142572,
+)
+CRITEO_NUMERIC_FIELD = int(np.log(2.0 ** 31) ** 2) + 3  # int(log(v)**2) for v > 2; -1, 0 and NULL
+
+
+def test_the_published_criteo_fields_indexed_a_field_at_a_time_have_25_narrow_planes_of_39():
+    """`lr-criteo`'s generator draws 27 narrow fields of 39 (its
+    `assumed.fields`); the source's own columns, indexed as the public
+    pipelines index them, give 25 (13 numeric at class 512, 12 categorical)."""
+    sizes = [CRITEO_NUMERIC_FIELD] * 13 + list(CRITEO_KAGGLE_CATEGORICAL)
+    assert len(sizes) == 39 and sum(CRITEO_KAGGLE_CATEGORICAL) == 33_762_577  # the data set's 33.76 M categorical values
+    classes = annotate_spans(field_major_shard(sizes, 64, 41)).span_classes
+    assert classes == tuple(containers.span_class(0, size - 1) for size in sizes)
+    assert sum(1 for c in classes if c) == 25 and classes[:13] == (512,) * 13
+    assert sum(1 for c in classes[13:] if 0 < c <= 128) == 8
+    ours = real_fields_config()["generator"]["field_sizes"]
+    assert sum(1 for size in ours if containers.span_class(0, size - 1)) == 27
+
+
+@pytest.mark.parametrize("order", ["columns_in_one_order", "shuffled_in_every_record"])
+def test_name_term_records_through_this_repositorys_reader_are_field_major(tmp_path, order):
+    from photon_ml_tpu.data.index_map import feature_key
+    from photon_ml_tpu.io.avro_data import FeatureShardConfig, read_game_dataset, write_training_examples
+
+    fields = {"weekday": 7, "hour": 24, "device": 4, "country": 60, "position": 10, "advertiser": 3000, "user_bucket": 9000}
+    rng = np.random.default_rng(51)
+    rows = []
+    for _ in range(3000):  # an index map holds the ids it saw: a wide field needs rows
+        row = [(feature_key(name, str(rng.integers(0, size))), 1.0) for name, size in fields.items()]
+        if order == "shuffled_in_every_record":
+            rng.shuffle(row)
+        rows.append(row)
+    path = str(tmp_path / "clicks.avro")
+    write_training_examples(path, rows, rng.integers(0, 2, len(rows)).astype(float))
+    data, index_maps = read_game_dataset(path, {"g": FeatureShardConfig(("features",), True)})
+    # A name's terms own one contiguous range of the sorted index map ...
+    for name in fields:
+        ids = sorted(i for key, i in index_maps["g"].items() if key.startswith(name + "\x01"))
+        assert ids == list(range(ids[0], ids[0] + len(ids)))
+    # ... and a plane holds one column of the records: a field, where the
+    # records list their columns in one order. The intercept is the last plane.
+    note = span_note(data.annotated_shard("g"))
+    if order == "columns_in_one_order":
+        assert data.annotated_shard("g").span_classes == (128, 128, 128, 128, 128, 2048, 0, 128)
+        assert note["dense_span"] == 7
+    else:
+        assert note["dense_span"] == 1  # the intercept; the program of the other planes is the gathered one
+
+
+# -- the fit reads the spans once a data set and says what it found ------------
+
+
+def real_fields_config():
+    """The benchmark's own 39 fields over its 1,000,000 ids: 21 span at most
+    98 ids, 27 at most 1,522, the other 12 from 2,404 to 366,654."""
+    with open(os.path.join(ROOT, "benchmarks", "configs", "lr-criteo.json")) as f:
+        return json.load(f)
+
+
+@pytest.fixture(scope="module")
+def real_fields_problem():
+    return criteo_shape.generate(real_fields_config(), 2_147_483_693, rows=16_000)
+
+
+@pytest.fixture
+def span_reads(monkeypatch):
+    """Every reduction over a shard's arrays for its spans, as it is made."""
+    reads = []
+    reduce = containers._plane_spans
+
+    def counted(indices, values):
+        reads.append(indices.shape)
+        return reduce(indices, values)
+
+    monkeypatch.setattr(containers, "_plane_spans", counted)
+    return reads
+
+
+def test_a_fit_notes_its_dense_span_planes_and_reads_the_spans_once_a_data_set(real_fields_problem, span_reads):
+    config = real_fields_config()
+    est, opt = estimator(config)
+    train, validation = dataset(real_fields_problem["train"]), dataset(real_fields_problem["validation"])
+    est.fit(train, validation, [opt])
+    dispatch = est.run_profile()["dispatch"]
+    narrow = sum(1 for size in config["generator"]["field_sizes"] if size <= containers.DENSE_SPAN_LIMIT)
+    assert narrow == 27
+    classes = sorted({containers.span_class(0, size - 1) for size in config["generator"]["field_sizes"]} - {0})
+    note = {"planes": 39, "dense_span": narrow, "classes": classes, "limit": containers.DENSE_SPAN_LIMIT}
+    assert dispatch["sparse_objective"] == "ell_xla"
+    assert dispatch["ell_planes"] == note and dispatch["ell_planes_scored"] == note
+    assert sorted(span_reads) == [(2_000, 39), (16_000, 39)]  # the validation rows', the training rows'
+    # The next fits on these data sets, another regularisation weight's
+    # coordinate among them, and scoring the rows again fetch nothing.
+    est.fit(train, validation, [opt])
+    other = {"global": dataclasses.replace(opt["global"], reg_weight=3.0)}
+    est.fit(train, validation, [other])
+    assert len(span_reads) == 2
+    assert est.run_profile()["dispatch"]["ell_planes_scored"] == note
+    assert train.annotated_shard("g") is train.annotated_shard("g")
+    assert train.annotated_shard("g").indices is train.shards["g"].indices  # no plane is stored anew
+
+
+def test_a_hashed_shard_has_no_dense_span_plane(span_reads):
+    rng = np.random.default_rng(5)
+    data = GameDataset.build({"g": hashed_shard(3_000, 12, 60_000, 9)}, (rng.random(3_000) < 0.3).astype(np.float32))
+    est, opt = estimator(small_config())
+    est.fit(data, data, [opt])
+    dispatch = est.run_profile()["dispatch"]
+    none = {"planes": 12, "dense_span": 0, "classes": [], "limit": containers.DENSE_SPAN_LIMIT}
+    assert dispatch["ell_planes"] == none and dispatch["ell_planes_scored"] == none
+    assert span_reads == [(3_000, 12)]  # one data set
+    assert data.annotated_shard("g") is data.shards["g"]
+
+
+@pytest.mark.parametrize("what", ["coefficients", "auc"])
+def test_the_fit_is_the_gathered_fit(problem, fitted, monkeypatch, what):
+    """With the limit at nothing every plane's margins are gathered, as before;
+    `fitted` multiplied 30 of its 39 planes as dense spans and is that fit to
+    the bit: a narrow plane's margins are the gathered ones (one term of a
+    span's sum is non-zero), these fields' narrow planes come first in the
+    stored order, and the transpose is the scatter-add either way."""
+    est, result = fitted
+    assert est.run_profile()["dispatch"]["ell_planes"]["dense_span"] == 30
+    monkeypatch.setattr(containers, "DENSE_SPAN_LIMIT", 0)
+    plain_est, opt = estimator(small_config())
+    plain = plain_est.fit(dataset(problem["train"]), dataset(problem["validation"]), [opt])[0]
+    assert plain_est.run_profile()["dispatch"]["ell_planes"]["dense_span"] == 0
+    if what == "coefficients":
+        w, ref = (np.asarray(r.model["global"].coefficients.means) for r in (result, plain))
+        assert np.linalg.norm(ref) > 1.0 and np.array_equal(w, ref)
+    else:
+        assert float(result.evaluation.primary_value) == float(plain.evaluation.primary_value)
