@@ -649,11 +649,13 @@ class GameEstimator:
         """This fit's stage walls and evaluation counts, per fit and per
         process: `fit_timing["stages_s"]` (plain floats; a stage that did
         not run reads 0.0) beside `fit_timing["fn_evals"]`,
-        `fit_timing["line_search_rejected"]` and
+        `fit_timing["line_search_rejected"]`, `fit_timing["hv_evals"]`
+        (TRON fixed effects only) and
         `fit_timing["gradient_allreduce_bytes"]` (empty on one device), and
         the same numbers into `telemetry.METRICS` — histogram
-        `fit_stage_s{stage=<name>}`, counters `objective_evaluations` and
-        `line_search_rejected_trials` `{coordinate=<id>,kind=fixed|random}`,
+        `fit_stage_s{stage=<name>}`, counters `objective_evaluations`,
+        `line_search_rejected_trials` and `hessian_vector_products`
+        `{coordinate=<id>,kind=fixed|random}`,
         `gradient_allreduce_bytes{coordinate=<id>}` — so a reader that sees only
         the process gets a window's totals as the process total less the
         fits made before it."""
@@ -675,6 +677,10 @@ class GameEstimator:
         for cid, trials in self.fit_timing["line_search_rejected"].items():
             telemetry.METRICS.increment(
                 "line_search_rejected_trials", trials, labels=labels(cid)
+            )
+        for cid, products in self.fit_timing["hv_evals"].items():
+            telemetry.METRICS.increment(
+                "hessian_vector_products", products, labels=labels(cid)
             )
         for cid, nbytes in self.fit_timing["gradient_allreduce_bytes"].items():
             telemetry.METRICS.increment(
@@ -724,7 +730,7 @@ class GameEstimator:
         self.timing_registry.clear_notes(
             "pack_path", "re_path", "sparse_layout", "sparse_objective",
             "pack_declined", "sample_sharding", "dense_storage",
-            "ell_planes", "ell_planes_scored",
+            "ell_planes", "ell_planes_scored", "tron",
         )
         # Snapshot the pod-scale robustness counters so fit_timing reports
         # THIS fit's events (the process-wide counters are cumulative).
@@ -776,6 +782,7 @@ class GameEstimator:
         collective_bytes = 0
         fn_evals: Dict[str, int] = {}
         line_search_rejected: Dict[str, int] = {}
+        hv_evals: Dict[str, int] = {}
         allreduce_bytes: Dict[str, int] = {}
         sharding_infos: Dict[str, dict] = {}
         default_cfg = CoordinateOptimizationConfig()
@@ -887,6 +894,8 @@ class GameEstimator:
                     )
             for cid, trials in cd.line_search_rejected.items():
                 line_search_rejected[cid] = line_search_rejected.get(cid, 0) + trials
+            for cid, counts in cd.tron.items():
+                hv_evals[cid] = hv_evals.get(cid, 0) + counts["hessian_vector_products"]
             self.fit_timing["solve_s"] += descent.seconds + final_evaluate.seconds
             logger.info(
                 "configuration %d/%d trained%s",
@@ -897,6 +906,7 @@ class GameEstimator:
         with stage_timer("fit/publish"):
             self.fit_timing["fn_evals"] = fn_evals
             self.fit_timing["line_search_rejected"] = line_search_rejected
+            self.fit_timing["hv_evals"] = hv_evals
             self.fit_timing["gradient_allreduce_bytes"] = allreduce_bytes
             # Finalize the per-stage prepare breakdown: deltas of the timing
             # registry over this fit call. In a synchronous run the stages +
@@ -1268,6 +1278,11 @@ class GameEstimator:
             "ell_planes": self.timing_registry.get_note("ell_planes") or "none",
             "ell_planes_scored": self.timing_registry.get_note("ell_planes_scored")
             or "none",
+            # {accepted, rejected, hessian_vector_products, kernel} of the
+            # fixed effect a TRON solve last updated in this fit, summed over
+            # its updates (CoordinateDescentResult.tron); the refused steps
+            # are reported here and nowhere else.
+            "tron": self.timing_registry.get_note("tron") or "none",
         }
         bucket_shapes: Dict[str, object] = {}
         for cid, prep in (self._prepared or {}).items():

@@ -226,6 +226,10 @@ class FixedEffectCoordinate:
                 feats, jnp.zeros((feats.shape[-1],), jnp.float32)
             )
         )
+        # What computes a TRON solve's Hessian-vector products here: the fused
+        # dense kernel wherever it computes the value and gradient, else the
+        # XLA composition (every sparse shard's, whatever its products run on).
+        self.hessian_vector_kernel = "xla" if self._use_pallas is False else "pallas"
         # How the matrix the fused kernels will read lies, read ONCE here
         # from the concrete array (inside train_fn's trace it is a tracer
         # with no layout) and handed to them with the data: where it lies

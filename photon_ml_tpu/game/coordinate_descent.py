@@ -32,7 +32,11 @@ from photon_ml_tpu.evaluation.suite import EvaluationResults, EvaluationSuite
 from photon_ml_tpu.game.model import GameModel
 from photon_ml_tpu.types import OptimizerType
 from photon_ml_tpu.utils import faults, telemetry
-from photon_ml_tpu.utils.observability import record_stage, stage_timer
+from photon_ml_tpu.utils.observability import (
+    record_stage,
+    set_stage_note,
+    stage_timer,
+)
 
 logger = logging.getLogger(__name__)
 
@@ -165,27 +169,34 @@ def _recover_from_mesh_loss(
     return models, best_models, snap_best_res, snap_pass, completed_steps, "memory"
 
 
-def _fetch_verdict(ok, stats, line_search):
-    """(finite, fn_evals, rejected trials) of one attempted update, with the
-    ONE device-to-host fetch the divergence guard always made: the fixed
-    effect's `OptResult.fn_evals` and `iterations` are device scalars, ready
-    since its solve ended, and ride along with the guard's boolean. A random
-    effect's stats hold the counts on the host already (`_finish_train`'s one
-    fetch). The rejected trials of a line-search solve are its evaluations
-    less the first and one an iteration (a search that fails outright reads
-    one short); None for TRON, whose count holds Hessian-vector products.
-    Both None where the coordinate's train reports no count."""
-    evals = getattr(stats, "fn_evals", None)
-    if evals is None:
-        return bool(ok), None, None
-    iterations = stats.iterations
-    if isinstance(evals, jax.Array):
-        ok, evals, iterations = jax.device_get((ok, evals, iterations))
-    evals = int(evals)
-    rejected = (
-        evals - getattr(stats, "solves", 1) - int(iterations) if line_search else None
+def _fetch_verdict(ok, stats):
+    """(finite, counts) of one attempted update, with the ONE device-to-host
+    fetch the divergence guard always made: the fixed effect's
+    `OptResult.fn_evals`, `iterations` and (TRON) `hv_evals` are device
+    scalars, ready since its solve ended, and ride along with the guard's
+    boolean. A random effect's stats hold the counts on the host already
+    (`_finish_train`'s one fetch). `counts` holds `fn_evals`, `iterations`
+    and `rejected`, what the solve evaluated and threw away: its passes over
+    the data less the first evaluation, one an iteration and the
+    Hessian-vector products — a line-search solve's failed Armijo trials (a
+    search that fails outright reads one short), a TRON solve's refused
+    trial steps — and `hv_evals` where the stats carry that count (a fixed
+    effect's TRON solve). Empty where the coordinate's train reports no
+    count."""
+    if getattr(stats, "fn_evals", None) is None:
+        return bool(ok), {}
+    names = ["fn_evals", "iterations"]
+    if getattr(stats, "hv_evals", None) is not None:
+        names.append("hv_evals")
+    fetched = (ok, *(getattr(stats, name) for name in names))
+    if isinstance(stats.fn_evals, jax.Array):
+        fetched = jax.device_get(fetched)
+    counts = {name: int(value) for name, value in zip(names, fetched[1:])}
+    counts["rejected"] = (
+        counts["fn_evals"] - getattr(stats, "solves", 1) - counts["iterations"]
+        - counts.get("hv_evals", 0)
     )
-    return bool(ok), evals, rejected
+    return bool(fetched[0]), counts
 
 
 def _update_all_finite(model, scores) -> bool:
@@ -218,6 +229,13 @@ class CoordinateDescentResult:
     # iteration, each a value+gradient evaluation thrown away. A TRON
     # coordinate is absent.
     line_search_rejected: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # Per TRON coordinate whose train reports them (a fixed effect's), what
+    # its solves did: {"accepted", "rejected" (trial steps refused, each a
+    # truncated CG and a value+gradient evaluation thrown away),
+    # "hessian_vector_products" (among `fn_evals`; one a CG iteration, so
+    # also the CG iterations), "kernel" ("pallas" | "xla": what computes a
+    # product)}.
+    tron: Dict[str, dict] = dataclasses.field(default_factory=dict)
     # Analytic wire bytes moved through entity-shard ring collectives by
     # the accepted coordinate updates (RandomEffectCoordinate.train sets
     # last_train_collective_bytes per sweep; 0 on the replicated path) —
@@ -323,6 +341,7 @@ def run_coordinate_descent(
     timing: Dict[str, float] = {}
     fn_evals: Dict[str, int] = {}
     line_search_rejected: Dict[str, int] = {}
+    tron: Dict[str, dict] = {}
     diverged_steps = 0
     collective_bytes = 0
     validation_history: List[Tuple[int, str, EvaluationResults]] = []
@@ -524,11 +543,7 @@ def run_coordinate_descent(
             model = None
             new_scores = None
             new_summed = None
-            update_evals: Optional[int] = None
-            update_rejected: Optional[int] = None
-            line_search = (
-                coord.config.optimizer.optimizer_type != OptimizerType.TRON
-            )
+            update_counts: Dict[str, int] = {}  # summed over the attempts
             # One stage per coordinate update, its wall the update's
             # `timing` entry; the cd/* stages inside it are declared in
             # contracts.SOLVE_STAGES (which are dispatch walls, which wait).
@@ -591,13 +606,9 @@ def run_coordinate_descent(
                                     cand_scores,
                                     _model_arrays(cand_model, cand_scores),
                                 )
-                                finite, evals, rejected = _fetch_verdict(
-                                    ok, stats, line_search
-                                )
-                            if evals is not None:
-                                update_evals = (update_evals or 0) + evals
-                            if rejected is not None:
-                                update_rejected = (update_rejected or 0) + rejected
+                                finite, counts = _fetch_verdict(ok, stats)
+                            for name, count in counts.items():
+                                update_counts[name] = update_counts.get(name, 0) + count
                         except faults.MeshLoss:
                             raise
                         except BaseException as exc:
@@ -632,6 +643,7 @@ def run_coordinate_descent(
                         attempt + 1,
                     )
                 accepted = model is not None
+                update_evals = update_counts.get("fn_evals")
                 update.set(accepted=accepted, fn_evals=update_evals)
                 if accepted:
                     summed = new_summed
@@ -650,9 +662,26 @@ def run_coordinate_descent(
                     )
             if update_evals is not None:
                 fn_evals[cid] = fn_evals.get(cid, 0) + update_evals
-            if update_rejected is not None:
+            if "hv_evals" in update_counts:  # a fixed effect's TRON solve
+                solved = tron.setdefault(
+                    cid,
+                    {
+                        "accepted": 0,
+                        "rejected": 0,
+                        "hessian_vector_products": 0,
+                        "kernel": coord.hessian_vector_kernel,
+                    },
+                )
+                solved["accepted"] += update_counts["iterations"]
+                solved["rejected"] += update_counts["rejected"]
+                solved["hessian_vector_products"] += update_counts["hv_evals"]
+                set_stage_note("tron", dict(solved))
+            elif (
+                update_evals is not None
+                and coord.config.optimizer.optimizer_type != OptimizerType.TRON
+            ):
                 line_search_rejected[cid] = (
-                    line_search_rejected.get(cid, 0) + update_rejected
+                    line_search_rejected.get(cid, 0) + update_counts["rejected"]
                 )
             timing[f"{cid}/iter{it}"] = update.seconds
             telemetry.METRICS.observe("coordinate_update_s", update.seconds)
@@ -843,6 +872,7 @@ def run_coordinate_descent(
         diverged_steps=diverged_steps,
         fn_evals=fn_evals,
         line_search_rejected=line_search_rejected,
+        tron=tron,
         collective_bytes=collective_bytes,
         mesh_losses=mesh_losses,
         repeated_sweeps=repeated_sweeps,

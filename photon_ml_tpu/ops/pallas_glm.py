@@ -290,7 +290,13 @@ def kernels_healthy() -> bool:
             LOGISTIC, w, zero, X, y, off, wt, interpret=FORCE_INTERPRET,
             column_major=True,
         )
-        jax.block_until_ready((val, g, hv, val_bf, g_bf, val_cm, g_cm))
+        # and the Hessian-vector kernel in the form a TRON solve on such a
+        # matrix calls it: bf16-stored and column-major at once (d = 2,000).
+        hv_bf_cm, _ = hessian_vector_sums(
+            LOGISTIC, w, zero, w, zero, X.astype(jnp.bfloat16), y, off, wt,
+            interpret=FORCE_INTERPRET, column_major=True,
+        )
+        jax.block_until_ready((val, g, hv, val_bf, g_bf, val_cm, g_cm, hv_bf_cm))
     except Exception as exc:  # compile or runtime failure
         raise RuntimeError(
             f"pallas_glm kernels do not compile or run on the "
@@ -324,6 +330,9 @@ def kernels_healthy() -> bool:
         ),
         "gradient_bf16": bool(
             jnp.max(jnp.abs(g_bf - g_ref)) < 5e-2 * g_scale + 1e-2
+        ),
+        "hessian_vector_bf16_column_major": bool(
+            jnp.max(jnp.abs(hv_bf_cm - hv_ref)) < 5e-2 * hv_scale + 1e-2
         ),
         "value_column_major": bool(
             jnp.allclose(val_cm, val_ref, **PALLAS_GATE_TOLERANCES["f32"])

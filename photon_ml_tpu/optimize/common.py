@@ -59,6 +59,12 @@ class OptResult(NamedTuple):
     # products — each streams the design matrix once on the fused path, so
     # wall-clock / fn_evals is the per-pass cost.
     fn_evals: Optional[Array] = None
+    # TRON only (None from a line-search solve): the Hessian-vector products
+    # among `fn_evals`, one a CG iteration, rejected steps' included. A TRON
+    # solve evaluates value+gradient once before its loop and once a trial
+    # step, so `fn_evals == 1 + iterations + rejected steps + hv_evals` and
+    # the rejected steps need no count of their own.
+    hv_evals: Optional[Array] = None
     # (max_iterations + 1, D) per-iteration coefficient snapshots when
     # track_coefficients is requested (the reference OptimizationStatesTracker
     # keeps full OptimizerStates; here it is an opt-in fixed-size array).

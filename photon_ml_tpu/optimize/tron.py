@@ -60,8 +60,7 @@ class _CGCarry(NamedTuple):
     residual: Array
     direction: Array
     rtr: Array
-    iteration: Array
-    hvps: Array  # exact Hessian-vector products executed
+    iteration: Array  # CG iterations = Hessian-vector products executed
     done: Array
 
 
@@ -76,6 +75,10 @@ def _truncated_cg(
     TRON.truncatedConjugateGradientMethod (TRON.scala:278-338) including the
     boundary quadratic: when ||s + alpha*d|| crosses the trust radius, solve
     ||s + alpha*d||^2 = boundary^2 for the positive root.
+
+    One Hessian-vector product an iteration and none beside them: the
+    residual test that ends the loop is made before the product, as upstream
+    makes it, so `cg_iterations` is also the count of passes over the data.
     """
     tol = 0.1 * jnp.linalg.norm(gradient)
     init = _CGCarry(
@@ -84,16 +87,17 @@ def _truncated_cg(
         direction=-gradient,
         rtr=jnp.dot(gradient, gradient),
         iteration=jnp.zeros((), jnp.int32),
-        hvps=jnp.zeros((), jnp.int32),
         done=jnp.zeros((), bool),
     )
 
     def cond(c: _CGCarry) -> Array:
-        return (~c.done) & (c.iteration < MAX_CG_ITERATIONS)
+        return (
+            ~c.done
+            & (c.iteration < MAX_CG_ITERATIONS)
+            & (jnp.linalg.norm(c.residual) > tol)
+        )
 
     def body(c: _CGCarry) -> _CGCarry:
-        converged = jnp.linalg.norm(c.residual) <= tol
-
         hd = hvp(c.direction)
         alpha = safe_div(c.rtr, jnp.dot(c.direction, hd))
         step_try = c.step + alpha * c.direction
@@ -117,22 +121,17 @@ def _truncated_cg(
         beta = safe_div(rtr_new, c.rtr)
         dir_in = resid_in + beta * c.direction
 
-        active = ~converged
-        new_done = converged | (active & crossed)
-        sel = active & crossed
-
         return _CGCarry(
-            step=jnp.where(converged, c.step, jnp.where(sel, step_bound, step_try)),
-            residual=jnp.where(converged, c.residual, jnp.where(sel, resid_bound, resid_in)),
-            direction=jnp.where(sel | converged, c.direction, dir_in),
-            rtr=jnp.where(sel | converged, c.rtr, rtr_new),
-            iteration=jnp.where(converged, c.iteration, c.iteration + 1),
-            hvps=c.hvps + 1,
-            done=new_done,
+            step=jnp.where(crossed, step_bound, step_try),
+            residual=jnp.where(crossed, resid_bound, resid_in),
+            direction=jnp.where(crossed, c.direction, dir_in),
+            rtr=jnp.where(crossed, c.rtr, rtr_new),
+            iteration=c.iteration + 1,
+            done=crossed,
         )
 
     out = lax.while_loop(cond, body, init)
-    return out.hvps, out.step, out.residual
+    return out.iteration, out.step, out.residual
 
 
 class _Carry(NamedTuple):
@@ -149,8 +148,9 @@ class _Carry(NamedTuple):
     gnorm_history: Array
     coef_history: Array
     delta_history: Array  # trust radius per iteration (tracking only)
-    cg_history: Array  # CG Hessian-vector products per iteration (tracking)
-    evals: Array  # value/gradient evaluations + CG Hessian-vector products
+    cg_history: Array  # CG iterations per accepted iteration (tracking)
+    evals: Array  # passes over the data: value+gradient evaluations + hv_evals
+    hv_evals: Array  # Hessian-vector products, one a CG iteration
 
 
 @partial(
@@ -210,6 +210,7 @@ def minimize_tron(
         delta_history=delta_history,
         cg_history=cg_history,
         evals=jnp.ones((), jnp.int32),
+        hv_evals=jnp.zeros((), jnp.int32),
     )
 
     def cond(c: _Carry) -> Array:
@@ -306,6 +307,7 @@ def minimize_tron(
                 c.cg_history,
             ),
             evals=c.evals + hvp_calls + 1,
+            hv_evals=c.hv_evals + hvp_calls,
         )
 
     final = lax.while_loop(cond, body, init)
@@ -318,6 +320,7 @@ def minimize_tron(
         loss_history=final.loss_history,
         gradient_norm_history=final.gnorm_history,
         fn_evals=final.evals,
+        hv_evals=final.hv_evals,
         coefficients_history=final.coef_history if final.coef_history.shape[0] else None,
         trust_radius_history=final.delta_history if final.delta_history.shape[0] else None,
         cg_iterations_history=final.cg_history if final.cg_history.shape[0] else None,

@@ -104,6 +104,10 @@ FIT_TIMING_REQUIRED_KEYS = (
     # ISSUE 30: line-search trials per coordinate that failed the Armijo
     # test (L-BFGS evaluations beyond the first and one an iteration).
     "line_search_rejected",
+    # ISSUE 40: per fixed effect solved with TRON, the Hessian-vector
+    # products among its `fn_evals`; empty where no fixed effect solves
+    # with TRON.
+    "hv_evals",
     # ISSUE 36: bytes each device added to the all-reduce of a
     # sample-sharded fixed effect's value and gradient, per coordinate
     # (evaluations x 4 (d + 1)); empty on one device.

@@ -191,6 +191,14 @@ METRIC_DESCRIPTIONS = {
     "line_search_rejected_trials": "line-search trials that failed the "
     "Armijo test, per coordinate (labeled coordinate=<id>,"
     "kind=fixed|random; L-BFGS, OWL-QN and box solves)",
+    # What a fixed effect's TRON solves did beyond `objective_evaluations`
+    # (which counts every pass over the data, these products included):
+    # OptResult.hv_evals. Added once a fit; absent where no fixed effect
+    # solves with TRON. The trial steps the trust region refused are in the
+    # stage note `tron` (run_profile()["dispatch"]), not a counter.
+    "hessian_vector_products": "Hessian-vector products a fixed effect's "
+    "TRON solves made, one a CG iteration (labeled coordinate=<id>,"
+    "kind=fixed|random)",
     # A sample-sharded fixed effect (parallel/mesh.sample_sharded_dataset)
     # sums its objective over the mesh once an evaluation: evaluations x
     # 4 (d + 1) bytes from each device, added once a fit. Absent on one device.

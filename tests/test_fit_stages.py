@@ -212,12 +212,26 @@ class TestRejectedTrialsRideTheOneFetch:
             lambda tree: fetched.append(len(tree)) or device_get(tree),
         )
         cd = run_coordinate_descent({"global": coord}, 1)
-        assert fetched == [3]  # ok, fn_evals, iterations: one fetch an update
         if optimizer == OptimizerType.TRON:
+            # ok, fn_evals, iterations, hv_evals: still one fetch an update
+            assert fetched == [4]
             assert cd.line_search_rejected == {}
+            hv = int(res.hv_evals)
+            rejected = int(res.fn_evals) - 1 - int(res.iterations) - hv
+            assert cd.tron == {
+                "global": {
+                    "accepted": int(res.iterations),
+                    "rejected": rejected,
+                    "hessian_vector_products": hv,
+                    "kernel": "xla",
+                }
+            }
+            assert hv >= int(res.iterations) > 0 and rejected >= 0
         else:
+            assert fetched == [3]  # ok, fn_evals, iterations
             rejected = int(res.fn_evals) - 1 - int(res.iterations)
             assert cd.line_search_rejected == {"global": rejected}
+            assert cd.tron == {} and res.hv_evals is None
             assert rejected >= 0
 
     def test_random_effect_count_is_by_solve(self):

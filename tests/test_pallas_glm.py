@@ -249,3 +249,33 @@ def test_health_probe_checks_numerics(rng, monkeypatch):
     monkeypatch.setattr(pallas_glm, "value_gradient_sums", wrong)
     with pytest.raises(RuntimeError, match="disagree.*value"):
         pallas_glm.should_use(big, w)
+
+@pytest.mark.parametrize("fault", ["refused", "wrong"])
+def test_health_probe_covers_the_hessian_vector_form_a_tron_fit_calls(monkeypatch, fault):
+    """ISSUE 40: a TRON solve on a bf16-stored matrix that lies column-major
+    calls `hessian_vector_sums` in that form, which the probe ran only
+    row-major in float32: a Mosaic refusal (or a wrong sum) of that form has
+    to stop the job at the dispatch decision, not in the first TRON fit."""
+    big = jnp.zeros((4096, 256), jnp.float32)
+    w = jnp.zeros((256,), jnp.float32)
+    monkeypatch.setattr(pallas_glm, "FORCE_INTERPRET", True)
+    monkeypatch.setattr(pallas_glm, "_HEALTHY", False)
+
+    real, forms = pallas_glm.hessian_vector_sums, []
+
+    def probed(loss, w_eff, shift, v_eff, v_shift, features, *rest, **kw):
+        form = (jnp.dtype(features.dtype).name, bool(kw.get("column_major")))
+        forms.append(form)
+        if form == ("bfloat16", True):
+            if fault == "refused":
+                raise RuntimeError("mosaic refuses bf16 X^T blocks")
+            hv, sum_r = real(loss, w_eff, shift, v_eff, v_shift, features, *rest, **kw)
+            return hv * 3.0, sum_r
+        return real(loss, w_eff, shift, v_eff, v_shift, features, *rest, **kw)
+
+    monkeypatch.setattr(pallas_glm, "hessian_vector_sums", probed)
+    match = "mosaic refuses" if fault == "refused" else "disagree.*hessian_vector_bf16_column_major"
+    with pytest.raises(RuntimeError, match=match):
+        pallas_glm.should_use(big, w)
+    assert ("float32", False) in forms and ("bfloat16", True) in forms
+    assert pallas_glm._HEALTHY is False

@@ -536,6 +536,48 @@ def test_the_dense_solve_holds_no_padded_column_at_the_epsilon_shape(one_chip):
     assert len(reshapes) <= 6 and all(i.elements == i.minor == EPSILON_N for i in reshapes), reshapes
 
 
+def test_the_tron_solve_runs_both_kernels_as_x_lies_at_the_epsilon_shape(one_chip):
+    """`lr-epsilon-tron.fit`'s solve (ISSUE 40): the Hessian-vector kernel in
+    the CG loop of the trust-region loop, the value+gradient kernel before the
+    loops and once a trial step, both reading the column-major bfloat16 matrix
+    as (d, tile) blocks of X^T: the chip's compiler takes the program, and
+    nothing in it relays the matrix."""
+    from photon_ml_tpu.data.containers import LabeledData
+    from photon_ml_tpu.ops import objective
+    from photon_ml_tpu.optimize.tron import minimize_tron
+
+    shape = (EPSILON_N, EPSILON_D)
+
+    def solve(features, labels, offsets, weights, w0):
+        data = LabeledData(features, labels, offsets, weights, column_major=True)
+        return minimize_tron(
+            lambda w: objective.value_and_gradient(LOGISTIC, w, data, None, 1.0, use_pallas=True),
+            lambda w, v: objective.hessian_vector(LOGISTIC, w, v, data, None, 1.0, use_pallas=True),
+            w0, max_iterations=15, tolerance=1e-5,
+        ).coefficients
+
+    rows = _vec(one_chip, EPSILON_N)
+    instructions = _array_instructions(_compiled_text(jax.jit(solve).lower(
+        jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip),
+        rows, rows, rows, _vec(one_chip, EPSILON_D),
+    )))
+
+    def depths(kernel):
+        return {
+            i.op_name.count("while/body") for i in instructions.values()
+            if i.opcode == "get-tuple-element" and i.op_name.endswith(f"{kernel}/pallas_call")
+        }
+
+    assert depths("value_gradient_sums") == {0, 1}
+    assert depths("hessian_vector_sums") == {2}
+    (x,) = [
+        i for i in instructions.values()
+        if i.opcode == "parameter" and i.elements == EPSILON_N * EPSILON_D
+    ]
+    assert x.layout.startswith("{0,1:"), x.layout
+    assert not _whole_matrix_relayouts(instructions, shape)
+
+
 # The observation `column_major` rests on (PR 37): the compiler's DEFAULT
 # layout for a 2-D array follows its shape. Where the feature width is no
 # multiple of 128 and the row count is, column-major pads nothing and
