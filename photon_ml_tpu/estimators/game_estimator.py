@@ -718,7 +718,9 @@ class GameEstimator:
         # fit's SOLVE_STAGES walls: `prepare_s` of `fit/revalidate`,
         # `fit/validation_prep` and every configuration's
         # `fit/coordinates`, `solve_s` of every `fit/descent` and
-        # `fit/final_evaluate`. `prepare_s` additionally splits into the
+        # `fit/final_evaluate` (which takes the descent's own last
+        # validation and evaluates only where there is none).
+        # `prepare_s` additionally splits into the
         # PREPARE_STAGES keys (+ `other`, the residual glue) recorded by
         # the data-plane functions themselves.
         stage_base = dict(self.timing_registry.sections)
@@ -864,11 +866,20 @@ class GameEstimator:
                         else None
                     ),
                 )
+            # The descent's last validation is the returned model's own
+            # evaluation wherever it made one; only a model it never
+            # evaluated (a finished checkpoint resumed, every update
+            # rejected, a mesh-loss rollback) is scored and evaluated here.
             evaluation = None
             with stage_timer("fit/final_evaluate") as final_evaluate:
                 if validation_data is not None and suite is not None:
-                    transformer = self._make_transformer(cd.model)
-                    evaluation = transformer.evaluate(validation_data, suite, val_prep)
+                    evaluation = cd.evaluation
+                    final_evaluate.set(reused=evaluation is not None)
+                    if evaluation is None:
+                        transformer = self._make_transformer(cd.model)
+                        evaluation = transformer.evaluate(
+                            validation_data, suite, val_prep
+                        )
             results.append(
                 GameResult(
                     model=cd.model,

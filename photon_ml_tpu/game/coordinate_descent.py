@@ -247,6 +247,13 @@ class CoordinateDescentResult:
     # mesh, so a clean run reports 0/0 and a single loss reports 1/1.
     mesh_losses: int = 0
     repeated_sweeps: int = 0
+    # The validation results of `model` itself, as this call computed them:
+    # the last validation, taken with every coordinate of `model` scored. It
+    # outlives a rejected update (the models did not change) and not a
+    # mesh-loss rollback (they did, unevaluated). None where this call
+    # evaluated nothing: no validation, a resume with no step left, every
+    # update rejected.
+    evaluation: Optional[EvaluationResults] = None
 
 
 def run_coordinate_descent(
@@ -504,6 +511,8 @@ def run_coordinate_descent(
         validation_history[-1][2] if validation_history else None
     )
     last_unlocked = unlocked[-1]
+    # What CoordinateDescentResult.evaluation will hold.
+    model_evaluation: Optional[EvaluationResults] = None
     mesh_losses = 0
     repeated_sweeps = 0
     it = 0
@@ -736,6 +745,11 @@ def run_coordinate_descent(
                 validation_history.append((it, cid, results))
                 logger.info("validation after %s: %s", cid, results.results)
                 pass_results = results
+                # A model handed in for no coordinate of this call is in
+                # `models` and in no sum of scores.
+                model_evaluation = (
+                    results if val_scores.keys() == models.keys() else None
+                )
 
             # Best-model selection happens on full passes only, when every
             # coordinate's model exists (CoordinateDescent.scala:499-652) —
@@ -794,6 +808,7 @@ def run_coordinate_descent(
                 task=next(iter(coordinates.values())).task,
                 completed_steps=completed_steps,
             )
+            model_evaluation = None  # the models rolled back, unevaluated
             if source == "memory":
                 # The rolled-back sweep replays in full, so its counter
                 # increments recur deterministically — restore to the
@@ -876,4 +891,5 @@ def run_coordinate_descent(
         collective_bytes=collective_bytes,
         mesh_losses=mesh_losses,
         repeated_sweeps=repeated_sweeps,
+        evaluation=model_evaluation,
     )

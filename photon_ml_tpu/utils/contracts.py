@@ -34,10 +34,15 @@ PREPARE_STAGES = ("re_build", "projector", "stats", "pack", "upload", "compile")
 # `cd/validation_score` are DISPATCH walls (the host handing programs to the
 # device; a random-effect `cd/train` also waits once, for its solves'
 # counts), and the host WAITS for the device in `cd/commit` (the divergence
-# guard's fetch), in `cd/validation_evaluate` and in `fit/final_evaluate`
-# (one evaluation program handed over, then the fetch of its metrics: the
-# wall is the device's scoring and evaluation work plus one round trip). A
-# stage that did not run in a fit reads 0.0.
+# guard's fetch) and in `cd/validation_evaluate` (one evaluation program
+# handed over, then the fetch of its metrics: the wall is the device's
+# scoring and evaluation work plus one round trip). `fit/final_evaluate`
+# takes the descent's last validation as the returned model's evaluation
+# (`CoordinateDescentResult.evaluation`; microseconds, no device work) and
+# scores, evaluates and waits as `cd/validation_evaluate` does only where
+# the descent evaluated nothing (a finished checkpoint resumed, every update
+# rejected, a mesh-loss rollback). A stage that did not run in a fit reads
+# 0.0.
 SOLVE_STAGES = (
     "fit",
     "fit/revalidate",

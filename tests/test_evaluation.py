@@ -364,8 +364,8 @@ def test_second_fit_does_not_trace_the_evaluation_again(rng):
     calls1, traces1 = _evaluation_counts()
     second = est.fit(train, val, [cfg])
     calls2, traces2 = _evaluation_counts()
-    # One evaluation inside coordinate descent and the final one, each fit.
-    assert calls1 - calls0 == 2 and calls2 - calls1 == 2
+    # The one inside coordinate descent, which is also the final one (ISSUE 41).
+    assert calls1 - calls0 == 1 and calls2 - calls1 == 1
     # 0 where an earlier test of the process evaluated AUC over 150 rows.
     assert traces1 - traces0 <= 1
     assert traces2 - traces1 == 0

@@ -2,6 +2,7 @@
 three sinks, stages at every boundary of a fit, objective evaluations counted
 where they happen, and the program's spans on the profiler's clock."""
 
+import dataclasses
 import glob
 import os
 import time
@@ -29,7 +30,7 @@ from photon_ml_tpu.optimize.config import (
     OptimizerConfig,
 )
 from photon_ml_tpu.types import OptimizerType, TaskType
-from photon_ml_tpu.utils import telemetry
+from photon_ml_tpu.utils import faults, telemetry
 from photon_ml_tpu.utils.contracts import (
     PREPARE_STAGES,
     SOLVE_STAGE_PARENT,
@@ -152,6 +153,182 @@ class TestStagesOfAFit:
             "coordinate=per-e,kind=random": sum(e["per-e"] for e in evals),
         }
         assert evals[0] == evals[1] == evals[2]  # the same fit three times
+
+
+@dataclasses.dataclass(frozen=True)
+class _ValidatedFit:
+    """One fit, and what its final evaluation must be made of."""
+
+    coordinates: tuple = ("global",)
+    passes: int = 1
+    configurations: int = 1
+    validated: bool = True
+    locked: tuple = ()
+    warm_start: bool = False  # an earlier fit's model handed in
+    fault: str = ""  # a `faults.inject` plan armed round the fit
+    resumed: bool = False  # the fit's checkpoint is an earlier, finished fit's
+    evaluations: int = 1  # `evaluation_calls` the fit adds
+    reused: tuple = (True,)  # a configuration: the descent's result, or the fallback's
+    diverged_steps: int = 0
+
+
+_VALIDATED_FITS = {
+    "one_fixed_effect_one_pass": _ValidatedFit(),
+    # An evaluation after each of the four updates, and no fifth.
+    "fixed_and_random_two_passes": _ValidatedFit(
+        coordinates=("global", "per-e"), passes=2, evaluations=4
+    ),
+    "locked_coordinate_and_warm_start": _ValidatedFit(
+        coordinates=("global", "per-e"), locked=("global",), warm_start=True
+    ),
+    "two_configurations": _ValidatedFit(
+        configurations=2, evaluations=2, reused=(True, True)
+    ),
+    # Both attempts of the only update are refused: the descent hands back
+    # the warm start, which it never evaluated.
+    "every_attempt_rejected": _ValidatedFit(
+        warm_start=True, fault="solve@1+2", reused=(False,), diverged_steps=2
+    ),
+    # The second pass loses the mesh, rolls back and is then refused: the
+    # first pass's validation is not handed on across the rollback.
+    "mesh_lost_and_the_replay_rejected": _ValidatedFit(
+        passes=2, fault="mesh_loss@2,solve@2+3", evaluations=2, reused=(False,),
+        diverged_steps=2,
+    ),
+    "resumed_from_a_finished_checkpoint": _ValidatedFit(resumed=True, reused=(False,)),
+    "no_validation_data": _ValidatedFit(validated=False, evaluations=0),
+}
+
+
+class TestAValidatedModelIsEvaluatedOnce:
+    """ISSUE 41: `fit/final_evaluate` hands back the descent's last
+    validation wherever that was made on the models the descent returns,
+    and scores and evaluates only where there is none."""
+
+    @staticmethod
+    def _estimator(case, **kwargs):
+        data_configs = {
+            "global": FixedEffectDataConfig("g"),
+            "per-e": RandomEffectDataConfig("e", "g", min_bucket=8),
+        }
+        return GameEstimator(
+            TASK,
+            {cid: data_configs[cid] for cid in case.coordinates},
+            coordinate_descent_iterations=case.passes,
+            validation_evaluators=[EvaluatorType.parse("AUC")],
+            pipeline=False,
+            **kwargs,
+        )
+
+    @pytest.mark.parametrize("name", list(_VALIDATED_FITS))
+    def test_final_evaluation_is_the_descents_last_validation(
+        self, name, monkeypatch, tmp_path
+    ):
+        from photon_ml_tpu.estimators import game_estimator
+
+        case = _VALIDATED_FITS[name]
+        train, val = _glmix(n=1500, n_val=400)
+
+        def configuration(ci, coordinates):
+            return {
+                cid: CoordinateOptimizationConfig(
+                    optimizer=OptimizerConfig(max_iterations=3), reg_weight=1.0 + ci
+                )
+                for cid in coordinates
+            }
+
+        kwargs = {}
+        if case.resumed:
+            kwargs["checkpoint_dir"] = str(tmp_path / "ckpt")
+        earlier = None
+        if case.warm_start or case.resumed:
+            earlier = self._estimator(case, **kwargs).fit(
+                train, val, [configuration(0, case.coordinates)]
+            )[0]
+        trained = [c for c in case.coordinates if c not in case.locked]
+        cfgs = [configuration(ci, trained) for ci in range(case.configurations)]
+        est = self._estimator(case, locked_coordinates=set(case.locked), **kwargs)
+
+        descents = []
+        descend = game_estimator.run_coordinate_descent
+        monkeypatch.setattr(
+            game_estimator,
+            "run_coordinate_descent",
+            lambda *a, **k: descents.append(descend(*a, **k)) or descents[-1],
+        )
+        tracer = telemetry.install_tracer(telemetry.Tracer())
+        calls = telemetry.METRICS.get_counter("evaluation_calls")
+        try:
+            with faults.inject(case.fault):
+                results = est.fit(
+                    train,
+                    val if case.validated else None,
+                    cfgs,
+                    initial_model=earlier.model if case.warm_start else None,
+                )
+        finally:
+            telemetry.uninstall_tracer()
+        calls = telemetry.METRICS.get_counter("evaluation_calls") - calls
+        assert calls == case.evaluations
+        assert len(results) == len(descents) == case.configurations
+        stages = est.fit_timing["stages_s"]
+        assert stages["fit/final_evaluate"] > 0.0  # the stage is still a stage
+        assert est.fit_timing["solve_s"] == pytest.approx(
+            stages["fit/descent"] + stages["fit/final_evaluate"]
+        )
+        if not case.validated:
+            assert [r.evaluation for r in results] == [None]
+            assert descents[0].evaluation is None
+            assert descents[0].validation_history == []
+            return
+
+        final_spans = [s for s in tracer.spans() if s["name"] == "fit/final_evaluate"]
+        assert [s["args"]["reused"] for s in final_spans] == list(case.reused)
+        suite = est._validation_suite(val)
+        for result, cd, reused in zip(results, descents, case.reused):
+            assert list(result.evaluation.results) == ["AUC"]
+            if reused:
+                # The object best-model selection compared, not a copy of it.
+                assert result.evaluation is cd.evaluation is cd.validation_history[-1][2]
+            else:
+                assert cd.evaluation is None
+            # What `transformer.evaluate` reads on the returned model: the
+            # same scores, offsets and program. With two coordinates the
+            # descent sums the scores in its own order and `transform` in
+            # the model's; on these rows the two AUCs came out equal to the
+            # last bit all the same.
+            again = est._make_transformer(result.model).evaluate(val, suite)
+            assert again.results == result.evaluation.results
+        assert est.fit_timing["diverged_steps"] == case.diverged_steps
+        assert descents[0].mesh_losses == case.fault.count("mesh_loss")
+        if earlier is not None and not any(case.reused):
+            # Nothing was trained: the earlier fit's model, evaluated anew.
+            assert results[0].evaluation.results == earlier.evaluation.results
+        if case.resumed:
+            assert descents[0].timing == {}  # every step was already done
+
+    def test_a_model_of_no_coordinate_of_the_call_voids_the_result(self):
+        """The validation sums the scores of the call's coordinates; a
+        model handed in for another is in the returned `GameModel` and in
+        no sum, so the last validation is not that model's evaluation."""
+        from photon_ml_tpu.evaluation.suite import EvaluationSuite
+        from photon_ml_tpu.game.model import GameModel
+
+        train, _ = _glmix(n=1500, n_val=10)
+        cfg = CoordinateOptimizationConfig(optimizer=OptimizerConfig(max_iterations=3))
+        coord = FixedEffectCoordinate(train, "g", cfg, TASK)
+        validated = dict(
+            validation_scorer=lambda cid, model: coord.score(model),
+            validation_suite=EvaluationSuite([EvaluatorType.parse("AUC")], train.labels),
+        )
+        own = run_coordinate_descent({"global": coord}, 1, **validated)
+        assert own.evaluation is own.validation_history[-1][2]
+        stranger = GameModel({"elsewhere": own.model["global"]})
+        cd = run_coordinate_descent(
+            {"global": coord}, 1, initial_models=stranger, **validated
+        )
+        assert set(cd.model.coordinate_ids) == {"global", "elsewhere"}
+        assert len(cd.validation_history) == 1 and cd.evaluation is None
 
 
 class TestEvaluationsCountedWhereTheyHappen:
