@@ -25,6 +25,11 @@ matrix: a weekday, a device type, the intercept) need not be gathered at all:
 arrays, and `matvec` multiplies such a plane against that span of the
 coefficients by compare, select and reduce on the vector unit ("dense span",
 below); the wide planes keep the gather, and every plane the scatter-add.
+
+A random-effect bucket's block needs neither where its entities' projected
+space is narrow: `dense_block` lays the bucket's (E, K, S) ELL block out
+once as a `DenseBlock`, X^T of shape (E, D, S), and each product of the
+solve then reads it once ("dense blocks", below).
 """
 
 from __future__ import annotations
@@ -50,6 +55,20 @@ DENSE_SPAN_LIMIT = 2048
 # here to the limit, so that a field whose rarest ids a seed may or may not
 # draw still compiles one program.
 DENSE_SPAN_FLOOR = 128
+
+# Dense blocks. A random-effect bucket's rows are solved as a `DenseBlock`
+# (X^T, (E, D, S)) where two things hold of its static shapes, and as its ELL
+# block (E, K, S) otherwise (`dense_block_admitted`). Derived, not tuned:
+# (a) the dense block fits: E * D * S values as the chip lays them (S on the
+#     128 lanes, D on the 8 sublanes) take at most a quarter of one v5e's
+#     16 GiB, beside the solve's other buffers and the shard it gathers from;
+# (b) a dense row costs less HBM time than the entries it replaces: a gathered
+#     ELL entry took ~8 ns on a v5e (540 plane gathers of 2,097,152 entries in
+#     9.24 s, PERF.md section 5), ~5.9 KB of HBM time at the 739 GB/s the
+#     dense kernels reach, where a dense row costs 4 D bytes. D <= 128 K keeps
+#     a tenfold margin.
+DENSE_BLOCK_MAX_BYTES = 4 << 30
+DENSE_BLOCK_IDS_PER_PLANE = 128
 
 
 @dataclasses.dataclass(frozen=True)
@@ -311,7 +330,72 @@ def span_note(features: SparseFeatures) -> dict:
     }
 
 
-Features = Union[Array, SparseFeatures]
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class DenseBlock:
+    """A bucket's entity rows laid dense and transposed: `xt[..., d, s]` is
+    row s's value of feature d, the rows (S, the bucket's capacity) on the
+    minor axis, so that D pads only to a multiple of 8. Built from an ELL
+    block by `dense_block`; under `vmap` one entity's (D, S).
+
+    The products read the block once each and contract it by multiply and
+    reduce in the values' dtype, never by a `dot_general`, whose default
+    precision on the TPU rounds float32 operands to bfloat16."""
+
+    xt: Array  # (..., D, S)
+
+    @property
+    def dim(self) -> int:
+        return self.xt.shape[-2]
+
+    def matvec(self, w: Array) -> Array:
+        """z[s] = sum_d xt[d, s] w[d]."""
+        return jnp.sum(self.xt * w[..., :, None], axis=-2)
+
+    def rmatvec(self, u: Array) -> Array:
+        """g[d] = sum_s xt[d, s] u[s]."""
+        return jnp.sum(self.xt * u[..., None, :], axis=-1)
+
+    def sq_rmatvec(self, u: Array) -> Array:
+        """sum_s xt[d, s]^2 u[s] (Hessian diagonals)."""
+        return jnp.sum(jnp.square(self.xt) * u[..., None, :], axis=-1)
+
+    def to_dense(self) -> Array:
+        """(..., S, D): the rows as a plain matrix."""
+        return jnp.swapaxes(self.xt, -1, -2)
+
+
+def dense_block_bytes(entities: int, dim: int, capacity: int) -> int:
+    """What a float32 (entities, dim, capacity) `DenseBlock` takes as the chip
+    lays it: dim padded to the 8 sublanes, capacity to the 128 lanes."""
+    return entities * -(-dim // 8) * 8 * -(-capacity // 128) * 128 * 4
+
+
+def dense_block_admitted(entities: int, planes: int, capacity: int, dim: int) -> bool:
+    """Whether a bucket of `entities` blocks of `capacity` rows, `planes` ELL
+    planes over `dim` features, is solved as a `DenseBlock`: from static
+    shapes alone (DENSE_BLOCK_MAX_BYTES, DENSE_BLOCK_IDS_PER_PLANE say why)."""
+    return (
+        dense_block_bytes(entities, dim, capacity) <= DENSE_BLOCK_MAX_BYTES
+        and dim <= DENSE_BLOCK_IDS_PER_PLANE * planes
+    )
+
+
+def dense_block(features: SparseFeatures) -> DenseBlock:
+    """X^T of an ELL block: `xt[..., d, s] = sum_k where(idx[k, ..., s] == d,
+    val[k, ..., s], 0)`, one elementwise fusion over an iota of the features,
+    no scatter. Exact: non-padding ids are unique within a row, so each
+    output holds at most one non-zero term, and a padding slot adds 0.0."""
+    idx, val = features._planes()
+    ids = jnp.arange(features.dim, dtype=jnp.int32)[:, None]
+    xt = jnp.zeros((*idx.shape[1:-1], features.dim, idx.shape[-1]), val.dtype)
+    for k in range(idx.shape[0]):
+        hit = idx[k].astype(jnp.int32)[..., None, :] == ids
+        xt = xt + jnp.where(hit, val[k][..., None, :], 0)
+    return DenseBlock(xt)
+
+
+Features = Union[Array, SparseFeatures, DenseBlock]
 
 
 @jax.tree_util.register_dataclass

@@ -45,10 +45,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from photon_ml_tpu.data.containers import (
+    DenseBlock,
     Features,
     LabeledData,
     SparseFeatures,
     annotate_spans,
+    dense_block,
+    dense_block_admitted,
+    dense_block_bytes,
 )
 from photon_ml_tpu.types import ProjectorType
 from photon_ml_tpu.utils import faults
@@ -876,6 +880,37 @@ def gather_block_features(features: Features, gather: Array) -> Features:
     return jnp.take(features, gather, axis=0)
 
 
+def block_layout(features: Features, entities: int, capacity: int) -> str:
+    """How the solves of a bucket of `entities` blocks of `capacity` rows
+    read them: "dense" (a dense shard's (E, S, D) rows, or an ELL shard's
+    laid as a `DenseBlock` where `dense_block_admitted`) or "sparse" (the ELL
+    block, (E, K, S)). From static shapes alone, so the scan sweep, the
+    bucket loop and a count on the host decide alike."""
+    if not isinstance(features, SparseFeatures):
+        return "dense"
+    planes = features.indices.shape[features.ell_axis]
+    admitted = dense_block_admitted(entities, planes, capacity, features.dim)
+    return "dense" if admitted else "sparse"
+
+
+def block_layout_note(features: Features, buckets: Sequence["EntityBlocks"]) -> dict:
+    """A random effect's buckets by layout, for the run profile (stage note
+    `re_block_layout`): each layout's bucket count, the distinct (entities,
+    capacity, layout) shapes, and the bytes of its widest `DenseBlock` (0
+    where no ELL bucket is laid dense)."""
+    layouts = [block_layout(features, b.num_entities, b.capacity) for b in buckets]
+    shapes = sorted({(b.num_entities, b.capacity, lay) for b, lay in zip(buckets, layouts)})
+    widest = 0
+    if isinstance(features, SparseFeatures):
+        dense = [(e, s) for e, s, lay in shapes if lay == "dense"]
+        widest = max((dense_block_bytes(e, features.dim, s) for e, s in dense), default=0)
+    return {
+        "layout": {lay: layouts.count(lay) for lay in sorted(set(layouts))},
+        "shapes": [list(shape) for shape in shapes],
+        "dense_bytes": widest,
+    }
+
+
 def gather_block_arrays(
     features: Features,
     labels: Array,
@@ -889,11 +924,20 @@ def gather_block_arrays(
     """Array-level core of `gather_block_data`: build one bucket's
     (E, S, ...) LabeledData from raw (possibly traced) arrays. Trace-safe —
     the scan-dispatched sweep (game/coordinate.py) runs it INSIDE its scan
-    body, so both code paths share one definition and cannot drift."""
+    body, so both code paths share one definition and cannot drift.
+
+    An ELL bucket whose shapes admit it (`block_layout`) is laid dense
+    here, once a bucket: the solve's every pass then reads the (E, D, S)
+    block once, where the ELL block costs a gather (or a scatter-add) a
+    plane."""
     feats = gather_block_features(features, gather)
+    if isinstance(feats, SparseFeatures) and block_layout(features, *gather.shape) == "dense":
+        feats = dense_block(feats)
     if feature_mask is not None:
         block_mask = jnp.take(feature_mask, ent_rows, axis=0)  # (E, D)
-        if isinstance(feats, SparseFeatures):
+        if isinstance(feats, DenseBlock):
+            feats = DenseBlock(feats.xt * block_mask[..., None])
+        elif isinstance(feats, SparseFeatures):
             mult = jax.vmap(lambda m, idx: m[idx])(block_mask, feats.indices)
             feats = dataclasses.replace(feats, values=feats.values * mult)
         else:

@@ -658,6 +658,8 @@ class GameEstimator:
         `line_search_rejected_trials` and `hessian_vector_products`
         `{coordinate=<id>,kind=fixed|random}`,
         `random_effect_lane_passes{coordinate=<id>,kind=used|paid}`,
+        `random_effect_blocks{coordinate=<id>,layout=dense|sparse}` (a
+        random effect's buckets by the layout its solves read them in),
         `gradient_allreduce_bytes{coordinate=<id>}` — so a reader that sees only
         the process gets a window's totals as the process total less the
         fits made before it — and the gauge
@@ -690,6 +692,12 @@ class GameEstimator:
                 telemetry.METRICS.increment(
                     "random_effect_lane_passes", n,
                     labels=(("coordinate", cid), ("kind", kind)),
+                )
+        for cid, note in (self.timing_registry.get_note("re_block_layout") or {}).items():
+            for layout, n in note["layout"].items():
+                telemetry.METRICS.increment(
+                    "random_effect_blocks", n,
+                    labels=(("coordinate", cid), ("layout", layout)),
                 )
         for cid, nbytes in self.fit_timing["gradient_allreduce_bytes"].items():
             telemetry.METRICS.increment(
@@ -749,7 +757,7 @@ class GameEstimator:
         self.timing_registry.clear_notes(
             "pack_path", "re_path", "sparse_layout", "sparse_objective",
             "pack_declined", "sample_sharding", "dense_storage",
-            "ell_planes", "ell_planes_scored", "tron",
+            "ell_planes", "ell_planes_scored", "tron", "re_block_layout",
         )
         # Snapshot the pod-scale robustness counters so fit_timing reports
         # THIS fit's events (the process-wide counters are cumulative).
@@ -828,6 +836,16 @@ class GameEstimator:
                     info = getattr(coord, "sharding_info", None)
                     if info is not None:
                         sharding_infos[cid] = info()
+                # How each random effect's solves read its buckets, dense or
+                # ELL (data/game_dataset.block_layout): a property of the
+                # bucket shapes, as the sharding is.
+                layouts = {
+                    cid: coord.block_layout
+                    for cid, coord in coordinates.items()
+                    if getattr(coord, "block_layout", None) is not None
+                }
+                if layouts:
+                    self.timing_registry.set_note("re_block_layout", layouts)
             if ci == 0:
                 # Every fixed-effect coordinate that wanted the ingest's
                 # host-COO stash has consumed it by now (its pack decision
@@ -1317,6 +1335,13 @@ class GameEstimator:
             # its updates (CoordinateDescentResult.tron); the refused steps
             # are reported here and nowhere else.
             "tron": self.timing_registry.get_note("tron") or "none",
+            # Per random effect, {layout, shapes, dense_bytes}: how many of
+            # its buckets its solves read as a dense (E, D, S) block and how
+            # many as ELL planes (data/game_dataset.block_layout), its
+            # distinct (entities, capacity, layout) shapes, and the bytes of
+            # its widest dense block. "none" where no random effect was built.
+            "re_block_layout": self.timing_registry.get_note("re_block_layout")
+            or "none",
         }
         bucket_shapes: Dict[str, object] = {}
         for cid, prep in (self._prepared or {}).items():

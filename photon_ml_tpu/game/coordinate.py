@@ -56,6 +56,7 @@ from photon_ml_tpu.data.containers import LabeledData, SparseFeatures, span_note
 from photon_ml_tpu.data.game_dataset import (
     GameDataset,
     RandomEffectDataset,
+    block_layout_note,
     gather_block_data,
 )
 from photon_ml_tpu.data.sampling import down_sample_weights, down_sampler_for_task
@@ -582,6 +583,10 @@ class RandomEffectCoordinate:
             else dataset.shards[re_dataset.feature_shard]
         )
         self.dim = feats.dim if isinstance(feats, SparseFeatures) else feats.shape[-1]
+        # How the solves read each bucket, dense or ELL, decided from its
+        # shapes where the block is built (gather_block_arrays): the stage
+        # note `re_block_layout` and the counter `random_effect_blocks`.
+        self.block_layout = block_layout_note(feats, re_dataset.buckets)
         # Entity-sharded coefficient store: when the RE dataset's entity
         # blocks are sharded over a mesh, the (E+1, D) matrix is row-sharded
         # over the same axis and accessed through ring collectives

@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from photon_ml_tpu.data.bucketed import BucketedSparseFeatures
-from photon_ml_tpu.data.containers import LabeledData, SparseFeatures
+from photon_ml_tpu.data.containers import DenseBlock, LabeledData, SparseFeatures
 from photon_ml_tpu.ops import pallas_glm, pallas_sparse
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.ops.normalization import NormalizationContext
@@ -68,7 +68,7 @@ def _matvec(features, w_eff: Array) -> Array:
                 features, w_eff, interpret=pallas_glm.FORCE_INTERPRET
             )
         return pallas_sparse.matvec_xla(features, w_eff)
-    if isinstance(features, SparseFeatures):
+    if isinstance(features, (SparseFeatures, DenseBlock)):
         return features.matvec(w_eff)
     return features @ w_eff
 
@@ -80,7 +80,7 @@ def _rmatvec(features, u: Array) -> Array:
                 features, u, interpret=pallas_glm.FORCE_INTERPRET
             )
         return pallas_sparse.rmatvec_xla(features, u)
-    if isinstance(features, SparseFeatures):
+    if isinstance(features, (SparseFeatures, DenseBlock)):
         return features.rmatvec(u)
     return u @ features
 
@@ -93,7 +93,7 @@ def _sq_rmatvec(features, u: Array) -> Array:
                 features, u, interpret=pallas_glm.FORCE_INTERPRET, square=True
             )
         return pallas_sparse.rmatvec_xla(features, u, square=True)
-    if isinstance(features, SparseFeatures):
+    if isinstance(features, (SparseFeatures, DenseBlock)):
         return features.sq_rmatvec(u)
     return u @ jnp.square(features)
 
@@ -362,7 +362,7 @@ def hessian_matrix(
     feats = data.features
     if isinstance(feats, BucketedSparseFeatures):
         X = pallas_sparse.to_dense_xla(feats)
-    elif isinstance(feats, SparseFeatures):
+    elif isinstance(feats, (SparseFeatures, DenseBlock)):
         X = feats.to_dense()
     else:
         X = feats

@@ -208,6 +208,12 @@ METRIC_DESCRIPTIONS = {
     "random_effect_lane_passes": "passes over the data a random effect's "
     "TRON sweep needed (kind=used) and its batched loops executed times "
     "their lanes (kind=paid) (labeled coordinate=<id>,kind=used|paid)",
+    # A random effect's buckets by the layout its solves read them in:
+    # `dense`, one (E, D, S) block read once a pass (or a dense shard's
+    # rows), or `sparse`, the ELL planes gathered and scatter-added one at a
+    # time (data/game_dataset.block_layout). Added once a fit.
+    "random_effect_blocks": "a random effect's entity buckets by the layout "
+    "its solves read them in (labeled coordinate=<id>,layout=dense|sparse)",
     # What a random effect trains on (RandomEffectDataset.num_entities and
     # num_active_samples: each entity's rows, or its cap where it has
     # more), set at every fit: a pass of one entity reads its share.

@@ -641,3 +641,67 @@ def test_serving_bucket_program_compiles_for_v5e(one_chip):
     ).compile()
     assert compiled.memory_analysis().argument_size_in_bytes > 4 * SERVE_USERS * SERVE_D
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+# `glmix-movielens-tron.fit`'s per-user and per-movie sweeps
+# (benchmarks/configs/glmix-movielens-tron.json): 4,000,000 rows of 9 ELL
+# planes, projected to 208 features an entity, buckets of 8,192 entities of
+# 256 rows and 4,096 of 512.
+GLMIX_ROWS, GLMIX_PLANES, GLMIX_DIM, GLMIX_ENTITIES = 4_000_000, 9, 208, 27_587
+
+
+@pytest.mark.parametrize("buckets,entities,capacity", [(4, 8192, 256), (2, 4096, 512)])
+def test_the_random_effect_sweep_reads_dense_blocks_at_the_glmix_shape(
+    one_chip, buckets, entities, capacity
+):
+    """The scan program of a TRON sweep lays each bucket out once as a dense
+    (E, D, S) block, S on the lanes, and scatter-adds nothing: the only
+    scatter is the coefficient rows' update."""
+    from photon_ml_tpu.data import containers
+    from photon_ml_tpu.data.containers import SparseFeatures
+    from photon_ml_tpu.data.game_dataset import (
+        GameDataset,
+        RandomEffectDataConfig,
+        build_random_effect_dataset,
+    )
+    from photon_ml_tpu.game.coordinate import RandomEffectCoordinate
+    from photon_ml_tpu.optimize.config import (
+        CoordinateOptimizationConfig,
+        OptimizerConfig,
+        RegularizationContext,
+    )
+    from photon_ml_tpu.types import OptimizerType, RegularizationType
+
+    rng = np.random.default_rng(0)
+    ds = GameDataset.build(
+        {"g": jnp.asarray(rng.normal(size=(64, 4)).astype(np.float32))},
+        (rng.uniform(size=64) < 0.5).astype(np.float32),
+        id_tags={"u": rng.integers(0, 4, 64)},
+    )
+    red = build_random_effect_dataset(ds, RandomEffectDataConfig("u", "g", min_bucket=8))
+    cfg = CoordinateOptimizationConfig(
+        optimizer=OptimizerConfig(OptimizerType.TRON, max_iterations=2, tolerance=1e-5),
+        regularization=RegularizationContext(RegularizationType.L2),
+        reg_weight=10.0,
+    )
+    coord = RandomEffectCoordinate(ds, red, cfg, TaskType.LOGISTIC_REGRESSION)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    planes = (GLMIX_PLANES, GLMIX_ROWS)
+    features = SparseFeatures(spec(planes, jnp.int16), spec(planes, jnp.float32), GLMIX_DIM, ell_axis=-2)
+    rows = spec((GLMIX_ROWS,), jnp.float32)
+    matrix = spec((GLMIX_ENTITIES + 1, GLMIX_DIM), jnp.float32)
+    compiled = coord._train_scan.lower(
+        features, rows, rows, rows, matrix, None,
+        spec((buckets, entities, capacity), jnp.int32),
+        spec((buckets, entities, capacity), jnp.float32),
+        spec((buckets, entities), jnp.int32), None, None, None, _scalar(one_chip),
+    ).compile()
+    block = containers.dense_block_bytes(entities, GLMIX_DIM, capacity)
+    assert block <= compiled.memory_analysis().temp_size_in_bytes <= containers.DENSE_BLOCK_MAX_BYTES
+    instructions = _array_instructions(compiled.as_text())
+    blocks = [i for i in _buffers(instructions).values() if i.elements * 4 == block]
+    assert blocks and all(i.minor == capacity for i in blocks), blocks
+    # Fused or not, every scatter writes coefficient rows; the ELL layout's
+    # gradient scatter-adds would write E x D entries.
+    scatters = [i for i in instructions.values() if i.opcode == "scatter"]
+    assert scatters and all(i.elements == (GLMIX_ENTITIES + 1) * GLMIX_DIM for i in scatters), scatters
