@@ -242,10 +242,17 @@ def _evaluate(
     grouped: Mapping[str, GroupedIndex],
 ) -> "EvaluationResults":
     """One `evaluate_metrics` program, one fetch, the results by name."""
-    telemetry.METRICS.increment("evaluation_calls")
     fetched = np.asarray(
         evaluate_metrics(tuple(evaluator_types), scores, labels, weights, grouped)
     )
+    return _results(evaluator_types, primary, fetched)
+
+
+def _results(
+    evaluator_types: Sequence[EvaluatorType], primary: EvaluatorType, fetched
+) -> "EvaluationResults":
+    """The fetched metric vector by name: one evaluation made."""
+    telemetry.METRICS.increment("evaluation_calls")
     return EvaluationResults(
         primary=primary,
         results={str(et): float(v) for et, v in zip(evaluator_types, fetched)},
@@ -308,6 +315,18 @@ class EvaluationSuite:
             self.weights,
             self._grouped,
         )
+
+    def metrics(self, scores: Array) -> Array:
+        """`evaluate` handed to the device and not waited on: the float32
+        metric vector in `evaluator_types` order. A caller that fetches it
+        with other values reads it back through `results`."""
+        return evaluate_metrics(
+            tuple(self.evaluator_types), scores, self.labels, self.weights, self._grouped
+        )
+
+    def results(self, fetched) -> "EvaluationResults":
+        """The results of a metric vector `metrics` made, fetched."""
+        return _results(self.evaluator_types, self.primary, fetched)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -44,7 +44,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 from functools import partial
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -237,6 +237,7 @@ class FixedEffectCoordinate:
         self.task = task
         self.loss: PointwiseLoss = loss_for_task(task)
         self.norm = norm
+        self._reg_weights: Dict[float, Array] = {}
         # Decide the fused-Pallas objective path ONCE here, on the concrete
         # array — its dtype/shape/sharding are all visible, unlike inside the
         # jit trace where should_use would have to guess. The decision is
@@ -424,7 +425,13 @@ class FixedEffectCoordinate:
         column_major = self._column_major
 
         @jax.jit
-        def train_fn(features, labels, offsets, weights, w0, reg_weight, key):
+        def train_fn(features, labels, offsets, weights, w0, reg_weight, key=None):
+            # A cold start's zero vector is made here, in the program, and
+            # the key is handed in only where down-sampling reads it: the
+            # host makes neither before it dispatches the solve.
+            if w0 is None:
+                dim = features.dim if hasattr(features, "dim") else features.shape[-1]
+                w0 = jnp.zeros((dim,), labels.dtype)
             if use_sampling:
                 weights = down_sample_weights(
                     key,
@@ -475,17 +482,9 @@ class FixedEffectCoordinate:
     ) -> Tuple[FixedEffectModel, OptResult]:
         ds = self.dataset
         feats = self._features
-        dim = feats.dim if hasattr(feats, "dim") else feats.shape[-1]
-        w0 = (
-            initial_model.coefficients.means
-            if initial_model is not None
-            else jnp.zeros((dim,), ds.labels.dtype)
-        )
-        rw = jnp.asarray(
-            self.config.reg_weight if reg_weight is None else reg_weight,
-            ds.labels.dtype,
-        )
-        if key is None:
+        w0 = None if initial_model is None else initial_model.coefficients.means
+        rw = self._reg_weight(self.config.reg_weight if reg_weight is None else reg_weight)
+        if self.config.down_sampling_rate < 1.0 and key is None:
             key = jax.random.PRNGKey(0)
         res = self._train_fn(feats, ds.labels, offsets, ds.weights, w0, rw, key)
         variances = None
@@ -495,6 +494,17 @@ class FixedEffectCoordinate:
             )
         model = FixedEffectModel(Coefficients(res.coefficients, variances), self.task)
         return model, res
+
+    def _reg_weight(self, value) -> Array:
+        """The regularisation weight as the solve takes it: a device scalar,
+        made once a value and kept (an array is immutable), so a refit with
+        the same weight transfers nothing before its solve."""
+        kept = self._reg_weights.get(float(value))
+        if kept is None:
+            kept = self._reg_weights[float(value)] = jnp.asarray(
+                value, self.dataset.labels.dtype
+            )
+        return kept
 
     @property
     def training_features(self):
@@ -512,9 +522,10 @@ class FixedEffectCoordinate:
     # lowering changes reduction order), so each trial's ops — and bits —
     # are identical to a standalone train()/score() call.
 
-    def trial_train(self, offsets, w0, reg_weight, key):
+    def trial_train(self, offsets, w0, reg_weight, key=None):
         """One trial's solve as traced values; returns the (coefficients,
-        variances) arrays (variances None unless configured)."""
+        variances) arrays (variances None unless configured). `key` only
+        where the coordinate down-samples."""
         ds = self.dataset
         res = self._train_fn(
             self._features, ds.labels, offsets, ds.weights, w0, reg_weight, key

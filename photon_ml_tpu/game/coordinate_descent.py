@@ -22,11 +22,13 @@ Supported, mirroring the reference:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 from typing import Dict, List, Mapping, Optional, Sequence, Set, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from photon_ml_tpu.evaluation.suite import EvaluationResults, EvaluationSuite
 from photon_ml_tpu.game.model import GameModel
@@ -57,42 +59,42 @@ def _model_arrays(model, scores) -> tuple:
     return tuple(arrays)
 
 
-# Per-coordinate sweep glue as TWO fused XLA programs (the scan-the-sweep
+# Per-coordinate sweep glue as fused XLA programs (the scan-the-sweep
 # companion to the coordinate-level scan in game/coordinate.py): residual +
 # offset build is one dispatch, and the commit — new summed scores PLUS the
 # divergence guard's all-finite reduction over every updated array — is one
-# more, whose single boolean fetch is the sweep's only host sync. The ops
-# are identical to the previous unfused expressions, so residuals, summed
-# scores and the guard decision are bitwise unchanged.
+# more. A validated update hands its validation scoring, their sum and the
+# evaluation over behind the commit, and one small program stacks the
+# guard's boolean, the solve's counts and the metrics into ONE vector: the
+# update's only host sync. The arithmetic is the unfused expressions', so
+# residuals, summed scores, metrics and the guard decision are bitwise
+# unchanged.
 
 
-@jax.jit
 def _residual_offsets(summed, prev_scores, base_offsets):
-    residual = summed - prev_scores
-    return residual, base_offsets + residual
+    """(residual, offsets) of one update. `None` is a score vector of zeros:
+    no coordinate has scored yet, or this one has not. The program makes
+    it, not the host, and places it as the offsets lie, so a sample-sharded
+    fit's residual stays cut by sample as when the host made it."""
+    sharding = getattr(base_offsets, "sharding", None)
+    if sharding is not None and len(sharding.device_set) < 2:
+        sharding = None
+    return _residual_program(sharding)(summed, prev_scores, base_offsets)
 
 
-def _score_zeros(n: int, dtype, like):
-    """A zero score vector placed WHERE the sample arrays live. On a
-    single process this is exactly `jnp.zeros` (bitwise-identical
-    dispatch). When `like` (the dataset's offsets) is a global array over
-    a multi-process mesh, a process-local zeros array must not enter the
-    residual computation — mixing addressable-only and global operands is
-    the "Multiprocess computations aren't implemented" crash — so the
-    zeros are assembled with the SAME (replicated) sharding."""
-    sharding = getattr(like, "sharding", None)
-    mesh = getattr(sharding, "mesh", None)
-    if mesh is not None:
-        from photon_ml_tpu.parallel.mesh import mesh_spans_processes
+@functools.lru_cache(maxsize=None)
+def _residual_program(sharding):
+    @jax.jit
+    def _residual_offsets(summed, prev_scores, base_offsets):
+        zeros = jnp.zeros_like(base_offsets)
+        if sharding is not None:
+            zeros = jax.lax.with_sharding_constraint(zeros, sharding)
+        residual = (zeros if summed is None else summed) - (
+            zeros if prev_scores is None else prev_scores
+        )
+        return residual, base_offsets + residual
 
-        if mesh_spans_processes(mesh):
-            import numpy as np
-
-            z = np.zeros((n,), dtype)
-            return jax.make_array_from_callback(
-                z.shape, sharding, lambda idx: z[idx]
-            )
-    return jnp.zeros((n,), dtype)
+    return _residual_offsets
 
 
 @jax.jit
@@ -101,6 +103,48 @@ def _commit_update(residual, new_scores, guarded_arrays):
     for a in guarded_arrays:
         ok = ok & jnp.all(jnp.isfinite(a))
     return residual + new_scores, ok
+
+
+@jax.jit
+def _validation_total(offsets, scores):
+    """The validation rows' scores summed in the order given, seeded with
+    their offsets where there are any: what the evaluation reads."""
+    total = offsets
+    for s in scores:
+        total = s if total is None else total + s
+    return total
+
+
+@jax.jit
+def _verdict_vector(ok, counts, metrics):
+    """One int32 vector: the guard's boolean, the solve's device counts and
+    the float32 metrics' bits, so the host fetches one array."""
+    parts = [jnp.reshape(ok, (1,)).astype(jnp.int32)]
+    parts += [jnp.reshape(c, (1,)).astype(jnp.int32) for c in counts]
+    if metrics is not None:
+        parts.append(jax.lax.bitcast_convert_type(metrics, jnp.int32))
+    return jnp.concatenate(parts)
+
+
+def _seed_scores(coordinates, ids, models, validation_scorer):
+    """(scores, summed, validation scores) of the models that exist before
+    an update: locked, warm-start, checkpointed or reassembled ones (the
+    reference seeds summedScores from initial models, :168-220; on resume
+    the residual state is a pure function of the models). `summed` is None
+    where no model exists yet: `_residual_offsets` makes that zero itself."""
+    scores: Dict[str, jnp.ndarray] = {}
+    summed = None
+    for cid in ids:
+        if cid in models:
+            s = coordinates[cid].score(models[cid])
+            scores[cid] = s
+            summed = s if summed is None else summed + s
+    val_scores: Dict[str, jnp.ndarray] = {}
+    if validation_scorer is not None:
+        for cid in ids:
+            if cid in models:
+                val_scores[cid] = validation_scorer(cid, models[cid])
+    return scores, summed, val_scores
 
 
 def _recover_from_mesh_loss(
@@ -169,12 +213,13 @@ def _recover_from_mesh_loss(
     return models, best_models, snap_best_res, snap_pass, completed_steps, "memory"
 
 
-def _fetch_verdict(ok, stats):
-    """(finite, counts) of one attempted update, with the ONE device-to-host
-    fetch the divergence guard always made: the fixed effect's
-    `OptResult.fn_evals`, `iterations` and (TRON) `hv_evals` are device
-    scalars, ready since its solve ended, and ride along with the guard's
-    boolean. A random effect's stats hold the counts on the host already
+def _fetch_verdict(ok, stats, metrics=None):
+    """(finite, counts, metric values) of one attempted update, read in ONE
+    device-to-host fetch of one array (`_verdict_vector`): the guard's
+    boolean, the fixed effect's `OptResult.fn_evals`, `iterations` and
+    (TRON) `hv_evals` — device scalars, ready since its solve ended — and,
+    where the update is validated, the evaluation's metric vector (None
+    otherwise). A random effect's stats hold the counts on the host already
     (`_finish_train`'s one fetch). `counts` holds `fn_evals`, `iterations`
     and `rejected`, what the solve evaluated and threw away: its passes over
     the data less the first evaluation, one an iteration and the
@@ -184,31 +229,29 @@ def _fetch_verdict(ok, stats):
     solve), with a random effect's `lane_passes_used` / `lane_passes_paid`
     (its TRON sweep's passes needed and executed). Empty where the
     coordinate's train reports no count."""
-    if getattr(stats, "fn_evals", None) is None:
-        return bool(ok), {}
-    names = ["fn_evals", "iterations"]
-    for name in ("hv_evals", "lane_passes_used", "lane_passes_paid"):
-        if getattr(stats, name, None) is not None:
-            names.append(name)
-    fetched = (ok, *(getattr(stats, name) for name in names))
-    if isinstance(stats.fn_evals, jax.Array):
-        fetched = jax.device_get(fetched)
-    counts = {name: int(value) for name, value in zip(names, fetched[1:])}
-    counts["rejected"] = (
-        counts["fn_evals"] - getattr(stats, "solves", 1) - counts["iterations"]
-        - counts.get("hv_evals", 0)
+    names = []
+    if getattr(stats, "fn_evals", None) is not None:
+        names = ["fn_evals", "iterations"]
+        for name in ("hv_evals", "lane_passes_used", "lane_passes_paid"):
+            if getattr(stats, name, None) is not None:
+                names.append(name)
+    on_device = [n for n in names if isinstance(getattr(stats, n), jax.Array)]
+    fetched = jax.device_get(
+        _verdict_vector(ok, tuple(getattr(stats, n) for n in on_device), metrics)
     )
-    return bool(fetched[0]), counts
-
-
-def _update_all_finite(model, scores) -> bool:
-    """ONE scalar all-finite check over a coordinate update (new model +
-    new scores): the and-reduction builds device-side, so the guard costs a
-    single boolean fetch per coordinate update, not one per array."""
-    ok = jnp.bool_(True)
-    for a in _model_arrays(model, scores):
-        ok = ok & jnp.all(jnp.isfinite(a))
-    return bool(ok)
+    device_counts = dict(zip(on_device, fetched[1 : 1 + len(on_device)]))
+    counts = {
+        name: int(device_counts.get(name, getattr(stats, name))) for name in names
+    }
+    if counts:
+        counts["rejected"] = (
+            counts["fn_evals"] - getattr(stats, "solves", 1) - counts["iterations"]
+            - counts.get("hv_evals", 0)
+        )
+    values = None
+    if metrics is not None:
+        values = fetched[1 + len(on_device) :].view(np.float32)
+    return bool(fetched[0]), counts, values
 
 
 @dataclasses.dataclass
@@ -346,10 +389,7 @@ def run_coordinate_descent(
         if initial_models is None or c not in initial_models:
             raise ValueError(f"Locked coordinate {c!r} needs an initial model")
 
-    first = next(iter(coordinates.values()))
-    base_offsets = first.dataset.offsets
-    n = first.dataset.num_samples
-    dtype = base_offsets.dtype
+    base_offsets = next(iter(coordinates.values())).dataset.offsets
 
     models: Dict[str, object] = dict(initial_models.models) if initial_models else {}
     timing: Dict[str, float] = {}
@@ -452,24 +492,15 @@ def run_coordinate_descent(
                 completed_steps,
             )
 
-    scores: Dict[str, jnp.ndarray] = {}
-    summed = _score_zeros(n, dtype, base_offsets)
-    # Locked coordinates, warm-start and checkpointed models contribute
-    # scores immediately (reference seeds summedScores from initial models,
-    # :168-220; on resume the residual state is a pure function of models).
-    for cid in ids:
-        if cid in models:
-            s = coordinates[cid].score(models[cid])
-            scores[cid] = s
-            summed = summed + s
-
-    val_scores: Dict[str, jnp.ndarray] = {}
-    if validation_scorer is not None:
-        for cid in ids:
-            if cid in models:
-                val_scores[cid] = validation_scorer(cid, models[cid])
-
-    import jax
+    scores, summed, val_scores = _seed_scores(
+        coordinates, ids, models, validation_scorer
+    )
+    validating = validation_scorer is not None and validation_suite is not None
+    # Where the host pipeline is on, a validated update's durable model
+    # write runs on a thread beside the evaluation's round trip; it starts
+    # before the verdict and is dropped with a rejected candidate (save()
+    # adopts only the accepted one's, and the next commit prunes the file).
+    staging = ckpt is not None and prefetch and validating
 
     # Planned quantity (ISSUE 14): how many upcoming unlocked coordinates
     # the loop prefetches while the current one solves. Default 1 (the
@@ -508,7 +539,6 @@ def run_coordinate_descent(
             if len(kicked) >= prefetch_depth:
                 return
 
-    root_key = jax.random.PRNGKey(seed)
     # Most recent validation results (best-pass selection compares against
     # these at each pass-final coordinate). On resume, reconstruct from the
     # persisted history: a replayed step whose update is REJECTED skips
@@ -558,9 +588,9 @@ def run_coordinate_descent(
             # fault-free result; a deterministic divergence reproduces and
             # the coordinate keeps its last-good model.
             model = None
-            new_scores = None
-            new_summed = None
             update_counts: Dict[str, int] = {}  # summed over the attempts
+            waits = 0  # the blocking fetches this update makes
+            staged_write = None
             # One stage per coordinate update, its wall the update's
             # `timing` entry; the cd/* stages inside it are declared in
             # contracts.SOLVE_STAGES (which are dispatch walls, which wait).
@@ -570,9 +600,7 @@ def run_coordinate_descent(
                 with stage_timer("cd/residual"):
                     _prefetch_after(step)
                     residual, offsets = _residual_offsets(
-                        summed,
-                        scores.get(cid, _score_zeros(n, dtype, base_offsets)),
-                        base_offsets,
+                        summed, scores.get(cid), base_offsets
                     )
                 kwargs = {}
                 if reg_weights and cid in reg_weights:
@@ -581,7 +609,7 @@ def run_coordinate_descent(
                     # Fresh subsample per optimize call, as in the reference's
                     # runWithSampling
                     # (DistributedOptimizationProblem.scala:144).
-                    kwargs["key"] = jax.random.fold_in(root_key, step)
+                    kwargs["key"] = jax.random.fold_in(jax.random.PRNGKey(seed), step)
 
                 # Mesh-loss fault site (ISSUE 13): one invocation per
                 # coordinate update. An armed plan simulates part of the
@@ -596,60 +624,93 @@ def run_coordinate_descent(
                     ) from exc
 
                 for attempt in range(1 + faults.solve_retry_attempts()):
+                    metrics = cand_val = None
+                    try:
+                        with stage_timer("cd/train"):
+                            cand_model, stats = coord.train(
+                                offsets, models.get(cid), **kwargs
+                            )
+                        with stage_timer("cd/score"):
+                            cand_scores = coord.score(cand_model)
+                        with stage_timer("cd/commit") as commit:
+                            # One fused program: the next summed-scores
+                            # vector and the divergence guard's reduction.
+                            # A validated update hands its validation over
+                            # behind it before the verdict is known, and the
+                            # host waits once: the guard's boolean, the
+                            # solve's counts and the metrics in one fetch.
+                            cand_summed, ok = _commit_update(
+                                residual,
+                                cand_scores,
+                                _model_arrays(cand_model, cand_scores),
+                            )
+                            if validating:
+                                commit.set(deferred_validation=True)
+                                with stage_timer("cd/validation_score"):
+                                    cand_val = validation_scorer(cid, cand_model)
+                                    # Seeded with the validation offsets so
+                                    # selection uses the same score definition
+                                    # as the final reported evaluation.
+                                    total = _validation_total(
+                                        validation_offsets,
+                                        tuple({**val_scores, cid: cand_val}.values()),
+                                    )
+                                with stage_timer("cd/validation_evaluate"):
+                                    metrics = validation_suite.metrics(total)
+                                if staging:
+                                    with stage_timer("cd/checkpoint"):
+                                        staged_write = ckpt.begin_model_write(
+                                            completed_steps=step + 1,
+                                            cid=cid,
+                                            model=cand_model,
+                                        )
+                            finite, counts, values = _fetch_verdict(
+                                ok, stats, metrics
+                            )
+                            waits += 1
+                        for name, count in counts.items():
+                            update_counts[name] = update_counts.get(name, 0) + count
+                    except faults.MeshLoss:
+                        raise
+                    except BaseException as exc:
+                        # Escalation to MeshLoss: a device-shaped
+                        # failure that escaped the coordinate's OWN
+                        # failure domain (bounded re-dispatch AND the
+                        # bucket-loop fallback both lost) on an
+                        # entity-sharded coordinate means the shard
+                        # group is dead — in-place retry would re-hit
+                        # the same dead devices, so hand it to the
+                        # sweep-boundary elastic resume instead.
+                        if getattr(
+                            coord, "entity_mesh", None
+                        ) is not None and faults.is_device_error(exc):
+                            raise faults.MeshLoss(
+                                f"device-shaped failure on the "
+                                f"entity-sharded coordinate {cid!r} "
+                                f"at iteration {it}: {exc!r}"
+                            ) from exc
+                        raise
                     try:
                         faults.fault_point("solve")
                     except faults.InjectedFault:
-                        # Only the solve site's OWN injection reads as a
-                        # divergence; faults raised inside train/score (e.g.
-                        # an upload whose retries exhausted) keep their
-                        # surface semantics — swallowing them here would ship
-                        # an untrained model as a "diverged" counter.
+                        # The solve site's own injection reads as the
+                        # guard's rejection of the update; faults raised
+                        # inside train/score (e.g. an upload whose retries
+                        # exhausted) keep their surface semantics —
+                        # swallowing them would ship an untrained model as
+                        # a "diverged" counter.
                         finite = False
-                    else:
-                        try:
-                            with stage_timer("cd/train"):
-                                cand_model, stats = coord.train(
-                                    offsets, models.get(cid), **kwargs
-                                )
-                            with stage_timer("cd/score"):
-                                cand_scores = coord.score(cand_model)
-                            with stage_timer("cd/commit"):
-                                # One fused program: the next summed-scores
-                                # vector and the divergence guard's
-                                # reduction; one fetch, the solve's
-                                # evaluation count riding along.
-                                cand_summed, ok = _commit_update(
-                                    residual,
-                                    cand_scores,
-                                    _model_arrays(cand_model, cand_scores),
-                                )
-                                finite, counts = _fetch_verdict(ok, stats)
-                            for name, count in counts.items():
-                                update_counts[name] = update_counts.get(name, 0) + count
-                        except faults.MeshLoss:
-                            raise
-                        except BaseException as exc:
-                            # Escalation to MeshLoss: a device-shaped
-                            # failure that escaped the coordinate's OWN
-                            # failure domain (bounded re-dispatch AND the
-                            # bucket-loop fallback both lost) on an
-                            # entity-sharded coordinate means the shard
-                            # group is dead — in-place retry would re-hit
-                            # the same dead devices, so hand it to the
-                            # sweep-boundary elastic resume instead.
-                            if getattr(
-                                coord, "entity_mesh", None
-                            ) is not None and faults.is_device_error(exc):
-                                raise faults.MeshLoss(
-                                    f"device-shaped failure on the "
-                                    f"entity-sharded coordinate {cid!r} "
-                                    f"at iteration {it}: {exc!r}"
-                                ) from exc
-                            raise
                     if finite:
-                        model, new_scores = cand_model, cand_scores
-                        new_summed = cand_summed
+                        model, new_scores, new_summed = cand_model, cand_scores, cand_summed
+                        new_val, new_values = cand_val, values
                         break
+                    if metrics is not None:
+                        telemetry.METRICS.increment(
+                            "validation_discarded", labels=(("coordinate", cid),)
+                        )
+                    if staged_write is not None:
+                        staged_write[4].join()
+                        staged_write = None
                     diverged_steps += 1
                     record_stage("diverged", 1.0)
                     logger.warning(
@@ -677,6 +738,9 @@ def run_coordinate_descent(
                         it,
                         cid,
                     )
+            telemetry.METRICS.observe(
+                "cd_host_waits", waits, labels=(("coordinate", cid),)
+            )
             if update_evals is not None:
                 fn_evals[cid] = fn_evals.get(cid, 0) + update_evals
             if "hv_evals" in update_counts:  # a TRON solve
@@ -725,36 +789,9 @@ def run_coordinate_descent(
                 "uncounted" if update_evals is None else update_evals,
             )
 
-            # Overlap the step's durable model write with the validation
-            # evaluation below (EvaluationSuite's device round trip): the
-            # npz write is host disk I/O, so the two hide behind each
-            # other. save() joins the write before the state.json commit —
-            # the crash-exact protocol is untouched. Pipeline-gated like
-            # every other overlap (a write thread on a 1-core host only
-            # steals the evaluator's core).
-            staged_write = None
-            if (
-                accepted
-                and ckpt is not None
-                and prefetch
-                and validation_scorer is not None
-                and validation_suite is not None
-            ):
-                with stage_timer("cd/checkpoint"):
-                    staged_write = ckpt.begin_model_write(
-                        completed_steps=step + 1, cid=cid, model=model
-                    )
-
-            if accepted and validation_scorer is not None and validation_suite is not None:
-                with stage_timer("cd/validation_score"):
-                    val_scores[cid] = validation_scorer(cid, model)
-                    # Seed with the validation offsets so selection uses the
-                    # same score definition as the final reported evaluation.
-                    total = validation_offsets
-                    for s in val_scores.values():
-                        total = s if total is None else total + s
-                with stage_timer("cd/validation_evaluate"):
-                    results = validation_suite.evaluate(total)
+            if accepted and validating:
+                val_scores[cid] = new_val
+                results = validation_suite.results(new_values)
                 validation_history.append((it, cid, results))
                 logger.info("validation after %s: %s", cid, results.results)
                 pass_results = results
@@ -793,8 +830,6 @@ def run_coordinate_descent(
                     )
                 if on_event is not None:
                     on_event("checkpoint", step=step + 1, coordinate=cid)
-            elif staged_write is not None:  # pragma: no cover - ckpt is set
-                staged_write[4].join()
         except faults.MeshLoss as exc:
             mesh_losses += 1
             faults.COUNTERS.increment("mesh_losses")
@@ -846,22 +881,10 @@ def run_coordinate_descent(
             # Residual state is a pure function of the models — recompute
             # it through the NEW coordinates (the rebuilt dataset may pad
             # samples differently on the smaller mesh).
-            first = next(iter(coordinates.values()))
-            base_offsets = first.dataset.offsets
-            n = first.dataset.num_samples
-            dtype = base_offsets.dtype
-            scores = {}
-            summed = _score_zeros(n, dtype, base_offsets)
-            for c2 in ids:
-                if c2 in models:
-                    s = coordinates[c2].score(models[c2])
-                    scores[c2] = s
-                    summed = summed + s
-            val_scores = {}
-            if validation_scorer is not None:
-                for c2 in ids:
-                    if c2 in models:
-                        val_scores[c2] = validation_scorer(c2, models[c2])
+            base_offsets = next(iter(coordinates.values())).dataset.offsets
+            scores, summed, val_scores = _seed_scores(
+                coordinates, ids, models, validation_scorer
+            )
             surviving = max(
                 int(m.devices.size)
                 if (m := getattr(c, "entity_mesh", None)) is not None

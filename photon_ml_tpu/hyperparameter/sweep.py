@@ -744,7 +744,7 @@ class SweepExecutor:
                         key_t = (
                             jax.random.fold_in(root_key, step)
                             if sampling[cid]
-                            else jax.random.PRNGKey(0)
+                            else None
                         )
                         w_new, var_new = coord.trial_train(
                             offsets, old["w"], rw, key_t

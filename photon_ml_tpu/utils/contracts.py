@@ -30,13 +30,16 @@ PREPARE_STAGES = ("re_build", "projector", "stats", "pack", "upload", "compile")
 # histogram `fit_stage_s{stage=<name>}`), a telemetry span, and a
 # `photon/<name>` annotation on the profiler's clock. Nesting is
 # SOLVE_STAGE_PARENT; siblings tile their parent up to the glue between them.
-# Dispatch is asynchronous: `cd/residual`, `cd/train`, `cd/score` and
-# `cd/validation_score` are DISPATCH walls (the host handing programs to the
-# device; a random-effect `cd/train` also waits once, for its solves'
-# counts), and the host WAITS for the device in `cd/commit` (the divergence
-# guard's fetch) and in `cd/validation_evaluate` (one evaluation program
-# handed over, then the fetch of its metrics: the wall is the device's
-# scoring and evaluation work plus one round trip). `fit/final_evaluate`
+# Dispatch is asynchronous: `cd/residual`, `cd/train`, `cd/score`,
+# `cd/validation_score` and `cd/validation_evaluate` are DISPATCH walls (the
+# host handing programs to the device; a random-effect `cd/train` also waits
+# once, for its solves' counts), and the host WAITS for the device once an
+# update, in `cd/commit`: a validated update hands its validation scoring
+# and evaluation over inside that stage, behind the guard's program and
+# before the verdict is known (its span carries `deferred_validation`), and
+# one fetch reads the guard's boolean, the solve's counts and the metrics,
+# so the wall is the solve's, the scoring's and the evaluation's device work
+# plus one round trip. `fit/final_evaluate`
 # takes the descent's last validation as the returned model's evaluation
 # (`CoordinateDescentResult.evaluation`; microseconds, no device work) and
 # scores, evaluates and waits as `cd/validation_evaluate` does only where
@@ -71,8 +74,8 @@ SOLVE_STAGE_PARENT = {
     "cd/train": "coordinate_update",
     "cd/score": "coordinate_update",
     "cd/commit": "coordinate_update",
-    "cd/validation_score": "fit/descent",
-    "cd/validation_evaluate": "fit/descent",
+    "cd/validation_score": "cd/commit",
+    "cd/validation_evaluate": "cd/commit",
     "cd/checkpoint": "fit/descent",
     "fit/final_evaluate": "fit",
     "fit/publish": "fit",

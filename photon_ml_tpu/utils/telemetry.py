@@ -234,13 +234,25 @@ METRIC_DESCRIPTIONS = {
     # (evaluation/suite.evaluate_metrics); whether JAX made that program
     # ready anew is in compile_cache.programs(), as for every program.
     "evaluation_calls": "evaluations made (EvaluationSuite.evaluate, "
-    "StreamingWindowEvaluator.evaluate_window): one device program and "
-    "one fetch each",
+    "StreamingWindowEvaluator.evaluate_window, a validated coordinate "
+    "update whose candidate was accepted): one device program and one "
+    "fetch each",
+    # A validated coordinate update evaluates its candidate before the
+    # guard's verdict is known (game/coordinate_descent); the guard's
+    # rejection drops that evaluation unread.
+    "validation_discarded": "validations of a candidate update dropped "
+    "because the divergence guard rejected it (labeled coordinate=<id>)",
     # -- histograms (fixed log-spaced buckets, mergeable) --
     "serving_latency_ms": "per-request wall latency through the batcher",
     "serving_queue_wait_ms": "submit-to-claim queue wait per request",
     "serving_batch_size": "requests per dispatched micro-batch",
     "coordinate_update_s": "wall seconds per coordinate-descent update",
+    # The coordinate-descent loop's own blocking fetches, one observation
+    # an update: 1 where the verdict and the validation's metrics ride in
+    # one fetch, one more a retried attempt; a random effect's fetch inside
+    # its train is not the loop's.
+    "cd_host_waits": "blocking device-to-host fetches one coordinate-"
+    "descent update made (labeled coordinate=<id>)",
     "fit_stage_s": "wall seconds per fit of each contracts.SOLVE_STAGES "
     "stage (labeled stage=<name>); the unlabeled aggregate mixes stages "
     "and means nothing",

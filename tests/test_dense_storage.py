@@ -137,7 +137,7 @@ def test_the_compiled_solve_takes_a_column_major_matrix_as_it_lies(rng, interpre
     _, coordinate, _ = fit(train, dataset(rng, 512, "one_device"), COLUMN_MAJOR)
     lowered = coordinate._train_fn.lower(
         coordinate.training_features, train.labels, train.offsets, train.weights,
-        jnp.zeros((DIM,), jnp.float32), jnp.float32(1.0), jax.random.PRNGKey(0),
+        jnp.zeros((DIM,), jnp.float32), jnp.float32(1.0),
     )
     assert order(coordinate.training_features) == COLUMN_MAJOR
     assert tuple(lowered.compile().input_formats[0][0].layout.major_to_minor) == COLUMN_MAJOR

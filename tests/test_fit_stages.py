@@ -516,6 +516,225 @@ class TestRandomEffectSolveStats:
         assert cd.fn_evals == {"per-entity": stats.fn_evals}
 
 
+def _waits(cid):
+    """(updates, blocking fetches) the process recorded for coordinate `cid`."""
+    snap = telemetry.METRICS.labeled_histograms("cd_host_waits").get(f"coordinate={cid}")
+    return (0, 0.0) if snap is None else (snap["count"], snap["sum"])
+
+
+def _todays_path(coordinates, passes, scorer, suite, validation_offsets, reg_weights):
+    """The validated descent as the host ran it before its validation rode
+    in the verdict's fetch: the zero scores, start vector, regularisation
+    weight and key made by the host, the verdict fetched, and then the
+    validation scored, summed and evaluated with a fetch of its own."""
+    from photon_ml_tpu.game.model import Coefficients, FixedEffectModel
+
+    base = next(iter(coordinates.values())).dataset.offsets
+    zeros = lambda: jnp.zeros(base.shape, base.dtype)
+    summed, scores, models, val_scores, history = zeros(), {}, {}, {}, []
+    for it in range(passes):
+        for cid, coord in coordinates.items():
+            residual = summed - scores.get(cid, zeros())
+            offsets = base + residual
+            if isinstance(coord, FixedEffectCoordinate):
+                feats, data = coord.training_features, coord.dataset
+                prev = models.get(cid)
+                w0 = prev.coefficients.means if prev else jnp.zeros((feats.shape[-1],), data.labels.dtype)
+                res = coord._train_fn(
+                    feats, data.labels, offsets, data.weights, w0,
+                    jnp.asarray(reg_weights[cid], data.labels.dtype), jax.random.PRNGKey(0),
+                )
+                model = FixedEffectModel(Coefficients(res.coefficients, None), TASK)
+            else:
+                model, _ = coord.train(offsets, models.get(cid), reg_weight=reg_weights[cid])
+            scores[cid] = coord.score(model)
+            summed = residual + scores[cid]
+            models[cid] = model
+            val_scores[cid] = scorer(cid, model)
+            total = validation_offsets
+            for v in val_scores.values():
+                total = v if total is None else total + v
+            history.append((it, cid, suite.evaluate(total).results))
+    return models, history
+
+
+class TestAValidatedUpdateWaitsOnce:
+    """A validated update is enqueued whole — its inputs made in
+    the programs or kept, the solve, the scoring, the commit and guard, the
+    validation's scoring, sum and evaluation — and the host waits once, for
+    one vector holding the guard's boolean, the solve's counts and the
+    metrics."""
+
+    @staticmethod
+    def _fit(coordinates, passes=2, fault=""):
+        from photon_ml_tpu.estimators import game_estimator
+
+        data_configs = {
+            "global": FixedEffectDataConfig("g"),
+            "per-e": RandomEffectDataConfig("e", "g", min_bucket=8),
+        }
+        est = GameEstimator(
+            TASK,
+            {cid: data_configs[cid] for cid in coordinates},
+            coordinate_descent_iterations=passes,
+            validation_evaluators=[EvaluatorType.parse("AUC"), EvaluatorType.parse("LOGISTIC_LOSS")],
+            pipeline=False,
+        )
+        cfg = {
+            cid: CoordinateOptimizationConfig(
+                optimizer=OptimizerConfig(max_iterations=3), reg_weight=1.5
+            )
+            for cid in coordinates
+        }
+        train, val = _glmix(n=1500, n_val=400, n_entities=40)
+        calls = []
+        descend = game_estimator.run_coordinate_descent
+
+        def recording(*a, **k):
+            calls.append((a, k, descend(*a, **k)))
+            return calls[-1][2]
+
+        tracer = telemetry.install_tracer(telemetry.Tracer())
+        try:
+            with pytest.MonkeyPatch.context() as mp, faults.inject(fault):
+                mp.setattr(game_estimator, "run_coordinate_descent", recording)
+                result = est.fit(train, val, [cfg])[0]
+        finally:
+            telemetry.uninstall_tracer()
+        (args, kwargs, cd), = calls
+        return result, cd, args, kwargs, tracer.spans()
+
+    @pytest.mark.parametrize("coordinates", [("global",), ("global", "per-e")], ids=["dense", "glmix"])
+    def test_one_wait_an_update_and_the_bits_of_the_path_before(self, coordinates):
+        before = {cid: _waits(cid) for cid in coordinates}
+        result, cd, args, kwargs, spans = self._fit(coordinates)
+        for cid in coordinates:
+            updates, waits = (a - b for a, b in zip(_waits(cid), before[cid]))
+            assert updates == waits == 2, (cid, updates, waits)  # two passes, one wait each
+        commits = [s for s in spans if s["name"] == "cd/commit"]
+        assert len(commits) == 2 * len(coordinates)
+        assert all(s["args"]["deferred_validation"] is True for s in commits)
+        ids = {s["args"]["span_id"] for s in commits}
+        assert len(_children("cd/commit")) == 2  # the validation's scoring and evaluation
+        for name in _children("cd/commit"):
+            inside = [s for s in spans if s["name"] == name]
+            assert len(inside) == len(commits) and all(s["args"]["parent_id"] in ids for s in inside)
+
+        coords, passes = args
+        models, history = _todays_path(
+            coords, passes, kwargs["validation_scorer"], kwargs["validation_suite"],
+            kwargs["validation_offsets"], kwargs["reg_weights"],
+        )
+        for cid, model in models.items():
+            got = result.model[cid]
+            for a, b in (
+                (got.coefficients.means, model.coefficients.means)
+                if cid == "global"
+                else (got.coefficients_matrix, model.coefficients_matrix),
+            ):
+                assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), cid
+        assert [(it, cid, r.results) for it, cid, r in cd.validation_history] == history
+        assert cd.evaluation is cd.validation_history[-1][2] is result.evaluation
+        assert cd.evaluation.results == history[-1][2]
+
+    def test_a_rejected_candidates_evaluation_is_dropped(self):
+        clean, clean_cd, *_ = self._fit(("global",), passes=1)
+        discarded = telemetry.METRICS.labeled_counters("validation_discarded")
+        before = _waits("global")
+        calls = telemetry.METRICS.get_counter("evaluation_calls")
+        faulted, cd, *_ = self._fit(("global",), passes=1, fault="solve@1")
+        assert cd.diverged_steps == 1
+        after = telemetry.METRICS.labeled_counters("validation_discarded")
+        assert after["coordinate=global"] - discarded.get("coordinate=global", 0) == 1
+        assert tuple(a - b for a, b in zip(_waits("global"), before)) == (1, 2)  # one update, two attempts
+        assert telemetry.METRICS.get_counter("evaluation_calls") - calls == 1
+        a, b = (r.model["global"].coefficients.means for r in (clean, faulted))
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+        assert [r.results for _, _, r in cd.validation_history] == [
+            r.results for _, _, r in clean_cd.validation_history
+        ]
+        assert faulted.evaluation.results == clean.evaluation.results
+
+
+def _dense_fit_data(optimizer):
+    train, val = _glmix(n=1500, n_val=400)
+    est = GameEstimator(
+        TASK, {"global": FixedEffectDataConfig("g")},
+        coordinate_descent_iterations=1,
+        validation_evaluators=[EvaluatorType.parse("AUC")],
+    )
+    cfg = CoordinateOptimizationConfig(
+        optimizer=OptimizerConfig(optimizer_type=optimizer, max_iterations=3), reg_weight=1.0
+    )
+    return est, train, val, {"global": cfg}
+
+
+def _sample_sharded_fit_data(_):
+    """The wide sparse fixed effect over four devices, a part a device, as
+    the four-chip cell lays it (tests/test_sample_sharded_sparse_fit.py)."""
+    from benchmarks.generators import criteo_shape_mesh
+    from photon_ml_tpu.parallel.mesh import make_mesh, sample_sharded_dataset
+    from tests import test_sample_sharded_sparse_fit as sharded
+
+    mesh = make_mesh(jax.devices()[: sharded.DEVICES])
+    problem = criteo_shape_mesh.generate(sharded.small_config(), 2_147_483_659, rows=4_001)
+    train, val = (
+        sample_sharded_dataset(sharded.device_parts(problem[part], real_rows_only=True), mesh)
+        for part in ("train", "validation")
+    )
+    est, opt = sharded.estimator(sharded.small_config())
+    return est, train, val, opt
+
+
+class TestNothingEagerBeforeTheSolve:
+    """Between a warm fit's start and its solve's dispatch the
+    host runs no eager operation and moves nothing to the device: every
+    array the solve reads is made in a program or kept from an earlier fit."""
+
+    @pytest.mark.parametrize(
+        "build, optimizer",
+        [
+            (_dense_fit_data, OptimizerType.LBFGS),
+            (_dense_fit_data, OptimizerType.TRON),
+            (_sample_sharded_fit_data, None),
+        ],
+        ids=["dense_lbfgs", "dense_tron", "sample_sharded_four_devices"],
+    )
+    def test_warm_fit_dispatches_the_solve_first(self, build, optimizer, monkeypatch):
+        import contextlib
+
+        from jax._src import dispatch
+
+        est, train, val, cfg = build(optimizer)
+        est.fit(train, val, [cfg])  # compiles, and makes what is kept
+        (coord,) = est._coordinate_cache.values()
+        eager, solves, window = [], [], contextlib.ExitStack()
+        primitive_callable = dispatch.xla_primitive_callable
+
+        def counting(prim, **params):
+            if solves == []:
+                eager.append(str(prim))
+            return primitive_callable(prim, **params)
+
+        train_fn = coord._train_fn
+
+        def solve(*args, **kwargs):
+            if solves == []:
+                window.close()  # the host-to-device guard ends at the solve
+            solves.append(1)
+            return train_fn(*args, **kwargs)
+
+        monkeypatch.setattr(dispatch, "xla_primitive_callable", counting)
+        monkeypatch.setattr(coord, "_train_fn", solve)
+        window.enter_context(jax.transfer_guard_host_to_device("disallow"))
+        try:
+            result = est.fit(train, val, [cfg])[0]
+        finally:
+            window.close()
+        assert solves == [1] and eager == [], eager
+        assert result.evaluation is not None
+
+
 class TestThreeSinks:
     def test_one_boundary_three_sinks(self, tmp_path):
         """A stage's wall reaches the ambient registry, a telemetry span

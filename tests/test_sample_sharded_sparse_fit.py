@@ -266,7 +266,7 @@ def test_the_solve_reduces_once_an_evaluation_and_gathers_no_plane(sharded_data)
     assert sum(1 for c in coordinate.training_features.span_classes if c) == 30  # the dense-span loops are in it
     text = coordinate._train_fn.lower(
         coordinate.training_features, train.labels, train.offsets, train.weights,
-        jnp.zeros((DIM,), jnp.float32), jnp.float32(1.0), jax.random.PRNGKey(0),
+        jnp.zeros((DIM,), jnp.float32), jnp.float32(1.0),
     ).compile().as_text()
     found = [(m.group(2), m.group(1), line) for line in text.splitlines() for m in [COLLECTIVE.search(line)] if m]
     assert [kind for kind, _, _ in found] == ["all-reduce"] * 2, [f[:2] for f in found]

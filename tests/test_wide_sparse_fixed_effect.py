@@ -275,7 +275,7 @@ def test_the_scopes_cover_the_ell_gather_and_scatter(problem, program, scopes, o
     if program == "train":
         text = compiled_text(
             coordinate._train_fn, coordinate.training_features, data.labels, data.offsets,
-            data.weights, w, jnp.float32(1.0), jax.random.PRNGKey(0),
+            data.weights, w, jnp.float32(1.0),
         )
     else:
         text = compiled_text(_fe_margins, coordinate.training_features, w, None)
