@@ -10,9 +10,10 @@ random effects with `python -m photon_ml_tpu.cli.train`, replay JSONL requests
 through `python -m photon_ml_tpu.cli.serve`, recompute the answers in plain
 numpy float32 from the written model, and run the dense Pallas probe. Then
 check what the runs themselves wrote (training-summary.json, profile.json,
-serving-summary.json): AUC, zero retry/degrade counters, the bucketed pack and
-sparse Pallas kernels engaged, the pack and assembly routes, native ingest,
-serving parity, compile-cache traffic.
+serving-summary.json): AUC, zero retry/degrade counters, the fixed effect's
+layout (one dense block at the default rows; the bucketed pack and sparse
+Pallas kernels where the block would pass 4 GiB), the pack and assembly
+routes, native ingest, serving parity, compile-cache traffic.
 
 One process per chip: this parent never imports JAX. Every stage is a child
 process that exits before the next starts, and the device facts in the last
@@ -745,14 +746,15 @@ def one_chip(args) -> int:
         "native_ingest", str(ingest.get("ingest_path", "")).startswith("native"),
         "correctness", ingest_path=ingest.get("ingest_path"),
     )
+    # The sparse fixed effect leaves the ELL route: at d = 201 and up to
+    # ~5 M rows as one float32 dense block (containers.dense_block_admitted),
+    # beyond that through the bucketed pack and the sparse Pallas kernels.
+    dense_block = dispatch["sparse_objective"] == "dense_block"
+    packed = ft["pack_path"] != "none" and str(dispatch["sparse_objective"]).startswith("pallas_")
     checks.check(
-        "bucketed_pack_happened", ft["pack_path"] != "none", "engagement",
-        pack_path=ft["pack_path"], layout=dispatch["layout"],
-    )
-    checks.check(
-        "sparse_pallas_objective_engaged",
-        str(dispatch["sparse_objective"]).startswith("pallas_"), "engagement",
-        sparse_objective=dispatch["sparse_objective"],
+        "fixed_effect_layout_engaged", dense_block or packed, "engagement",
+        sparse_objective=dispatch["sparse_objective"], pack_path=ft["pack_path"],
+        layout=dispatch["layout"], dense_storage=dispatch["dense_storage"],
     )
     # The random-effect assembly route is reported, not required: an auto
     # rule may keep it on the host on a TPU, and then the line names it.

@@ -29,7 +29,9 @@ below); the wide planes keep the gather, and every plane the scatter-add.
 A random-effect bucket's block needs neither where its entities' projected
 space is narrow: `dense_block` lays the bucket's (E, K, S) ELL block out
 once as a `DenseBlock`, X^T of shape (E, D, S), and each product of the
-solve then reads it once ("dense blocks", below).
+solve then reads it once ("dense blocks", below). A narrow fixed-effect
+shard is the same rule's case of one entity whose block is every row: X^T
+of shape (D, N).
 """
 
 from __future__ import annotations
@@ -58,7 +60,8 @@ DENSE_SPAN_FLOOR = 128
 
 # Dense blocks. A random-effect bucket's rows are solved as a `DenseBlock`
 # (X^T, (E, D, S)) where two things hold of its static shapes, and as its ELL
-# block (E, K, S) otherwise (`dense_block_admitted`). Derived, not tuned:
+# block (E, K, S) otherwise (`dense_block_admitted`); a fixed-effect shard of
+# N rows is asked as one entity of capacity N. Derived, not tuned:
 # (a) the dense block fits: E * D * S values as the chip lays them (S on the
 #     128 lanes, D on the 8 sublanes) take at most a quarter of one v5e's
 #     16 GiB, beside the solve's other buffers and the shard it gathers from;
@@ -336,7 +339,8 @@ class DenseBlock:
     """A bucket's entity rows laid dense and transposed: `xt[..., d, s]` is
     row s's value of feature d, the rows (S, the bucket's capacity) on the
     minor axis, so that D pads only to a multiple of 8. Built from an ELL
-    block by `dense_block`; under `vmap` one entity's (D, S).
+    block by `dense_block`; under `vmap` one entity's (D, S). A fixed
+    effect's is the whole shard, (D, N).
 
     The products read the block once each and contract it by multiply and
     reduce in the values' dtype, never by a `dot_general`, whose default
@@ -347,6 +351,11 @@ class DenseBlock:
     @property
     def dim(self) -> int:
         return self.xt.shape[-2]
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        """The logical (..., S, D) of the rows, as `SparseFeatures.shape`."""
+        return (*self.xt.shape[:-2], self.xt.shape[-1], self.xt.shape[-2])
 
     def matvec(self, w: Array) -> Array:
         """z[s] = sum_d xt[d, s] w[d]."""

@@ -315,6 +315,34 @@ class GameDataset:
             cached = self.bucketed_cache[key] = annotate_spans(feats)
         return cached
 
+    def dense_block_shard(self, name: str) -> Optional[DenseBlock]:
+        """The ELL shard laid as one `DenseBlock` in its values' dtype, X^T
+        of shape (dim, n), where `dense_block_admitted` admits its shapes as one
+        entity of all n rows (the random effects' rule: the block within
+        DENSE_BLOCK_MAX_BYTES, dim within 128 ids a plane); else None, and
+        for a shard whose planes lie on more than one device. Built in one
+        program at the first call and kept with the data set's other
+        per-shard decisions, so no rebuilt coordinate or later fit lays it
+        again. The ingest's CSR stash is dropped, and a pack it started is
+        cancelled and never waited on: no bucketed pack is made of it."""
+        feats = self.peek_shard(name)
+        if not isinstance(feats, SparseFeatures) or feats.ell_axis != -1 or feats.indices.ndim != 2:
+            return None
+        rows, planes = feats.indices.shape
+        if not dense_block_admitted(1, planes, rows, feats.dim):
+            return None
+        if isinstance(feats.indices, jax.Array) and len(feats.indices.sharding.device_set) != 1:
+            return None
+        feats = self.shards[name]
+        key = ("dense_block", name)
+        cached = self.bucketed_cache.get(key)
+        if cached is None or cached[0] is not feats.indices:
+            csr = self.host_csr.pop(name, None)
+            if getattr(csr, "pack_future", None) is not None:
+                csr.pack_future.cancel()
+            cached = self.bucketed_cache[key] = (feats.indices, _lay_dense_block(feats))
+        return cached[1]
+
     def release_stash(self) -> None:
         """Drop the ingest CSR stash when no coordinate will consume it
         (scoring, validation datasets) — cancelling any background pack
@@ -357,6 +385,11 @@ class GameDataset:
             if len(v) != n:
                 raise ValueError(f"id tag {k!r} has {len(v)} values for {n} samples")
         return cls(ShardDict(shards), labels, offsets, weights, tags)
+
+
+# One program lays a fixed-effect shard dense: its plane loop is one
+# elementwise fusion that writes the block and no (dim, n) intermediate.
+_lay_dense_block = jax.jit(dense_block)
 
 
 def _ell_row_planes(feats: SparseFeatures):

@@ -469,7 +469,8 @@ class GameEstimator:
         ELL device uploads, and the program construction happen; the first
         two record their own stages, and the remainder of the construction
         wall is attributed to `compile` (dispatch decisions + jit/program
-        building)."""
+        building). A fixed effect's construction adds one to the counter
+        `fixed_effect_blocks{coordinate=<id>,layout=dense|packed|ell}`."""
         key = (cid, _static_config_key(opt_config))
         coord = self._coordinate_cache.get(key)
         if coord is None:
@@ -487,6 +488,10 @@ class GameEstimator:
                 else:
                     coord = FixedEffectCoordinate(
                         dataset, prep.shard, static_cfg, self.task, prep.norm
+                    )
+                    telemetry.METRICS.increment(
+                        "fixed_effect_blocks",
+                        labels=(("coordinate", cid), ("layout", coord.layout)),
                     )
             self._coordinate_cache[key] = coord
         return coord

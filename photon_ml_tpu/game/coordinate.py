@@ -52,7 +52,14 @@ import numpy as np
 
 logger = logging.getLogger(__name__)
 
-from photon_ml_tpu.data.containers import LabeledData, SparseFeatures, span_note
+from photon_ml_tpu.data.bucketed import BucketedSparseFeatures
+from photon_ml_tpu.data.containers import (
+    DenseBlock,
+    LabeledData,
+    SparseFeatures,
+    dense_block_bytes,
+    span_note,
+)
 from photon_ml_tpu.data.game_dataset import (
     GameDataset,
     RandomEffectDataset,
@@ -239,6 +246,14 @@ class FixedEffectCoordinate:
         self.norm = norm
         self._reg_weights: Dict[float, Array] = {}
         self._features = self._prepared_features()
+        # How the shard is laid for its solves: `dense` (a `DenseBlock`, or a
+        # dense matrix), `packed` (the bucketed layout) or `ell` (the ELL
+        # planes); counter `fixed_effect_blocks`.
+        self.layout = (
+            "packed" if isinstance(self._features, BucketedSparseFeatures)
+            else "ell" if isinstance(self._features, SparseFeatures)
+            else "dense"
+        )
         # What computes this shard's objective, decided ONCE here on the
         # concrete arrays (their dtype, shape, placement and layout are all
         # visible, unlike inside train_fn's trace) and closed over by it.
@@ -272,7 +287,7 @@ class FixedEffectCoordinate:
         # ELL shard's where its objective is summed over the mesh.
         from photon_ml_tpu.parallel.mesh import leading_axis_mesh
 
-        if isinstance(self._features, SparseFeatures):
+        if isinstance(self._features, (SparseFeatures, DenseBlock)):
             mesh = self.route.mesh
         else:
             mesh = leading_axis_mesh(self._features)
@@ -301,9 +316,10 @@ class FixedEffectCoordinate:
     def _prepared_features(self):
         """The shard as this coordinate trains and scores it, kept with the
         dataset so that a rebuilt coordinate prepares nothing again: a dense
-        matrix the fused kernels read, stored bf16; a sparse shard packed
-        into the bucketed layout where the pack pays, else the device ELL
-        with its planes' dense-span annotation."""
+        matrix the fused kernels read, stored bf16; a narrow sparse shard
+        laid as one float32 `DenseBlock`; a wider one packed into the
+        bucketed layout where the pack pays, else the device ELL with its
+        planes' dense-span annotation."""
         dataset, shard = self.dataset, self.shard
         # Peek without forcing a device upload: if the bucketed pack
         # engages below, the raw ELL never ships to the device at all.
@@ -325,6 +341,23 @@ class FixedEffectCoordinate:
             if cache.get(key) is None:
                 cache[key] = dataset.shards[shard].astype(jnp.bfloat16)
             return cache[key]
+        # A shard whose shapes admit it as one dense block, X^T (dim, n), is
+        # solved and scored on that block, multiplied and reduced in float32:
+        # the random effects' rule (`dense_block_admitted`) with one entity.
+        # No pack is made or joined for it.
+        block = dataset.dense_block_shard(shard)
+        if block is not None:
+            set_stage_note("sparse_objective", "dense_block")
+            set_stage_note(
+                "dense_storage",
+                {
+                    "layout": "dense_block",
+                    "dtype": jnp.dtype(block.xt.dtype).name,
+                    # As the chip lays it, beside the shard's ELL planes.
+                    "bytes": dense_block_bytes(1, block.dim, block.xt.shape[-1]),
+                },
+            )
+            return block
         bf = None
         if pallas_sparse.kernels_eligible():
             # Pack once per dataset: sweeps/warm-start chains that rebuild
@@ -452,8 +485,8 @@ class FixedEffectCoordinate:
 
     @property
     def training_features(self):
-        """The representation training actually ran on (bucketed layout,
-        bf16-stored matrix, or the ELL) — scoring the training dataset
+        """The representation training actually ran on (dense block,
+        bucketed layout, bf16-stored matrix, or the ELL) — scoring the training dataset
         through it reuses compiled programs and device residency."""
         return self._features
 

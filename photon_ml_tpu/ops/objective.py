@@ -407,7 +407,10 @@ def hessian_matrix(
         X = feats
     if norm is not None and norm.shifts is not None:
         X = X - norm.shifts
-    H = (X * c[:, None]).T @ X
+    # A dense block is contracted in float32 (`DenseBlock`): the TPU's
+    # default precision would round it to bfloat16.
+    precision = jax.lax.Precision.HIGHEST if isinstance(feats, DenseBlock) else None
+    H = jnp.matmul((X * c[:, None]).T, X, precision=precision)
     if norm is not None and norm.factors is not None:
         H = H * jnp.outer(norm.factors, norm.factors)
     return H + l2 * jnp.eye(w.shape[0], dtype=w.dtype)

@@ -235,9 +235,9 @@ def dense_margins(features: Array, w: Array, norm) -> Array:
 @jax.jit
 def _fe_margins(features: Features, w: Array, norm) -> Array:
     # `features` may be an ELL SparseFeatures (either layout), a dense
-    # matrix, or the trained coordinate's BucketedSparseFeatures
-    # (training_prepared's preference) — all three expose the logical
-    # (n_rows, dim) via .shape, and compute_margins handles each. Dense
+    # matrix, or the trained coordinate's BucketedSparseFeatures or
+    # DenseBlock (training_prepared's preference) — each exposes the
+    # logical (n_rows, dim) via .shape, and compute_margins handles each. Dense
     # matrices take the row-stable path (see `dense_margins`); the sparse
     # layouts' gather + per-row-K reductions are already batch-invariant.
     with jax.named_scope("score/fixed"):

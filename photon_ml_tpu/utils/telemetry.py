@@ -214,6 +214,12 @@ METRIC_DESCRIPTIONS = {
     # time (data/game_dataset.block_layout). Added once a fit.
     "random_effect_blocks": "a random effect's entity buckets by the layout "
     "its solves read them in (labeled coordinate=<id>,layout=dense|sparse)",
+    # A fixed effect's shard by the layout its solves read it in: `dense`,
+    # one float32 X^T block (containers.DenseBlock) or a dense matrix;
+    # `packed`, the bucketed layout; `ell`, the ELL planes
+    # (FixedEffectCoordinate.layout). Added once a coordinate construction.
+    "fixed_effect_blocks": "a fixed effect's shard by the layout its solves "
+    "read it in (labeled coordinate=<id>,layout=dense|packed|ell)",
     # What a random effect trains on (RandomEffectDataset.num_entities and
     # num_active_samples: each entity's rows, or its cap where it has
     # more), set at every fit: a pass of one entity reads its share.

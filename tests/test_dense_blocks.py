@@ -6,6 +6,11 @@ shape (E, D, S) once, and the vmapped solves contract that block by multiply
 and reduce: no plane is gathered or scatter-added inside their loops. The
 two layouts are two writings of one objective; these tests hold them to
 each other, the rule to the shapes it reads, and the dense solve to float32.
+
+A narrow fixed-effect shard is the same rule's case of one entity of every
+row (`GameDataset.dense_block_shard`): one X^T block of shape (D, N), which
+its coordinate trains and scores on in place of the bucketed pack or the
+ELL planes.
 """
 
 from __future__ import annotations
@@ -301,3 +306,198 @@ def test_the_counter_reports_each_coordinates_buckets_by_layout(monkeypatch):
     note = est.run_profile()["dispatch"]["re_block_layout"]["per-user"]
     (coord,) = [c for (cid, _), c in est._coordinate_cache.items() if cid == "per-user"]
     assert note["dense_bytes"] == max(containers.dense_block_bytes(e, coord.dim, s) for e, s, _ in note["shapes"]) > 0
+
+
+# -- a narrow fixed effect: one entity of every row -----------------------------
+
+
+def _fixed_effect_shard(seed, n=3000, dim=D):
+    """An ELL shard of `_ell_rows` over `dim` features, and its data set."""
+    rng = np.random.default_rng(seed)
+    idx, val = _ell_rows(rng, n)
+    y = (rng.uniform(size=n) < 0.5).astype(np.float32)
+    shard = SparseFeatures(jnp.asarray(idx), jnp.asarray(val), dim)
+    return GameDataset.build({"g": shard}, y), rng
+
+
+def test_a_narrow_fixed_effect_is_one_block_whose_sums_are_the_ell_routes():
+    from photon_ml_tpu.data.containers import LabeledData
+    from photon_ml_tpu.ops import objective
+    from photon_ml_tpu.ops.losses import loss_for_task
+
+    ds, rng = _fixed_effect_shard(7)
+    n = ds.num_samples
+    block, ell = ds.dense_block_shard("g"), ds.shards["g"]
+    assert isinstance(block, containers.DenseBlock) and block.xt.dtype == jnp.float32
+    assert block.xt.shape == (D, n) and block.shape == ell.shape == (n, D)
+    assert ds.dense_block_shard("g") is block  # laid once a data set
+    assert objective.route_for(block) == objective.XLA
+    loss = loss_for_task(TASK)
+    w, v = (jnp.asarray(rng.normal(size=D).astype(np.float32)) for _ in range(2))
+    offsets = jnp.asarray(rng.normal(size=n).astype(np.float32))
+    weights = jnp.asarray(rng.uniform(size=n).astype(np.float32))
+
+    def data(features):
+        return LabeledData(features, ds.labels, offsets, weights)
+
+    (val_b, g_b), (val_e, g_e) = (objective.value_and_gradient(loss, w, data(f), l2=0.5) for f in (block, ell))
+    np.testing.assert_allclose(float(val_b), float(val_e), rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(g_b), np.asarray(g_e), rtol=1e-6)
+    hv_b, hv_e = (objective.hessian_vector(loss, w, v, data(f), l2=0.5) for f in (block, ell))
+    np.testing.assert_allclose(np.asarray(hv_b), np.asarray(hv_e), rtol=1e-6)
+    diag_b, diag_e = (objective.hessian_diagonal(loss, w, data(f), l2=0.5) for f in (block, ell))
+    np.testing.assert_allclose(np.asarray(diag_b), np.asarray(diag_e), rtol=1e-6)
+    # Margins: a few units in the last place of the row's terms.
+    idx, val = np.asarray(ell.indices), np.asarray(ell.values)
+    terms = np.abs(val * np.asarray(w)[idx]).sum(axis=1)
+    z_b, z_e = (np.asarray(objective.compute_margins(w, data(f))) for f in (block, ell))
+    assert np.all(np.abs(z_b - z_e) <= 4 * np.finfo(np.float32).eps * terms)
+
+
+def test_the_scoring_program_scores_a_dense_block():
+    from photon_ml_tpu.transformers.game_transformer import _fe_margins
+
+    ds, rng = _fixed_effect_shard(8, n=500)
+    w = jnp.asarray(rng.normal(size=D).astype(np.float32))
+    block, ell = ds.dense_block_shard("g"), ds.shards["g"]
+    z_b, z_e = np.asarray(_fe_margins(block, w, None)), np.asarray(_fe_margins(ell, w, None))
+    terms = np.abs(np.asarray(ell.values) * np.asarray(w)[np.asarray(ell.indices)]).sum(axis=1)
+    assert z_b.shape == (500,)
+    assert np.all(np.abs(z_b - z_e) <= 4 * np.finfo(np.float32).eps * terms)
+
+
+@pytest.mark.parametrize("admitted", [True, False], ids=["narrow", "wider_than_128_a_plane"])
+def test_a_fixed_effect_coordinate_lays_its_shard_by_the_rule(monkeypatch, admitted):
+    """With the sparse kernels eligible and ingest's pack started, an admitted
+    shard is laid dense and no pack is joined or made; a declined one goes on
+    to the pack's decision as before."""
+    from photon_ml_tpu.data.game_dataset import HostCSR
+    from photon_ml_tpu.ops import pallas_glm, pallas_sparse
+
+    monkeypatch.setattr(pallas_glm, "FORCE_INTERPRET", True)
+    monkeypatch.setenv("PHOTON_PIPELINE", "1")
+    monkeypatch.setenv("PHOTON_DEVICE_PACK", "0")
+    dim = D if admitted else containers.DENSE_BLOCK_IDS_PER_PLANE * K + 1
+    ds, _ = _fixed_effect_shard(9, n=9000, dim=dim)
+    idx, val = np.asarray(ds.shards["g"].indices), np.asarray(ds.shards["g"].values)
+    live = val != 0
+    csr = HostCSR(
+        np.concatenate([[0], np.cumsum(live.sum(axis=1))]).astype(np.int64),
+        idx[live].astype(np.int64), val[live], dim,
+    )
+    ds.host_csr = {"g": csr}
+    pallas_sparse.begin_pack_async(csr, ds.num_samples)
+    assert csr.pack_future is not None
+    joined = []
+
+    def finish_pack(stash, n):  # the join, declined: the ELL path follows
+        joined.append(stash)
+        return None
+
+    monkeypatch.setattr(pallas_sparse, "finish_pack", finish_pack)
+    monkeypatch.setattr(pallas_sparse, "maybe_pack", lambda *a, **k: pytest.fail("packed from the device ELL"))
+    before = telemetry.METRICS.labeled_counters("fixed_effect_blocks")
+    est = GameEstimator(TASK, {"global": FixedEffectDataConfig("g")})
+    cfg = CoordinateOptimizationConfig(optimizer=OptimizerConfig(max_iterations=3))
+    (result,) = est.fit(ds, None, [{"global": cfg}])
+    after = telemetry.METRICS.labeled_counters("fixed_effect_blocks")
+    (coord,) = est._coordinate_cache.values()
+    layout = "dense" if admitted else "ell"
+    assert coord.layout == layout
+    assert after[f"coordinate=global,layout={layout}"] - before.get(f"coordinate=global,layout={layout}", 0) == 1
+    assert sum(after.values()) - sum(before.values()) == 1
+    dispatch = est.run_profile()["dispatch"]
+    assert "g" not in ds.host_csr
+    if admitted:
+        assert isinstance(coord.training_features, containers.DenseBlock) and not joined
+        assert dispatch["sparse_objective"] == "dense_block"
+        assert dispatch["dense_storage"] == {
+            "layout": "dense_block", "dtype": "float32",
+            "bytes": containers.dense_block_bytes(1, D, 9000),
+        }
+    else:
+        assert isinstance(coord.training_features, SparseFeatures) and joined == [csr]
+        assert dispatch["sparse_objective"] == "ell_xla" and dispatch["dense_storage"] == "none"
+    assert np.isfinite(np.asarray(result.model["global"].coefficients.means)).all()
+
+
+@pytest.mark.parametrize(
+    "planes, rows, dim, dense",
+    [
+        (9, 4_000_000, 201, True),  # glmix-movielens-tron's fixed effect: 3.33 GB
+        (9, 5_000_000, 201, True),  # 4.16 GB
+        (9, 5_200_000, 201, False),  # 4.33 GB: over the ceiling
+        (9, 20_000_263, 201, False),  # the same shard at the source's rows, 16.6 GB
+        (39, 8_000_000, 1_000_000, False),  # lr-criteo
+        (39, 11_460_155, 1_000_000, False),  # a chip's part of lr-criteo-full
+        (9, 30_000, 9 * 128 + 1, False),  # wider than 128 ids a plane
+    ],
+)
+def test_a_fixed_effect_shard_is_admitted_from_its_shapes_alone(planes, rows, dim, dense):
+    """Views of one zero, so nothing of the shard's size is made."""
+    from photon_ml_tpu.data.game_dataset import ShardDict
+    from photon_ml_tpu.ops import objective
+
+    assert containers.dense_block_admitted(1, planes, rows, dim) is dense
+    if dense:
+        return
+    shard = SparseFeatures(
+        np.broadcast_to(np.int32(0), (rows, planes)), np.broadcast_to(np.float32(0), (rows, planes)), dim
+    )
+    one = np.zeros(1, np.float32)
+    ds = GameDataset(ShardDict({"g": shard}), one, one, one, {})
+    assert ds.dense_block_shard("g") is None
+    assert dict.__getitem__(ds.shards, "g") is shard  # nothing was uploaded
+    assert objective.route_for(shard) == objective.XLA  # today's route
+
+
+def test_a_sample_sharded_shard_is_not_laid_dense():
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from photon_ml_tpu.ops import objective
+
+    mesh = Mesh(np.asarray(jax.devices()[:2]), ("data",))
+    ds, _ = _fixed_effect_shard(10, n=64)
+    ell = ds.shards["g"]
+    rows = NamedSharding(mesh, PartitionSpec("data", None))
+    sharded = SparseFeatures(jax.device_put(ell.indices, rows), jax.device_put(ell.values, rows), D)
+    ds = GameDataset.build({"g": sharded}, np.asarray(ds.labels))
+    assert ds.dense_block_shard("g") is None
+    assert objective.route_for(sharded).kind == "ell_sharded"
+
+
+def test_a_glmix_fit_on_the_dense_block_matches_the_declined_ell_route():
+    """`generators/movielens_shape` at a CPU size through the benchmark's own
+    driver, once at the configuration's width (the fixed effect laid dense)
+    and once over 128 x 9 + 1 features (declined: the ELL route), the same
+    rows and ids: the extra features are never hit and stay 0."""
+    import copy
+    import json
+    import os
+
+    from benchmarks.drivers import refit
+    from benchmarks.generators import movielens_shape
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs", "glmix-movielens-tron.json")) as f:
+        config = json.load(f)
+    data = movielens_shape.generate(config, 2_147_483_659, 20_000)
+    fits = {}
+    declined = containers.DENSE_BLOCK_IDS_PER_PLANE * config["nnz_per_row"] + 1
+    for dim in (config["shards"]["g"]["dim"], declined):
+        wide = copy.deepcopy(data)
+        for part in ("train", "validation"):
+            wide[part]["shards"]["g"]["dim"] = dim
+        state = refit.setup(config, {"traffic": {}}, wide)
+        records, _ = refit.window(state, 0.0, lambda i: None)
+        (coord,) = [c for (cid, _), c in state.estimator._coordinate_cache.items() if cid == "global"]
+        fits[coord.layout] = refit.outputs(state, records[-1]), records[-1]["metric"]
+        refit.release(state)
+    (dense, auc), (ell, ell_auc) = fits["dense"], fits["ell"]
+    w, w_ell = dense["global"], ell["global"]
+    assert not w_ell[len(w):].any() and np.abs(w).max() > 0.05
+    assert np.linalg.norm(w - w_ell[: len(w)]) <= 2e-6 * np.linalg.norm(w_ell)
+    assert abs(auc - ell_auc) <= 1e-6
+    for cid in ("per-user", "per-movie"):
+        a, b = dense[cid], ell[cid][:, : len(w)]
+        assert np.linalg.norm(a - b) <= 2e-6 * np.linalg.norm(b)

@@ -430,7 +430,9 @@ class TestObjectiveIntegration:
         from photon_ml_tpu.types import TaskType
 
         rng = np.random.default_rng(6)
-        n, d, k = 9000, 200, 6
+        # Wider than 128 ids a plane: the dense block is declined
+        # (containers.dense_block_admitted), so the pack is what is judged.
+        n, d, k = 9000, 800, 6
         idx = rng.integers(0, d, size=(n, k)).astype(np.int32)
         val = rng.normal(size=(n, k)).astype(np.float32)
         sp = SparseFeatures(jnp.asarray(idx), jnp.asarray(val), d)
@@ -477,7 +479,8 @@ class TestObjectiveIntegration:
         from photon_ml_tpu.types import TaskType
 
         rng = np.random.default_rng(16)
-        n, d, k = 9100, 190, 6  # shapes no other test traces
+        # Shapes no other test traces, wider than 128 ids a plane (no dense block).
+        n, d, k = 9100, 790, 6
         idx = rng.integers(0, d, size=(n, k)).astype(np.int32)
         val = rng.normal(size=(n, k)).astype(np.float32)
         y = (rng.uniform(size=n) < 0.5).astype(np.float32)
@@ -509,7 +512,7 @@ class TestHostCooPack:
         from photon_ml_tpu.types import TaskType
 
         rng = np.random.default_rng(9)
-        n, d, k = 9000, 200, 6
+        n, d, k = 9000, 800, 6  # no dense block: d > 128 k
         idx = rng.integers(0, d, size=(n, k)).astype(np.int32)
         val = rng.normal(size=(n, k)).astype(np.float32)
         sp = SparseFeatures(jnp.asarray(idx), jnp.asarray(val), d)
@@ -558,7 +561,7 @@ class TestHostCooPack:
         from photon_ml_tpu.types import TaskType
 
         rng = np.random.default_rng(10)
-        n, d, k = 9000, 200, 6
+        n, d, k = 9000, 800, 6  # no dense block: d > 128 k
         idx = rng.integers(0, d, size=(n, k)).astype(np.int32)
         val = rng.normal(size=(n, k)).astype(np.float32)
         y = (rng.uniform(size=n) > 0.5).astype(np.float32)

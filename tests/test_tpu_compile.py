@@ -709,3 +709,44 @@ def test_the_random_effect_sweep_reads_dense_blocks_at_the_glmix_shape(
     # gradient scatter-adds would write E x D entries.
     scatters = [i for i in instructions.values() if i.opcode == "scatter"]
     assert scatters and all(i.elements == (GLMIX_ENTITIES + 1) * GLMIX_DIM for i in scatters), scatters
+
+
+def test_the_glmix_fixed_effect_is_one_dense_block_read_by_multiply_and_reduce(one_chip):
+    """`glmix-movielens-tron.fit`'s fixed effect, 4,000,000 rows of 9 ELL
+    planes over 201 features, is admitted as one float32 X^T block: one
+    program lays it (its temporaries a small part of the block), and the
+    L-BFGS solve and the scoring read it by multiply and reduce, with no
+    `dot` or `convolution` (the default precision would round the block to
+    bfloat16) and no gather or scatter."""
+    from photon_ml_tpu.data import containers
+    from photon_ml_tpu.data.containers import DenseBlock, SparseFeatures
+    from photon_ml_tpu.data.game_dataset import FixedEffectDataConfig, GameDataset, _lay_dense_block
+    from photon_ml_tpu.game.coordinate import FixedEffectCoordinate
+    from photon_ml_tpu.optimize.config import CoordinateOptimizationConfig, OptimizerConfig
+    from photon_ml_tpu.transformers.game_transformer import _fe_margins
+
+    dim = GLMIX_DIM - 7  # 200 named features + the intercept; 208 as laid
+    assert containers.dense_block_admitted(1, GLMIX_PLANES, GLMIX_ROWS, dim)
+    block_bytes = containers.dense_block_bytes(1, dim, GLMIX_ROWS)
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    planes = (GLMIX_ROWS, GLMIX_PLANES)
+    lay = _lay_dense_block.lower(
+        SparseFeatures(spec(planes, jnp.int32), spec(planes, jnp.float32), dim)
+    ).compile()
+    memory = lay.memory_analysis()
+    assert memory.output_size_in_bytes == block_bytes and memory.temp_size_in_bytes < block_bytes // 8
+    # The coordinate as the cell builds it, on a small shard of the width.
+    small = SparseFeatures(jnp.zeros((64, GLMIX_PLANES), jnp.int32), jnp.zeros((64, GLMIX_PLANES)), dim)
+    ds = GameDataset.build({"g": small}, np.zeros(64, np.float32))
+    cfg = CoordinateOptimizationConfig(optimizer=OptimizerConfig(max_iterations=6, tolerance=1e-9))
+    coord = FixedEffectCoordinate(ds, "g", cfg, TaskType.LOGISTIC_REGRESSION)
+    assert coord.layout == "dense" and isinstance(coord.training_features, DenseBlock)
+    block = DenseBlock(spec((dim, GLMIX_ROWS), jnp.float32))
+    rows = spec((GLMIX_ROWS,), jnp.float32)
+    solve = coord._train_fn.lower(block, rows, rows, rows, None, _scalar(one_chip), None).compile()
+    score = _fe_margins.lower(block, spec((dim,), jnp.float32), None).compile()
+    for program in (solve, score):
+        assert program.memory_analysis().temp_size_in_bytes < block_bytes // 100
+        opcodes = {i.opcode for i in _array_instructions(program.as_text()).values()}
+        assert "fusion" in opcodes
+        assert not opcodes & {"dot", "convolution", "gather", "scatter"}, opcodes
