@@ -123,6 +123,19 @@ class _PreparedCoordinate:
     projector: Optional[object] = None
 
 
+@dataclasses.dataclass
+class _ValidationSlot:
+    """One validation data set's scoring views (`_validation_prepared`)."""
+
+    dataset: GameDataset
+    # Per coordinate: (id, shard, projector, entity index) the view was
+    # made through; the last two are compared by identity.
+    views: Tuple
+    prepared: Dict[str, PreparedCoordinateData]
+    # The `ell_planes_scored` note the build set, set again on a reuse.
+    scored_note: Optional[dict]
+
+
 class GameEstimator:
     """fit(data, validation, configs) -> [GameResult] (GameEstimator.scala:54).
 
@@ -180,6 +193,7 @@ class GameEstimator:
         self.timing_registry = TimingRegistry()
         self._prepared: Optional[Dict[str, _PreparedCoordinate]] = None
         self._prepared_dataset: Optional[GameDataset] = None
+        self._validation_slot: Optional[_ValidationSlot] = None
         self._coordinate_cache: Dict[Tuple, object] = {}
 
     # ------------------------------------------------------------------ prep
@@ -559,6 +573,71 @@ class GameEstimator:
                 out[cid] = PreparedCoordinateData(feats, None)
         return out
 
+    def _validation_prepared(
+        self,
+        validation_data: GameDataset,
+        specs: Mapping[str, CoordinateScoringSpec],
+    ) -> Tuple[Dict[str, PreparedCoordinateData], str]:
+        """Each coordinate's scoring view of `validation_data` (entity rows
+        and projected features, `prepare_coordinate_data`), built once a
+        validation data set and handed to every later fit and sweep on the
+        same object, with the verdict "built" or "reused".
+
+        One slot holds the data set, compared with `is` as `prepare` holds
+        the training set, and each coordinate's shard, projector and entity
+        index, so a view made through other artifacts is never handed back;
+        another data set replaces the slot. The `projector` stage is entered
+        either way, and a reuse sets the build's `ell_planes_scored` note
+        again. Adds one a coordinate to the counter
+        `validation_prepared{coordinate=<id>,outcome=built|reused}`."""
+        views = tuple(
+            (cid, specs[cid].shard, specs[cid].projector, specs[cid].entity_index)
+            for cid in self.update_sequence
+        )
+        slot = self._validation_slot
+        reused = (
+            slot is not None
+            and slot.dataset is validation_data
+            and len(slot.views) == len(views)
+            and all(
+                a[:2] == b[:2] and a[2] is b[2] and a[3] is b[3]
+                for a, b in zip(slot.views, views)
+            )
+        )
+        reg = self.timing_registry
+        with stage_scope(reg):
+            if not reused:
+                # Free the old views before the new ones are made.
+                self._validation_slot = None
+                reg.clear_notes("ell_planes_scored")
+                # Prefetch INSIDE the scope: AsyncUploader captures the
+                # submitter's registry at submit time, so these uploads'
+                # walls land in the breakdown's `upload` stage.
+                prefetch_fixed_effect_shards(
+                    specs, self.update_sequence, validation_data, self.pipeline
+                )
+            with self._exclusive_stage("projector"):
+                if not reused:
+                    prepared = {
+                        cid: prepare_coordinate_data(specs[cid], validation_data)
+                        for cid in self.update_sequence
+                    }
+                    slot = self._validation_slot = _ValidationSlot(
+                        validation_data,
+                        views,
+                        prepared,
+                        reg.get_note("ell_planes_scored"),
+                    )
+        if slot.scored_note is not None:
+            reg.set_note("ell_planes_scored", slot.scored_note)
+        outcome = "reused" if reused else "built"
+        for cid in self.update_sequence:
+            telemetry.METRICS.increment(
+                "validation_prepared",
+                labels=(("coordinate", cid), ("outcome", outcome)),
+            )
+        return slot.prepared, outcome
+
     def _validation_suite(self, validation: GameDataset) -> EvaluationSuite:
         evaluators = self.validation_evaluators or [
             default_evaluator_for_task(self.task)
@@ -783,29 +862,19 @@ class GameEstimator:
             suite = self._validation_suite(validation_data) if validation_data is not None else None
             specs = self.scoring_specs()
 
-        # Host prep of the validation dataset per coordinate (projection +
-        # entity-row resolution), once a fit and reused across every CD
-        # step; attributed to the `projector` stage (it is projection +
-        # entity-row resolution over the validation sample axis).
-        val_prep = None
+        # The validation data set's scoring views (projection + entity-row
+        # resolution), built by the first fit that sees the data set and
+        # reused by every later one and across every CD step; attributed to
+        # the `projector` stage.
+        val_prep = outcome = None
         with stage_timer("fit/validation_prep") as validation_prep:
             if validation_data is not None:
-                with stage_scope(self.timing_registry):
-                    # Prefetch INSIDE the scope: AsyncUploader captures the
-                    # submitter's registry at submit time, so these uploads'
-                    # walls land in the breakdown's `upload` stage.
-                    prefetch_fixed_effect_shards(
-                        specs, self.update_sequence, validation_data, self.pipeline
-                    )
-                    with self._exclusive_stage("projector"):
-                        val_prep = {
-                            cid: prepare_coordinate_data(specs[cid], validation_data)
-                            for cid in self.update_sequence
-                        }
+                val_prep, outcome = self._validation_prepared(validation_data, specs)
 
         self.fit_timing = {
             "prepare_s": revalidate.seconds + validation_prep.seconds,
             "solve_s": 0.0,
+            "validation_prep": outcome,
         }
 
         results: List[GameResult] = []
@@ -1100,15 +1169,7 @@ class GameEstimator:
         }
         suite = self._validation_suite(validation_data)
         specs = self.scoring_specs()
-        with stage_scope(self.timing_registry):
-            prefetch_fixed_effect_shards(
-                specs, self.update_sequence, validation_data, self.pipeline
-            )
-            with self._exclusive_stage("projector"):
-                val_prep = {
-                    cid: prepare_coordinate_data(specs[cid], validation_data)
-                    for cid in self.update_sequence
-                }
+        val_prep, _ = self._validation_prepared(validation_data, specs)
         # Traceable per-coordinate validation scorers: model ARRAYS ->
         # margins through the same jitted programs the serial validation
         # path dispatches (`coordinate_margins`' replicated branches), so

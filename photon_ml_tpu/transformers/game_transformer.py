@@ -19,10 +19,11 @@ needs (which the reference rebuilds inside transform():150-263):
     avoiding RandomEffectModelInProjectedSpace back-projection);
   * folding normalization into effective coefficients.
 
-That plumbing is host-side and dataset-bound, so it is factored into
-`prepare_coordinate_data` and done ONCE per (coordinate, dataset) — repeated
-scoring of the same dataset (the coordinate-descent validation loop) reuses
-the prepared features/entity rows.
+That plumbing is dataset-bound, so it is factored into
+`prepare_coordinate_data`; repeated scoring of the same dataset (the
+coordinate-descent validation loop, every later fit of one estimator on the
+same validation set: `GameEstimator._validation_prepared`) reuses the
+prepared features/entity rows.
 """
 
 from __future__ import annotations
@@ -164,8 +165,10 @@ def prefetch_fixed_effect_shards(
 def prepare_coordinate_data(
     spec: CoordinateScoringSpec, dataset: GameDataset
 ) -> PreparedCoordinateData:
-    """Host-side, once per (coordinate, dataset): resolve entity rows and run
-    the projector. Everything downstream is pure device compute."""
+    """Resolve entity rows and run the projector: once per (coordinate,
+    dataset) where the caller keeps the result, as
+    `GameEstimator._validation_prepared` does for a validation set.
+    Everything downstream is pure device compute."""
     if not spec.is_random_effect:
         # An ELL shard is scored with its dense-span annotation, which the
         # data set reads once and keeps (`GameDataset.annotated_shard`).

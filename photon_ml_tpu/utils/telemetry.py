@@ -220,6 +220,12 @@ METRIC_DESCRIPTIONS = {
     # (FixedEffectCoordinate.layout). Added once a coordinate construction.
     "fixed_effect_blocks": "a fixed effect's shard by the layout its solves "
     "read it in (labeled coordinate=<id>,layout=dense|packed|ell)",
+    # A coordinate's scoring view of a validation data set: `built` by the
+    # first fit or sweep that sees the data set, `reused` by every later one
+    # on the same object (GameEstimator._validation_prepared). Added once a
+    # coordinate a fit.
+    "validation_prepared": "a coordinate's view of the validation data set, "
+    "built or reused (labeled coordinate=<id>,outcome=built|reused)",
     # What a random effect trains on (RandomEffectDataset.num_entities and
     # num_active_samples: each entity's rows, or its cap where it has
     # more), set at every fit: a pass of one entity reads its share.
